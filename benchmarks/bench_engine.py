@@ -1,6 +1,7 @@
 """Dense ``cnn_apply`` vs compiled-engine execution, across sparsity levels.
 
-Runs mini-CNN and VGG16 shapes on CPU, and emits a JSON report with:
+Runs mini-CNN and VGG16 shapes through the default kernel dispatch (the
+Pallas kernel on a TPU, the XLA path on CPU), and emits a JSON report with:
 
   * dense-vs-engine wall-clock per (network, sparsity),
   * each compiled program's ``hardware_report()`` totals, priced three
@@ -24,12 +25,12 @@ Runs mini-CNN and VGG16 shapes on CPU, and emits a JSON report with:
     single-trace invariant under socket-driven concurrency, and a
     load-shedding phase whose served/shed split must conserve requests,
   * a 1-vs-N-device sharded-execution entry: the same compiled program
-    run unsharded and tile/batch-sharded over a mesh of N virtualized
-    host devices (subprocess, ``--xla_force_host_platform_device_count``),
-    recording both wall-clocks, the speedup, and the max output
-    difference.  On virtualized CPU devices the "speedup" mostly measures
-    collective overhead — the entry exists so the TPU run has a number to
-    replace,
+    run unsharded and tile/batch-sharded over a mesh, recording both
+    wall-clocks, the speedup, and the max output difference.  On an
+    accelerator it runs in-process on every device the process sees; on
+    the CPU backend it runs over N virtualized host devices (a child
+    process, ``--xla_force_host_platform_device_count``), where the
+    "speedup" mostly measures collective overhead,
   * a consistency check: compiling the Table-II-matched synthetic cifar10
     network must reproduce ``core/simulator.simulate_dataset``'s per-layer
     crossbar counts exactly (same pattern bits -> same ``map_layer``).
@@ -63,7 +64,6 @@ import json
 import os
 import subprocess
 import sys
-import textwrap
 import time
 
 import numpy as np
@@ -72,6 +72,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import timed
+from repro.compile_cache import enable_compile_cache
 from repro.obs.trace import Tracer
 from repro.core.pruning import (
     build_dictionaries,
@@ -79,13 +80,14 @@ from repro.core.pruning import (
     project_params,
 )
 from repro.core.simulator import simulate_dataset
-from repro.core.synthetic import synthesize_network
 from repro.engine import (
     CompileOptions,
     InferenceService,
     compile_network,
     make_forward,
+    partition_network,
 )
+from repro.launch.mesh import make_mesh
 from repro.serve import Request, ServingServer, classify_session
 from repro.models.cnn import (
     CNNConfig,
@@ -93,6 +95,7 @@ from repro.models.cnn import (
     conv_weight_names,
     init_cnn,
     mini_cnn_config,
+    synthetic_vgg16,
     vgg16_config,
 )
 
@@ -132,7 +135,7 @@ def _quantized_entry(cfg, params, bits, x, fp32_fn, fp32_us, rep_fp32):
     progq = compile_network(
         cfg, params, bits, options=CompileOptions(precision="int8")
     )
-    q_fn = make_forward(progq, backend="xla")
+    q_fn = make_forward(progq)
     _, q_us = timed(lambda: jax.block_until_ready(q_fn(x)), repeats=3)
     repq = progq.hardware_report()
     comp_bytes, _ = progq.weight_bytes()
@@ -191,14 +194,14 @@ def _bench_network(name: str, cfg: CNNConfig, batch: int,
             lambda: jax.block_until_ready(dense_fn(params, x)), repeats=3
         )
         prog = compile_network(cfg, params, bits)
-        eng_fn = make_forward(prog, backend="xla")
+        eng_fn = make_forward(prog)
         out_eng, eng_us = timed(
             lambda: jax.block_until_ready(eng_fn(x)), repeats=3
         )
         max_diff = float(
             jnp.abs(out_eng - dense_fn(params, x)).max()
         )
-        _, stats = make_forward(prog, backend="xla", collect_stats=True)(x)
+        _, stats = make_forward(prog, collect_stats=True)(x)
         rep = prog.hardware_report(
             skip_stats=stats, assumed_skip=ASSUMED_SKIP
         )
@@ -262,7 +265,7 @@ def _service_throughput(batch_slots: int = SERVICE_SLOTS,
     prog = compile_network(
         cfg, params, bits, options=CompileOptions(tracer=tracer)
     )
-    svc = InferenceService(prog, batch_slots=batch_slots, backend="xla",
+    svc = InferenceService(prog, batch_slots=batch_slots,
                            collect_stats=True, tracer=tracer)
     n = sum(SERVICE_BURSTS)
     images = np.array(jax.random.normal(
@@ -288,7 +291,7 @@ def _service_throughput(batch_slots: int = SERVICE_SLOTS,
     dt = time.perf_counter() - t0
 
     batches = svc.batches_run - base_batches
-    fwd = make_forward(prog, backend="xla", collect_stats=True)
+    fwd = make_forward(prog, collect_stats=True)
     out, ref_stats = fwd(jnp.asarray(images))
     jax.block_until_ready(out)
     _, fwd_us = timed(
@@ -326,7 +329,7 @@ def _service_throughput(batch_slots: int = SERVICE_SLOTS,
         # outside the timed region: one eager per-layer forward for the
         # execute-category spans, then the predicted-vs-measured drift
         # section (timing-dependent, so never baseline-gated)
-        tfwd = make_forward(prog, backend="xla", tracer=tracer)
+        tfwd = make_forward(prog, tracer=tracer)
         jax.block_until_ready(tfwd(jnp.asarray(images[:batch_slots])))
         rep = prog.hardware_report(skip_stats=svc.activation_stats,
                                    observed=tfwd.observed_times())
@@ -459,102 +462,78 @@ def _http_service_throughput(batch_slots: int = SERVICE_SLOTS) -> dict:
     return entry
 
 
-# The backend must see the forced host-device count before it initializes,
-# so the sharded comparison runs in a subprocess (same pattern as
-# tests/test_distributed.py).
-_SHARDED_BODY = """
+def _sharded_entry(n_devices: int, batch: int, sparsity: float) -> dict:
+    """Single-device vs ``(data, model) = (2, N/2)``-sharded forward of
+    the same compiled program, on the first ``n_devices`` devices."""
+    data = 2 if n_devices >= 2 else 1
+    model = n_devices // data
+    cfg = mini_cnn_config(num_classes=4, input_hw=12, widths=(8, 16, 16))
+    params, bits = _pruned(cfg, sparsity, num_patterns=8, seed=1)
+    prog = compile_network(cfg, params, bits)
+    x = jax.random.normal(jax.random.PRNGKey(0), (batch, 1, 12, 12))
+    single = make_forward(prog)
+    y1, single_us = timed(
+        lambda: jax.block_until_ready(single(x)), repeats=5
+    )
+    mesh = make_mesh((data, model), ("data", "model"),
+                     devices=jax.devices()[:n_devices])
+    sharded = make_forward(
+        partition_network(prog, data=data, model=model), mesh=mesh
+    )
+    yn, sharded_us = timed(
+        lambda: jax.block_until_ready(sharded(x)), repeats=5
+    )
+    return {
+        "devices": n_devices, "mesh": [data, model], "batch": batch,
+        "sparsity": sparsity,
+        "single_device_us": single_us, "sharded_us": sharded_us,
+        "speedup": single_us / max(sharded_us, 1e-9),
+        "max_abs_diff": float(np.abs(np.asarray(yn) - np.asarray(y1)).max()),
+    }
+
+
+# The CPU backend must see the forced host-device count before it
+# initializes, so on the CPU the sharded entry runs in a child process.
+_SHARDED_CHILD = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"
-import json, time
-import jax, numpy as np
-from repro.core.pruning import (build_dictionaries, magnitude_prune,
-                                project_params)
-from repro.engine import compile_network, make_forward, partition_network
-from repro.launch.mesh import make_mesh
-from repro.models.cnn import conv_weight_names, init_cnn, mini_cnn_config
-
-cfg = mini_cnn_config(num_classes=4, input_hw=12, widths=(8, 16, 16))
-params = init_cnn(cfg, jax.random.PRNGKey(1))
-names = conv_weight_names(cfg)
-params = magnitude_prune(params, names, {sparsity})
-dicts = build_dictionaries(params, names, 8)
-params, bits = project_params(params, dicts)
-prog = compile_network(cfg, params, bits)
-x = jax.random.normal(jax.random.PRNGKey(0), ({batch}, 1, 12, 12))
-
-
-def timed(fn, repeats=5):
-    out = jax.block_until_ready(fn())  # compile + warm
-    t0 = time.perf_counter()
-    for _ in range(repeats):
-        out = jax.block_until_ready(fn())
-    return out, (time.perf_counter() - t0) / repeats * 1e6
-
-
-single = make_forward(prog, backend="xla")
-y1, single_us = timed(lambda: single(x))
-mesh = make_mesh(({data}, {model}), ("data", "model"))
-sharded = make_forward(partition_network(prog, data={data}, model={model}),
-                       backend="xla", mesh=mesh)
-yn, sharded_us = timed(lambda: sharded(x))
-print(json.dumps({{
-    "devices": {n}, "mesh": [{data}, {model}], "batch": {batch},
-    "sparsity": {sparsity},
-    "single_device_us": single_us, "sharded_us": sharded_us,
-    "speedup": single_us / max(sharded_us, 1e-9),
-    "max_abs_diff": float(np.abs(np.asarray(yn) - np.asarray(y1)).max()),
-}}))
+import json
+from benchmarks.bench_engine import _sharded_entry
+print(json.dumps(_sharded_entry({n}, {batch}, {sparsity})))
 """
 
 
 def _sharded_throughput(n_devices: int = 4, batch: int = 8,
                         sparsity: float = 0.75) -> dict:
-    """1-vs-N-device throughput of the same compiled program (subprocess
-    with virtualized host devices; data x model mesh = 2 x N/2)."""
-    data = 2 if n_devices >= 2 else 1
-    code = textwrap.dedent(_SHARDED_BODY).format(
-        n=n_devices, data=data, model=n_devices // data,
-        batch=batch, sparsity=sparsity,
-    )
+    """1-vs-N-device throughput of the same compiled program.
+
+    On an accelerator it runs in this process over every device the
+    process sees (the process holds the chips, so a child could not get
+    them).  On the CPU backend it runs in-process when ``n_devices``
+    devices already exist, else in a child with that many virtualized
+    host devices.  A failure raises.
+    """
+    if jax.default_backend() != "cpu":
+        return _sharded_entry(jax.device_count(), batch, sparsity)
+    if jax.device_count() >= n_devices:
+        return _sharded_entry(n_devices, batch, sparsity)
+    code = _SHARDED_CHILD.format(n=n_devices, batch=batch, sparsity=sparsity)
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
     env = dict(os.environ)
-    src = os.path.abspath(
-        os.path.join(os.path.dirname(__file__), "..", "src")
-    )
     env["PYTHONPATH"] = os.pathsep.join(
-        [src] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else [])
+        [os.path.join(root, "src"), root]
+        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else [])
     )
     out = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
         env=env, timeout=600,
     )
     if out.returncode != 0:
-        return {"error": out.stderr[-2000:], "devices": n_devices}
+        raise RuntimeError(
+            f"sharded entry failed on {n_devices} virtual devices:\n"
+            f"{out.stderr[-2000:]}"
+        )
     return json.loads(out.stdout.strip().splitlines()[-1])
-
-
-def _synthetic_vgg():
-    """(cfg, params, bits) for the synthetic cifar10 VGG — the largest
-    network the bench compiles, shared by the consistency and verify
-    entries."""
-    stats, layers = synthesize_network("cifar10", seed=0)
-    cfg = vgg16_config(num_classes=10, input_hw=stats.input_hw)
-    params = {}
-    bits = {}
-    for i, layer in enumerate(layers, start=1):
-        spec = layer.spec
-        params[f"conv{i}"] = {
-            "w": jnp.asarray(
-                layer.weights.reshape(spec.c_out, spec.c_in, 3, 3)
-            ),
-            "b": jnp.zeros((spec.c_out,), jnp.float32),
-        }
-        bits[f"conv{i}"] = layer.pattern_bits
-    c_last = cfg.conv_channels[-1][1]
-    params["fc"] = {
-        "w": jnp.zeros((c_last, cfg.num_classes), jnp.float32),
-        "b": jnp.zeros((cfg.num_classes,), jnp.float32),
-    }
-    return cfg, params, bits
 
 
 def _mapping_model_entry(name: str, cfg, params, bits,
@@ -659,7 +638,7 @@ def _mapping_entry(smoke: bool) -> dict:
     params, bits = _pruned(cfg, 0.75, num_patterns=8, seed=1)
     models = [_mapping_model_entry("mini_cnn", cfg, params, bits, 0.75)]
     if not smoke:
-        vcfg, vparams, vbits = _synthetic_vgg()
+        vcfg, vparams, vbits = synthetic_vgg16("cifar10", num_classes=10)
         models.append(
             _mapping_model_entry("vgg16_cifar_synth", vcfg, vparams, vbits)
         )
@@ -680,7 +659,7 @@ def _mapping_entry(smoke: bool) -> dict:
 
 def _consistency_check() -> dict:
     """Engine hardware_report vs simulate_dataset on identical bits."""
-    cfg, params, bits = _synthetic_vgg()
+    cfg, params, bits = synthetic_vgg16("cifar10", num_classes=10)
     prog = compile_network(cfg, params, bits)
     rep = prog.hardware_report()
     sim = simulate_dataset("cifar10", seed=0)
@@ -705,7 +684,7 @@ def _verify_overhead() -> dict:
     """
     from repro.analysis.verify import verify_network
 
-    cfg, params, bits = _synthetic_vgg()
+    cfg, params, bits = synthetic_vgg16("cifar10", num_classes=10)
     compile_s = verify_s = 0.0
     errors = warnings_ = 0
     for precision in ("fp32", "int8"):
@@ -748,7 +727,7 @@ def _ranges_overhead() -> dict:
     """
     from repro.analysis.ranges import analyze_network
 
-    cfg, params, bits = _synthetic_vgg()
+    cfg, params, bits = synthetic_vgg16("cifar10", num_classes=10)
     compile_s = ranges_s = 0.0
     errors = warnings_ = 0
     deterministic = True
@@ -866,14 +845,13 @@ def run():
         f";all_ok={hs['all_ok']}"
     )
     sh = report["sharded"]
-    if "error" not in sh:
-        yield (
-            f"engine_sharded_{sh['devices']}dev,"
-            f"{sh['sharded_us']:.1f},"
-            f"single_us={sh['single_device_us']:.1f}"
-            f";speedup={sh['speedup']:.2f}"
-            f";max_diff={sh['max_abs_diff']:.1e}"
-        )
+    yield (
+        f"engine_sharded_{sh['devices']}dev,"
+        f"{sh['sharded_us']:.1f},"
+        f"single_us={sh['single_device_us']:.1f}"
+        f";speedup={sh['speedup']:.2f}"
+        f";max_diff={sh['max_abs_diff']:.1e}"
+    )
     c = report["consistency"]
     yield (
         f"engine_consistency,0.0,"
@@ -909,6 +887,7 @@ def main():
                          "chrome://tracing) of the service entry: compile "
                          "phases, per-layer forward, request lifecycles")
     args = ap.parse_args()
+    enable_compile_cache()
     tracer = Tracer() if args.trace_out else None
     report = collect(quick=args.quick, smoke=args.smoke, tracer=tracer)
     payload = json.dumps(report, indent=2)

@@ -16,6 +16,9 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import bench_engine, bench_kernels, bench_moe, \
         bench_paper, bench_roofline
 

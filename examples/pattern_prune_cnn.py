@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.mapping import map_layer, map_layer_naive
 from repro.core.pruning import PruneConfig, admm_pattern_prune, sparsity_of
 from repro.engine import (
@@ -78,6 +79,7 @@ ap.add_argument("--trace-out", default=None, metavar="FILE",
                 help="write a Chrome trace-event JSON of compile/serve "
                      "spans (open in Perfetto or chrome://tracing)")
 args = ap.parse_args()
+enable_compile_cache()
 if args.trace_out:
     from repro.obs import Tracer
 

@@ -11,13 +11,15 @@
 import numpy as np
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.indexing import build_index_stream, index_overhead_bits
 from repro.core.mapping import map_layer, map_layer_naive
 from repro.core.simulator import simulate_layer
 from repro.core.synthetic import LayerSpec, synthesize_layer
 from repro.core.sparse import block_density, build_block_pattern
-from repro.kernels.ops import pattern_spmm
+from repro.kernels.ops import default_backend, pattern_spmm
 
+enable_compile_cache()
 rng = np.random.default_rng(0)
 
 # -- 1. a pattern-pruned layer: 128 -> 256 channels, 3x3 kernels ----------
@@ -50,9 +52,7 @@ print(f"energy: {res.naive_energy_pj/res.ours_energy_pj:.2f}x  "
 w = rng.normal(size=(1024, 1024)).astype(np.float32)
 bp = build_block_pattern(w, num_patterns=8, density=0.25)
 x = jnp.asarray(rng.normal(size=(8, 1024)).astype(np.float32))
-y = pattern_spmm(x, bp, backend="xla")
-print(f"pattern_spmm: block density {block_density(bp):.2f} -> "
-      f"{1/block_density(bp):.1f}x fewer FLOPs/weight-bytes, "
-      f"output {y.shape}")
-print("(on TPU the same call dispatches the Pallas kernel "
-      "kernels/pattern_spmm.py)")
+y = pattern_spmm(x, bp)  # the Pallas kernel on a TPU, the XLA path elsewhere
+print(f"pattern_spmm ({default_backend()}): block density "
+      f"{block_density(bp):.2f} -> {1/block_density(bp):.1f}x fewer "
+      f"FLOPs/weight-bytes, output {y.shape}")

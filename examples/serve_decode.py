@@ -11,6 +11,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.models.transformer import count_params, init_params
 from repro.runtime.serve import ServeConfig, ServeLoop
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=12)
     ap.add_argument("--new-tokens", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch)
     params, _, statics = init_params(cfg, jax.random.PRNGKey(0))

@@ -32,6 +32,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.obs.trace import Tracer
 from repro.serve import ServingServer, classify_session, generate_session
 
@@ -142,6 +143,7 @@ def main():
     ap.add_argument("--check", action="store_true",
                     help="assert the serving invariants (CI smoke mode)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     tracer = Tracer() if args.trace_out else None
     if args.backend == "classify":

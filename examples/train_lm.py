@@ -14,6 +14,7 @@ import argparse
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.data.pipeline import DataConfig, packed_batches
 from repro.models.transformer import ModelConfig, count_params, init_params
 from repro.optim import adamw, linear_warmup_cosine
@@ -48,6 +49,7 @@ def main():
     ap.add_argument("--hundred-m", action="store_true")
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = small_config(args.hundred_m)
     params, _, statics = init_params(cfg, jax.random.PRNGKey(0))

@@ -376,7 +376,8 @@ def pattern_spmm_xla(
         ids, w_slot = slot  # ids: [T], w_slot: [T, block, tile]
         xg = jnp.take(xb, ids, axis=1)  # [M, T, block]
         acc = acc + jnp.einsum(
-            "mtb,tbn->mtn", xg, w_slot, preferred_element_type=jnp.float32
+            "mtb,tbn->mtn", xg, w_slot, preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,  # fp32, not one bf16 pass
         )
         return acc, None
 

@@ -2,11 +2,12 @@
 
 ``make_forward`` returns a jitted batched forward: per conv layer it
 extracts im2col patches (conv-as-spmm), dispatches through
-``kernels/ops.pattern_spmm`` (Pallas TPU kernel, interpreted Pallas or XLA
-fallback on CPU) — which applies the stored inverse output permutation
-(the Output Indexing Unit) — then bias + shared ``channel_norm``/ReLU and
-the 2x2 maxpool where the schedule says so, matching ``cnn_apply`` on the
-pruned weights to numerical tolerance.
+``kernels/ops.pattern_spmm`` (the Pallas TPU kernel on the chip; the XLA
+path, or the Pallas interpreter when asked for, on CPU) — which applies
+the stored inverse output permutation (the Output Indexing Unit) — then
+bias + shared ``channel_norm``/ReLU and the 2x2 maxpool where the
+schedule says so, matching ``cnn_apply`` on the pruned weights to
+numerical tolerance.
 
 With ``collect_stats=True`` the forward additionally counts, per layer
 and per OU row-group (= (input channel, pattern) pair), how many input
@@ -53,7 +54,6 @@ from collections import OrderedDict
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.engine.partition import pad_bp_tiles, partition_from_mesh
@@ -217,12 +217,12 @@ class _ShardedDispatch(_Dispatch):
         if quantized:
             args += (prepared.w_scales,)
             in_specs += (P(mspec),)
-        y = shard_map(
+        y = jax.shard_map(
             local,
             mesh=self.mesh,
             in_specs=in_specs,
             out_specs=P(dspec, None),
-            check_rep=False,
+            check_vma=False,
         )(*args)
         # Output Indexing Unit: global inverse permutation after the psum
         # (padded columns sit past every inv_order entry and are dropped)
@@ -249,12 +249,12 @@ class _ShardedDispatch(_Dispatch):
             # the per-sample validity rows shard with their patch rows
             args += (row_valid,)
             in_specs += (P(dspec),)
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=self.mesh,
             in_specs=in_specs,
             out_specs=P(None, None),
-            check_rep=False,
+            check_vma=False,
         )(*args)
 
 
@@ -332,7 +332,9 @@ def make_forward(
 
     Args:
       backend: 'pallas' | 'xla' | None (auto: Pallas on TPU, XLA elsewhere).
-      interpret: force Pallas interpret mode (None: auto off-TPU).
+      interpret: run the Pallas kernels in the interpreter.  Only an
+        explicit ``True`` does; ``backend='pallas'`` off the TPU without
+        it raises.
       bm: spmm row tile; None autotunes from the batch size.
       collect_stats: also measure per-layer all-zero-selection counts.
       mesh: a ``jax.sharding.Mesh`` to execute on.  Tiles split over the
@@ -368,7 +370,10 @@ def make_forward(
     ``fn.trace_count()``, the number of times the forward has been traced
     (a retrace means a new batch shape hit the jit cache), and
     ``fn.observed_times()``, the mean measured seconds per layer over the
-    instrumented calls so far (empty until a traced call ran).
+    instrumented calls so far (empty until a traced call ran), and
+    ``fn.lower(x, valid)``, the jitted forward's ``jax.jit(...).lower``
+    (inspect the compiled program; after a call at the same shapes its
+    ``compile()`` is a cache hit).
     """
     if mesh is None:
         if partition is not None:
@@ -470,6 +475,7 @@ def make_forward(
             return logits, stats
 
     fn.trace_count = lambda: traces["n"]
+    fn.lower = jitted.lower
     fn.observed_times = lambda: {
         name: total / calls for name, (calls, total) in observed.items()
     }

@@ -123,6 +123,13 @@ class InferenceService:
         sending traffic through the scheduler (metrics stay at zero)."""
         warmup_forward(self._forward, self.program, self.batch_slots)
 
+    def lower(self):
+        """``jax.jit(...).lower`` of the served forward at the fixed batch
+        shape, to read the program the device runs; after :meth:`warmup`
+        its ``compile()`` is an in-memory cache hit."""
+        x = jnp.zeros((self.batch_slots, *self._input_shape()), jnp.float32)
+        return self._forward.lower(x, jnp.zeros(self.batch_slots, bool))
+
     @property
     def metrics(self) -> dict:
         """Scheduler metrics: queue/latency/occupancy of the served load."""

@@ -1,13 +1,15 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Each op dispatches between:
-  * the Pallas TPU kernel (``backend='pallas'`` — real TPU, or
-    ``interpret=True`` on CPU for validation), and
-  * the XLA fallback (``backend='xla'``) used by the CPU dry-run, where
-    TPU Pallas kernels cannot lower.
+  * the Pallas TPU kernel (``backend='pallas'``), compiled for the TPU, or
+    run by the Pallas interpreter only when the caller passes
+    ``interpret=True`` (the CPU tests do), and
+  * the XLA path (``backend='xla'``), which runs on any platform.
 
-Dispatch default: Pallas on TPU devices, XLA elsewhere.  Shapes are padded
-to tile multiples here so kernels only see aligned sizes.
+``backend=None`` picks Pallas on TPU devices and XLA elsewhere.  Asking
+for ``backend='pallas'`` off the TPU without ``interpret=True`` raises:
+nothing silently swaps the compiled kernel for the interpreter.  Shapes
+are padded to tile multiples here so kernels only see aligned sizes.
 """
 
 from __future__ import annotations
@@ -41,6 +43,24 @@ __all__ = [
 
 def default_backend() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "xla"
+
+
+def _interpret_flag(interpret: bool | None) -> bool:
+    """Whether a Pallas call runs interpreted; refuses to guess off-TPU.
+
+    ``interpret=None`` means the compiled kernel, which only the TPU can
+    run.  Elsewhere the caller must ask for the interpreter explicitly.
+    """
+    if interpret:
+        return True
+    platform = jax.default_backend()
+    if platform != "tpu":
+        raise ValueError(
+            f"backend='pallas' needs a TPU, but JAX runs on {platform!r}; "
+            "pass interpret=True to run the Pallas interpreter, or use "
+            "backend='xla'"
+        )
+    return False
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -97,9 +117,7 @@ def pattern_spmm_raw(
     if quant:
         xq, x_scale = quantize_rows(xm)
     if backend == "pallas":
-        interp = (
-            interpret if interpret is not None else jax.default_backend() != "tpu"
-        )
+        interp = _interpret_flag(interpret)
         xin = xq if quant else xm
         m = xin.shape[0]
         if bm is None:
@@ -173,9 +191,7 @@ def flash_attention(
     kf = k.reshape(b * hq, sk, d)
     vf = v.reshape(b * hq, sk, d)
     if backend == "pallas":
-        interp = (
-            interpret if interpret is not None else jax.default_backend() != "tpu"
-        )
+        interp = _interpret_flag(interpret)
         qp = _pad_to(qf, 1, bq)
         kp = _pad_to(kf, 1, bk)
         vp = _pad_to(vf, 1, bk)

@@ -32,23 +32,8 @@ __all__ = ["HLOStats", "parse_hlo_stats", "cost_analysis_dict"]
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict across jax versions.
-
-    Older jax returns a per-device list of dicts (usually length 1; summed
-    here so 'flops' stays the per-program total), newer jax returns the
-    dict directly.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, dict):
-        return cost
-    out: dict = {}
-    for entry in cost or []:
-        for k, v in entry.items():
-            if isinstance(v, (int, float)):
-                out[k] = out.get(k, 0.0) + v
-            else:
-                out.setdefault(k, v)
-    return out
+    """``compiled.cost_analysis()``: a flat dict (empty when unavailable)."""
+    return compiled.cost_analysis() or {}
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

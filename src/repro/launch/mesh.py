@@ -9,31 +9,24 @@ Multi-pod:  (pod=2, data=16, model=16) — 512 chips; the pod axis is pure
 data parallelism (gradient all-reduce crosses DCN), which is also where
 gradient compression applies.
 
-``make_mesh`` is the version-portable constructor every caller (and test)
-should use: newer jax grew ``jax.sharding.AxisType`` and a required-ish
-``axis_types`` kwarg on ``jax.make_mesh``, older jax has neither.
+``make_mesh`` is the constructor every caller (and test) uses: it builds
+meshes whose axes are all ``AxisType.Auto``.
 """
 
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 explicit-sharding API
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # jax <= 0.4.x: meshes are implicitly 'auto'
-    _AxisType = None
+from jax.sharding import AxisType
 
 __all__ = ["make_mesh", "make_production_mesh", "make_local_mesh"]
 
 
 def make_mesh(shape, axes, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types where the API has them."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if _AxisType is not None:
-        kwargs["axis_types"] = (_AxisType.Auto,) * len(axes)
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), devices=devices,
+        axis_types=(AxisType.Auto,) * len(axes),
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -222,7 +222,6 @@ def _flash_decode_sharded(
     running max, psum of the rescaled denominators and weighted values.
     Replaces the O(cache-bytes) all-gather the GSPMD baseline emits with
     O(B*H*D) combine traffic."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, hq, _, dh = q.shape
@@ -260,12 +259,12 @@ def _flash_decode_sharded(
         o_glob = jax.lax.psum(o_loc, "model")
         return (o_glob / jnp.maximum(l_glob, 1e-30)).astype(qb.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(bspec), P(bspec, "model"), P(bspec, "model"), P()),
         out_specs=P(bspec),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, kv_len)
 
 

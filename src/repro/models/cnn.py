@@ -14,12 +14,14 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core.synthetic import VGG16_CONV_CHANNELS
+from repro.core.synthetic import TABLE_II, VGG16_CONV_CHANNELS, synthesize_network
 
 __all__ = [
     "CNNConfig",
     "vgg16_config",
+    "synthetic_vgg16",
     "mini_cnn_config",
     "init_cnn",
     "cnn_apply",
@@ -49,6 +51,38 @@ def vgg16_config(num_classes: int = 10, input_hw: int = 32) -> CNNConfig:
         num_classes=num_classes,
         input_hw=input_hw,
     )
+
+
+def synthetic_vgg16(
+    dataset: str, seed: int = 0, num_classes: int = 1000
+) -> tuple[CNNConfig, dict, dict]:
+    """Pattern-pruned VGG16-D at ``dataset``'s input size, from a seed.
+
+    The conv weights and pattern bits are ``core/synthetic``'s Table II
+    match for ``dataset``; the FC head is seeded Gaussian (``init_cnn``'s
+    scale), so logits and their argmax carry information.  Returns
+    ``(cfg, params, pattern_bits)`` for ``compile_network``.
+    """
+    _, layers = synthesize_network(dataset, seed=seed)
+    cfg = vgg16_config(num_classes, TABLE_II[dataset].input_hw)
+    params, bits = {}, {}
+    for i, layer in enumerate(layers, start=1):
+        spec = layer.spec
+        params[f"conv{i}"] = {
+            "w": jnp.asarray(layer.weights.reshape(spec.c_out, spec.c_in, 3, 3)),
+            "b": jnp.zeros((spec.c_out,), jnp.float32),
+        }
+        bits[f"conv{i}"] = layer.pattern_bits
+    feat = cfg.conv_channels[-1][1]
+    rng = np.random.default_rng([seed, 1])
+    params["fc"] = {
+        "w": jnp.asarray(
+            rng.normal(0.0, np.sqrt(1.0 / feat), (feat, num_classes)),
+            jnp.float32,
+        ),
+        "b": jnp.zeros((num_classes,), jnp.float32),
+    }
+    return cfg, params, bits
 
 
 def mini_cnn_config(

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.models.layers import linear, linear_init, mlp_apply, mlp_init
 
@@ -169,12 +168,12 @@ def _moe_shard_map(params, cfg: MoEConfig, x: jax.Array, mesh) -> jax.Array:
         return out.reshape(bl, s, d)
 
     espec = P("model") if has_model else P()
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), {k: espec for k in params["experts"]}, P(dp)),
         out_specs=P(dp),
-        check_rep=False,
+        check_vma=False,
     )(params["router"], params["experts"], x)
 
 

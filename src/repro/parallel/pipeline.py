@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["pipeline_apply"]
@@ -94,7 +93,7 @@ def pipeline_apply(
         return outs
 
     in_specs = (P(axis), P())
-    return shard_map(
+    return jax.shard_map(
         pipe, mesh=mesh, in_specs=in_specs, out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stacked_params, x_micro)
