@@ -1,0 +1,5 @@
+"""Images completed in the window over the window's length (host clock)."""
+
+
+def read(run):
+    return run.images / run.window_s if run.window_s > 0 else None
