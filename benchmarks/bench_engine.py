@@ -41,9 +41,9 @@ Usage:
 
 ``--trace-out`` additionally records the service entry on a span tracer
 (``repro.obs``) and writes a Chrome trace-event JSON — load it in
-Perfetto or chrome://tracing to see compile phases, per-layer forward
-spans, and all 100 request lifecycles on one timeline; the service
-entry then also carries the predicted-vs-measured ``drift`` section.
+Perfetto or chrome://tracing to see compile phases, the service's
+``service.*`` step phases, and all 100 request lifecycles on one
+timeline.
 
 ``--smoke`` is the CI bench-regression configuration: mini-CNN only, one
 sparsity level, a 2-device sharded entry — small enough for every PR, but
@@ -254,11 +254,9 @@ def _service_throughput(batch_slots: int = SERVICE_SLOTS,
     machine speed cancels, so the baseline can gate it loosely).
 
     With a ``tracer`` (``--trace-out``) the same run also lands on the
-    shared timeline: compile-phase spans, the per-request lifecycles of
-    all 100 bursty-trace requests, and — after the timed region, so the
-    throughput numbers stay clean — one instrumented per-layer forward
-    whose measured wall-times feed a non-gated predicted-vs-measured
-    ``drift`` section (``hardware_report(observed=...)``).
+    shared timeline: compile-phase spans, each batch's ``service.step``
+    span with its refill/dispatch/wait/complete phases, and the
+    per-request lifecycles of all 100 bursty-trace requests.
     """
     cfg = mini_cnn_config(num_classes=4, input_hw=12, widths=(8, 16, 16))
     params, bits = _pruned(cfg, 0.75, num_patterns=8, seed=1)
@@ -325,15 +323,6 @@ def _service_throughput(batch_slots: int = SERVICE_SLOTS,
         / max(fwd_us, 1e-9),
         "stats_exact": stats_exact,
     }
-    if tracer is not None:
-        # outside the timed region: one eager per-layer forward for the
-        # execute-category spans, then the predicted-vs-measured drift
-        # section (timing-dependent, so never baseline-gated)
-        tfwd = make_forward(prog, tracer=tracer)
-        jax.block_until_ready(tfwd(jnp.asarray(images[:batch_slots])))
-        rep = prog.hardware_report(skip_stats=svc.activation_stats,
-                                   observed=tfwd.observed_times())
-        entry["drift"] = rep["drift"]
     return entry
 
 

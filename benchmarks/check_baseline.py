@@ -57,9 +57,9 @@ With ``--trace FILE`` the Chrome trace-event artifact written by
 ``bench_engine --trace-out`` is validated too: it must parse, every
 event must carry the trace-event schema fields (``ph``/``ts``/``pid``/
 ``tid``/``name``, ``dur`` on complete spans), and it must contain
-compile-phase spans, per-layer executor spans, the begin/end async
-events of all 100 bursty-trace request lifecycles, and one admit
-instant per lifecycle.  ``--require-mid-decode`` additionally demands
+compile-phase spans, the service's step spans with their four phases,
+the begin/end async events of all 100 bursty-trace request lifecycles,
+and one admit instant per lifecycle.  ``--require-mid-decode`` additionally demands
 ``admit_mid_decode`` instants — the CI serving-smoke job runs
 ``examples/serve_http.py --backend generate --trace-out`` and validates
 that artifact here with the report arguments omitted (trace-only mode).
@@ -376,6 +376,11 @@ def compare(current, baseline, time_tol, top1_slack) -> Checker:
 # the smoke service entry drains the fixed 100-request bursty trace, so
 # the artifact must carry at least that many request lifecycles
 MIN_REQUEST_SPANS = 100
+# InferenceService.step: one span per batch and one per phase inside it
+SERVICE_STEP_SPANS = (
+    "service.step", "service.refill", "service.dispatch", "service.wait",
+    "service.complete",
+)
 
 
 def check_trace(c: Checker, path: str,
@@ -413,7 +418,7 @@ def check_trace(c: Checker, path: str,
     spans = [e for e in events if e["ph"] == "X"]
     if require_mid_decode:
         # a generation serving trace: decode/prefill step spans instead
-        # of the bench trace's compile + per-layer executor spans
+        # of the bench trace's compile + classification step spans
         decode_spans = [
             e for e in spans
             if e.get("cat") == "serve" and e["name"] == "serve.decode"
@@ -428,15 +433,11 @@ def check_trace(c: Checker, path: str,
             bool(compile_spans),
             "trace: no compile-phase spans (ph=X, cat=compile)",
         )
-        layer_spans = [
-            e
-            for e in spans
-            if e.get("cat") == "execute" and e["name"].startswith("layer:")
-        ]
+        serve_names = {e["name"] for e in spans if e.get("cat") == "serve"}
+        missing = sorted(set(SERVICE_STEP_SPANS) - serve_names)
         c.check(
-            bool(layer_spans),
-            "trace: no per-layer executor spans "
-            "(ph=X, cat=execute, layer:*)",
+            not missing,
+            f"trace: no service step spans {missing} (ph=X, cat=serve)",
         )
     begins = [e for e in events if e["ph"] == "b" and e.get("cat") == "request"]
     ends = [e for e in events if e["ph"] == "e" and e.get("cat") == "request"]

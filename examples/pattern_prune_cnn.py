@@ -32,11 +32,10 @@ engine on a synthetic eval batch.  ``--precision fp32`` skips step 8;
 int8 numbers (e.g. 2-bit cells -> 4 slices -> more area, same accuracy).
 
 ``--trace-out trace.json`` records steps 5+ on a span tracer
-(``repro.obs``): compile phases, per-layer eager forward timings (which
-also feed a predicted-vs-measured drift report), and the served
-requests' lifecycles.  The script prints the top-3 slowest compile
-phases and layers, and the written file loads in Perfetto or
-chrome://tracing.
+(``repro.obs``): compile phases, the served batches' ``service.*`` step
+phases, and the served requests' lifecycles.  The script prints the
+top-3 slowest compile phases and step phases, and the written file loads
+in Perfetto or chrome://tracing.
 """
 
 import argparse
@@ -304,27 +303,19 @@ if args.precision != "fp32":
           f"({rep['energy_pj']/max(rep_q['energy_pj'],1e-9):.2f}x win)")
 
 # -- observability epilogue: where the time actually went --------------------
-# The instrumented forward runs the layers eagerly, one span each, so the
-# measured wall-times can sit next to the simulator's predicted cycles
-# (hardware_report's drift section) and the slowest compile phases /
-# layers fall straight out of the collected spans.
+# The slowest compile phases and serving-step phases fall straight out of
+# the collected spans.
 if tracer is not None:
-    fwd_tr = make_forward(program, tracer=tracer)
-    jax.block_until_ready(fwd_tr(x))
-    drift = program.hardware_report(observed=fwd_tr.observed_times())["drift"]
-    print(f"[{time.time()-t0:5.1f}s] predicted-vs-measured drift over "
-          f"{len(drift['layers'])} layers: "
-          f"max |share drift| {drift['max_abs_share_drift']:.1%}, "
-          f"rate spread {drift['rate_spread']:.1f}x")
     PHASES = ("prune", "reorder", "pack", "quantize")
     top_phases = [(n, s) for n, s in tracer.slowest(16, cat="compile")
                   if n in PHASES][:3]
     print("  top-3 compile phases: "
           + ", ".join(f"{n} {s*1e3:.1f} ms" for n, s in top_phases))
-    top_layers = tracer.slowest(3, cat="execute", prefix="layer:")
-    print("  top-3 layers:         "
-          + ", ".join(f"{n.removeprefix('layer:')} {s*1e3:.1f} ms"
-                      for n, s in top_layers))
+    top_steps = [(n, s) for n, s in tracer.slowest(8, cat="serve")
+                 if n != "service.step"][:3]
+    print("  top-3 step phases:    "
+          + ", ".join(f"{n.removeprefix('service.')} {s*1e3:.1f} ms"
+                      for n, s in top_steps))
     tracer.write(args.trace_out)
     print(f"  wrote {args.trace_out} (open in Perfetto / chrome://tracing)")
 

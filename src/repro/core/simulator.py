@@ -366,7 +366,7 @@ def drift_table(
 ) -> dict:
     """Predicted-vs-measured cost drift across layers.
 
-    The simulator predicts per-layer *cycles*; the instrumented executor
+    The simulator predicts per-layer *cycles*; a profile of the executor
     measures per-layer *seconds* — incommensurable units, so the honest
     comparison is each layer's **share** of the network total: a perfect
     cost model assigns every layer the same fraction of predicted cycles

@@ -20,27 +20,29 @@ statistics — so the forward traces exactly once for any traffic pattern.
 
 Observability
 -------------
-The whole stack is instrumented through ``repro.obs`` — pure stdlib,
-opt-in, and free when off (``tracer=None`` resolves to a shared no-op
-tracer; the jitted forward is byte-identical either way).
+The whole stack is instrumented through ``repro.obs`` — pure stdlib and
+free when off (``tracer=None`` resolves to a shared no-op tracer; a
+tracer never reaches the jitted forward).
 
 * **Tracing.** Pass one ``obs.Tracer`` through the layers you care
   about: ``compile_network(..., tracer=tr)`` records the lowering
   phases (``prune -> reorder -> pack -> quantize`` under per-layer
-  ``lower:<name>`` spans), ``make_forward(..., tracer=tr)`` switches to
-  an eager per-layer instrumented forward (``layer:*`` spans with real
-  wall-times — profile with it, serve without it), and
-  ``InferenceService(..., tracer=tr)`` emits per-request async
-  lifecycles (enqueue ``b`` -> admit ``n`` -> done ``e``) plus
-  queue-depth/slot-occupancy counter tracks.  ``tr.write("trace.json")``
-  produces Chrome trace-event JSON — load it in Perfetto or
-  chrome://tracing to see compile, execute, and serve on one timeline.
-* **Predicted-vs-measured drift.** The instrumented forward's
-  ``fn.observed_times()`` (layer -> mean seconds) feeds
-  ``CompiledNetwork.hardware_report(observed=...)``, which then carries
-  a ``drift`` section comparing each layer's *share* of measured wall
-  time against its share of predicted crossbar cycles — the simulator's
-  cost model audited against the executing engine.
+  ``lower:<name>`` spans), and ``InferenceService(..., tracer=tr)``
+  emits per-request async lifecycles (enqueue ``b`` -> admit ``n`` ->
+  done ``e``) plus queue-depth/slot-occupancy counter tracks.
+  ``tr.write("trace.json")`` produces Chrome trace-event JSON — load it
+  in Perfetto or chrome://tracing to see compile and serve on one
+  timeline.
+* **Profiles.** Under ``jax.profiler`` every span (the no-op tracer's
+  too) is also a host event on the profiler's clock — each serving step
+  is ``service.step`` with ``service.refill`` / ``dispatch`` / ``wait``
+  / ``complete`` children — and the jitted forward's device operations
+  carry ``<layer>/<stage>`` names (``make_forward``), so device time
+  and idle gaps can be attributed to layers, stages and host phases.
+  Per-layer seconds read from such a profile feed
+  ``CompiledNetwork.hardware_report(observed=...)``, whose ``drift``
+  section compares each layer's *share* of measured time against its
+  share of predicted crossbar cycles.
 * **Metrics.** ``SchedulerMetrics.snapshot()`` includes
   histogram-backed ``latency_p50_s``/``latency_p99_s`` and the
   queue-wait vs in-flight latency breakdown;

@@ -66,10 +66,14 @@ from repro.engine.stats import (
 from repro.kernels.ops import pattern_spmm, pattern_spmm_raw
 from repro.kernels.ops import _pad_to as _pad_axis_to_mult
 from repro.models.cnn import channel_norm, max_pool_2x2
-from repro.obs.trace import Tracer
 from repro.parallel.sharding import shard_block_pattern
 
-__all__ = ["extract_patches", "make_forward", "warmup_forward", "execute"]
+__all__ = [
+    "STAGES", "extract_patches", "make_forward", "warmup_forward", "execute",
+]
+
+# the stages of a layer, as the forward's ``jax.named_scope``s name them
+STAGES = ("patches", "spmm", "permute", "epilogue", "stats")
 
 
 def extract_patches(x: jax.Array, k: int) -> jax.Array:
@@ -217,17 +221,19 @@ class _ShardedDispatch(_Dispatch):
         if quantized:
             args += (prepared.w_scales,)
             in_specs += (P(mspec),)
-        y = jax.shard_map(
-            local,
-            mesh=self.mesh,
-            in_specs=in_specs,
-            out_specs=P(dspec, None),
-            check_vma=False,
-        )(*args)
+        with jax.named_scope("spmm"):
+            y = jax.shard_map(
+                local,
+                mesh=self.mesh,
+                in_specs=in_specs,
+                out_specs=P(dspec, None),
+                check_vma=False,
+            )(*args)
         # Output Indexing Unit: global inverse permutation after the psum
         # (padded columns sit past every inv_order entry and are dropped)
-        y = jnp.take(y, jnp.asarray(bp.inv_order), axis=1)
-        return y.astype(x2d.dtype)
+        with jax.named_scope("permute"):
+            y = jnp.take(y, jnp.asarray(bp.inv_order), axis=1)
+            return y.astype(x2d.dtype)
 
     def counts(self, patches, c_in, kk, masks, row_valid=None) -> jax.Array:
         part = self.part
@@ -267,23 +273,28 @@ def _run_conv(
     valid: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array | None]:
     b, c, h, w = x.shape
-    patches = extract_patches(x, op.kernel)  # [B, H, W, C*k*k]
-    patches = patches.reshape(b * h * w, -1)
+    with jax.named_scope("patches"):
+        patches = extract_patches(x, op.kernel)  # [B, H, W, C*k*k]
+        patches = patches.reshape(b * h * w, -1)
     counts = None
     if stat_masks is not None:
-        # every patch row belongs to one sample; dead-slot samples are
-        # excluded from the skip counters
-        row_valid = None if valid is None else jnp.repeat(valid, h * w)
-        counts = disp.counts(
-            patches, op.c_in, op.kernel * op.kernel, stat_masks, row_valid
-        )
-    patches = _pad_features(patches, op.bp.k_in)
-    y = disp.spmm(patches, op.bp, prepared)
-    y = y[:, : op.c_out] + jnp.asarray(op.bias)
-    y = y.reshape(b, h, w, op.c_out).transpose(0, 3, 1, 2)
-    y = jax.nn.relu(channel_norm(y))
-    if op.pool_after:
-        y = max_pool_2x2(y)
+        with jax.named_scope("stats"):
+            # every patch row belongs to one sample; dead-slot samples
+            # are excluded from the skip counters
+            row_valid = None if valid is None else jnp.repeat(valid, h * w)
+            counts = disp.counts(
+                patches, op.c_in, op.kernel * op.kernel, stat_masks,
+                row_valid,
+            )
+    with jax.named_scope("patches"):
+        patches = _pad_features(patches, op.bp.k_in)
+    y = disp.spmm(patches, op.bp, prepared)  # scopes spmm, permute
+    with jax.named_scope("epilogue"):
+        y = y[:, : op.c_out] + jnp.asarray(op.bias)
+        y = y.reshape(b, h, w, op.c_out).transpose(0, 3, 1, 2)
+        y = jax.nn.relu(channel_norm(y))
+        if op.pool_after:
+            y = max_pool_2x2(y)
     return y, counts
 
 
@@ -293,9 +304,11 @@ def _run_fc(
     disp: _Dispatch,
     prepared,
 ) -> jax.Array:
-    xf = _pad_features(x, op.bp.k_in)
-    y = disp.spmm(xf, op.bp, prepared)
-    return y[:, : op.d_out] + jnp.asarray(op.bias)
+    with jax.named_scope("patches"):
+        xf = _pad_features(x, op.bp.k_in)
+    y = disp.spmm(xf, op.bp, prepared)  # scopes spmm, permute
+    with jax.named_scope("epilogue"):
+        return y[:, : op.d_out] + jnp.asarray(op.bias)
 
 
 def _layer_windows(
@@ -326,7 +339,6 @@ def make_forward(
     collect_stats: bool = False,
     mesh=None,
     partition=None,
-    tracer: Tracer | None = None,
 ):
     """Build the jitted batched forward for ``program``.
 
@@ -344,19 +356,6 @@ def make_forward(
       partition: explicit :class:`~repro.engine.partition.NetworkPartition`
         (defaults to ``program.partition``, else derived from the mesh);
         validated against the mesh's axis sizes.
-      tracer: optional span tracer (``obs/trace.py``).  With an *enabled*
-        tracer, calls run an **instrumented** layer-by-layer path: each
-        layer's dispatch is wrapped in a ``layer:<name>`` span and
-        blocked on (``block_until_ready``), so the span durations are
-        real per-layer wall times, accumulated and exposed via
-        ``fn.observed_times()`` — the measured side of
-        ``hardware_report(observed=...)``'s predicted-vs-measured drift
-        table.  The instrumented path computes the same numbers but is
-        *not* the jitted whole-forward (per-layer blocking defeats
-        op fusion across layers); use it to profile, not to serve.
-        With ``tracer=None`` (or a disabled tracer) the historical jitted
-        path runs byte-identically: no extra jit inputs, no clock reads,
-        ``fn.trace_count()`` unchanged.
 
     Returns: fn(x: [B, C, H, W], valid=None) -> logits [B, num_classes],
     or, with ``collect_stats``, fn(x, valid=None) ->
@@ -369,11 +368,28 @@ def make_forward(
     dropped by the caller.  The returned callable exposes
     ``fn.trace_count()``, the number of times the forward has been traced
     (a retrace means a new batch shape hit the jit cache), and
-    ``fn.observed_times()``, the mean measured seconds per layer over the
-    instrumented calls so far (empty until a traced call ran), and
     ``fn.lower(x, valid)``, the jitted forward's ``jax.jit(...).lower``
     (inspect the compiled program; after a call at the same shapes its
     ``compile()`` is a cache hit).
+
+    Names on the device: every operation the forward traces sits under
+    the ``jax.named_scope`` pair ``<layer>/<stage>``, so its ``op_name``
+    metadata reads ``jit(forward)/<layer>/<stage>/...``.  ``<layer>`` is
+    the conv's ``op.name`` (``conv1`` ...), then ``gap`` and ``fc``;
+    ``<stage>`` is one of :data:`STAGES`:
+
+    * ``patches`` — the SAME pad, tap slices and stack, the NCHW->NHWC
+      transpose and reshape (im2col), and the K padding;
+    * ``spmm`` — the row padding, the ``pattern_spmm*`` kernel (or the
+      XLA path) and the row slice; sharded, the scatter and ``psum``;
+    * ``permute`` — the inverse output permutation (Output Indexing
+      Unit);
+    * ``epilogue`` — the column slice, bias, NHWC->NCHW transpose,
+      ``channel_norm``, ReLU and max-pool; for ``gap``, the mean;
+    * ``stats`` — the ``collect_stats`` skip counters.
+
+    Profiles attribute device time by these names; they are a contract.
+    Scopes change metadata only, never the compiled instructions.
     """
     if mesh is None:
         if partition is not None:
@@ -400,72 +416,32 @@ def make_forward(
         traces["n"] += 1  # python side effect: runs once per trace
         counts = {}
         for op in program.convs:
-            x, cnt = _run_conv(
-                op, x, disp, prepared[op.name], stat_masks.get(op.name),
-                valid,
-            )
+            with jax.named_scope(op.name):
+                x, cnt = _run_conv(
+                    op, x, disp, prepared[op.name], stat_masks.get(op.name),
+                    valid,
+                )
             if cnt is not None:
                 counts[op.name] = cnt
-        x = x.mean(axis=(2, 3))  # global average pool
-        logits = _run_fc(program.fc, x, disp, prepared["fc"])
+        with jax.named_scope("gap"), jax.named_scope("epilogue"):
+            x = x.mean(axis=(2, 3))  # global average pool
+        with jax.named_scope("fc"):
+            logits = _run_fc(program.fc, x, disp, prepared["fc"])
         return (logits, counts) if collect_stats else logits
 
     jitted = jax.jit(forward)
-
-    # per-layer wall time accumulated by the instrumented (traced) path:
-    # name -> [calls, total seconds on the tracer's clock]
-    observed: dict[str, list] = {}
-
-    def _observe(name: str, seconds: float) -> None:
-        acc = observed.setdefault(name, [0, 0.0])
-        acc[0] += 1
-        acc[1] += seconds
-
-    def instrumented(x: jax.Array, valid: jax.Array | None):
-        """Eager layer-by-layer forward: same math, spans + blocking per
-        layer so each span's duration is that layer's real wall time."""
-        with tracer.span(
-            "forward", cat="execute", batch=int(x.shape[0])
-        ) as fsp:
-            counts = {}
-            for op in program.convs:
-                with tracer.span(
-                    f"layer:{op.name}", cat="execute", op="conv"
-                ) as sp:
-                    x, cnt = _run_conv(
-                        op, x, disp, prepared[op.name],
-                        stat_masks.get(op.name), valid,
-                    )
-                    x = jax.block_until_ready(x)
-                _observe(op.name, sp.dur)
-                if cnt is not None:
-                    counts[op.name] = cnt
-            with tracer.span("layer:gap", cat="execute", op="pool"):
-                x = jax.block_until_ready(x.mean(axis=(2, 3)))
-            with tracer.span("layer:fc", cat="execute", op="fc") as sp:
-                logits = jax.block_until_ready(
-                    _run_fc(program.fc, x, disp, prepared["fc"])
-                )
-            _observe("fc", sp.dur)
-            fsp.args["layers"] = len(program.convs) + 2
-        return (logits, counts) if collect_stats else logits
-
-    def _dispatch(x, valid):
-        if tracer is not None and tracer.enabled:
-            return instrumented(x, valid)
-        return jitted(x, valid)
 
     def _as_valid(valid):
         return None if valid is None else jnp.asarray(valid, bool)
 
     if not collect_stats:
         def fn(x: jax.Array, valid=None) -> jax.Array:
-            return _dispatch(x, _as_valid(valid))
+            return jitted(x, _as_valid(valid))
     else:
         def fn(
             x: jax.Array, valid=None
         ) -> tuple[jax.Array, ActivationStats]:
-            logits, counts = _dispatch(x, _as_valid(valid))
+            logits, counts = jitted(x, _as_valid(valid))
             live = None if valid is None else int(np.asarray(valid).sum())
             stats = stats_from_counts(
                 program.convs,
@@ -476,9 +452,6 @@ def make_forward(
 
     fn.trace_count = lambda: traces["n"]
     fn.lower = jitted.lower
-    fn.observed_times = lambda: {
-        name: total / calls for name, (calls, total) in observed.items()
-    }
     return fn
 
 

@@ -370,8 +370,9 @@ class CompiledNetwork:
         when that layer was.
 
         ``observed`` maps layer names to *measured* per-layer seconds —
-        the ``fn.observed_times()`` of a tracer-instrumented
-        ``make_forward`` — and adds a ``drift`` section
+        e.g. the device time of each ``<layer>/*`` scope in a profile of
+        the jitted forward (``make_forward``) — and adds a ``drift``
+        section
         (``core/simulator.drift_table``): each layer's share of total
         predicted cycles vs its share of measured wall time, the
         per-layer drift between the two, and the implied

@@ -62,6 +62,11 @@ class SchedulerMetrics:
     (``obs/metrics.Histogram``) and broken down into queue wait
     (enqueue->admit, recorded at admission over ``admitted`` requests)
     vs in-flight time (admit->done, recorded at completion).
+
+    A front end that times its steps (``InferenceService.step``) adds one
+    sample per step to ``step_host_hist`` — the host's own work: refill,
+    dispatch and completion — and to ``step_wait_hist``, the wait for the
+    device's result, both in seconds.
     """
 
     batch_slots: int
@@ -84,6 +89,12 @@ class SchedulerMetrics:
         default_factory=_latency_hist, repr=False, compare=False
     )
     first_result_hist: Histogram = dataclasses.field(
+        default_factory=_latency_hist, repr=False, compare=False
+    )
+    step_host_hist: Histogram = dataclasses.field(
+        default_factory=_latency_hist, repr=False, compare=False
+    )
+    step_wait_hist: Histogram = dataclasses.field(
         default_factory=_latency_hist, repr=False, compare=False
     )
 
@@ -139,6 +150,11 @@ class SchedulerMetrics:
         self.first_result_sum += latency
         self.first_result_hist.observe(latency)
 
+    def record_step_times(self, host_s: float, wait_s: float) -> None:
+        """One step's host time and device wait, in seconds."""
+        self.step_host_hist.observe(host_s)
+        self.step_wait_hist.observe(wait_s)
+
     def record_complete(self, latency: float, in_flight: float) -> None:
         self.completed += 1
         self.latency_sum += latency
@@ -193,6 +209,8 @@ class SchedulerMetrics:
                 f"{prefix}_first_result_seconds"
             )
         )
+        lines.extend(self.step_host_hist.prom_lines(f"{prefix}_step_host_seconds"))
+        lines.extend(self.step_wait_hist.prom_lines(f"{prefix}_step_wait_seconds"))
         return "\n".join(lines) + "\n"
 
 
@@ -404,6 +422,12 @@ class SlotScheduler:
             live = sum(1 for s in self._slots if s is not None)
             self.metrics.occupancy_sum += live
             self._tracer.counter("scheduler/slots_live", live=live)
+
+    def record_step_times(self, host_s: float, wait_s: float) -> None:
+        """Record one step's host time (refill + dispatch + completion)
+        and its wait for the device's result, in seconds."""
+        with self._lock:
+            self.metrics.record_step_times(host_s, wait_s)
 
     def record_first_result(self, slot: int) -> None:
         """Record the enqueue->first-result latency for ``slot`` (e.g.

@@ -85,11 +85,12 @@ class InferenceService:
 
         ``tracer`` puts the service on a shared Perfetto timeline: every
         request becomes an async span (enqueue -> admit -> done, via the
-        scheduler), each executed batch a ``service.step`` span, and
-        queue depth / live slots counter tracks.  The tracer is *not*
-        handed to the jitted forward — serving always runs the
-        single-trace jitted path; use a separate tracer-instrumented
-        ``make_forward`` for per-layer timings.
+        scheduler), and queue depth / live slots counter tracks.  Each
+        executed batch is a ``service.step`` span whatever the tracer
+        (see :meth:`step`).  The tracer is *not* handed to the jitted
+        forward — serving always runs the single-trace jitted path, whose
+        per-layer device time a profile names by its ``<layer>/<stage>``
+        scopes (``make_forward``).
         """
         self.program = program
         self.batch_slots = batch_slots
@@ -100,6 +101,7 @@ class InferenceService:
             collect_stats=collect_stats, mesh=mesh, partition=partition,
         )
         self._tracer = tracer or NULL_TRACER
+        self._clock = clock
         self.scheduler = SlotScheduler(
             batch_slots, max_queue=max_queue, clock=clock, tracer=tracer
         )
@@ -176,33 +178,48 @@ class InferenceService:
         """Refill free slots from the queue and run one fixed-shape batch.
 
         Returns the requests completed by this batch (empty when there
-        was nothing to serve).
+        was nothing to serve).  A batch is a ``service.step`` span with
+        four children, on the profiler's timeline whatever the tracer:
+        ``service.refill`` (admission and the slot copies),
+        ``service.dispatch`` (the host-to-device copy and the forward's
+        launch; with ``collect_stats`` also the counters' fetch),
+        ``service.wait`` (``device_get`` of the logits) and
+        ``service.complete`` (the result scatter and the slot zeroing).
+        The scheduler records refill + dispatch + complete as the step's
+        host time and the wait as its wait time.
         """
         sched = self.scheduler
-        for slot, req in sched.refill():
-            self._slots_x[slot] = req.image
-        valid = sched.valid_mask()
-        if not valid.any():
+        if not sched.has_work():
             return []
-        with self._tracer.span(
-            "service.step", cat="serve", live=int(valid.sum()),
-            batch_slots=self.batch_slots,
-        ):
-            out = self._forward(jnp.asarray(self._slots_x), valid)
-            if self.collect_stats:
-                out, stats = out
-                self._record_stats(stats)
-            logits = np.asarray(jax.device_get(out))
-        self.batches_run += 1
-        sched.record_step()
-        finished = []
-        for slot, req in sched.live():
-            req.logits = logits[slot]
-            req.label = int(np.argmax(logits[slot]))
-            req.done = True
-            sched.complete(slot)
-            self._slots_x[slot] = 0.0  # dead slots stay zero-padded
-            finished.append(req)
+        span, clock = self._tracer.span, self._clock
+        t0 = clock()
+        with span("service.step", cat="serve", batch_slots=self.batch_slots):
+            with span("service.refill", cat="serve"):
+                for slot, req in sched.refill():
+                    self._slots_x[slot] = req.image
+                valid = sched.valid_mask()
+            with span("service.dispatch", cat="serve", live=int(valid.sum())):
+                out = self._forward(jnp.asarray(self._slots_x), valid)
+                if self.collect_stats:
+                    out, stats = out
+                    self._record_stats(stats)
+            t1 = clock()
+            with span("service.wait", cat="serve"):
+                logits = np.asarray(jax.device_get(out))
+            t2 = clock()
+            with span("service.complete", cat="serve"):
+                self.batches_run += 1
+                sched.record_step()
+                finished = []
+                for slot, req in sched.live():
+                    req.logits = logits[slot]
+                    req.label = int(np.argmax(logits[slot]))
+                    req.done = True
+                    sched.complete(slot)
+                    self._slots_x[slot] = 0.0  # dead slots stay zero-padded
+                    finished.append(req)
+            t3 = clock()
+        sched.record_step_times((t1 - t0) + (t3 - t2), t2 - t1)
         return finished
 
     def run(self) -> list[ServeRequest]:

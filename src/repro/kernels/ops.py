@@ -152,16 +152,21 @@ def pattern_spmm(
 
     ``bm=None`` (default) autotunes the row tile from the batch size.
     Quantized weights (``bp.w_scales is not None``) dispatch the int8
-    variant transparently; output dtype follows ``x`` either way.
+    variant transparently; output dtype follows ``x`` either way.  The
+    compressed matmul runs under ``jax.named_scope("spmm")`` and the
+    inverse permutation under ``"permute"`` (the executor's stage names).
     """
     lead = x.shape[:-1]
-    xm = x.reshape(-1, x.shape[-1])
-    y = pattern_spmm_raw(
-        xm, bp.w_comp, bp.block_ids, bp.block,
-        backend=backend, interpret=interpret, bm=bm, w_scales=bp.w_scales,
-    )
-    y = jnp.take(y, jnp.asarray(bp.inv_order), axis=1)
-    return y.reshape(*lead, bp.n_out).astype(x.dtype)
+    with jax.named_scope("spmm"):
+        xm = x.reshape(-1, x.shape[-1])
+        y = pattern_spmm_raw(
+            xm, bp.w_comp, bp.block_ids, bp.block,
+            backend=backend, interpret=interpret, bm=bm,
+            w_scales=bp.w_scales,
+        )
+    with jax.named_scope("permute"):
+        y = jnp.take(y, jnp.asarray(bp.inv_order), axis=1)
+        return y.reshape(*lead, bp.n_out).astype(x.dtype)
 
 
 def flash_attention(

@@ -8,18 +8,19 @@ on them without import cycles or accelerator-backend coupling:
     clock and a bounded ring buffer, exporting Chrome trace-event JSON
     (complete/instant/async/counter events) loadable in Perfetto or
     ``chrome://tracing``.  One :class:`~repro.obs.trace.Tracer` threaded
-    through ``compile_network`` -> ``make_forward`` ->
-    ``InferenceService`` puts compile phases, per-layer execution, and
-    request lifecycles on a single shared timeline.
+    through ``compile_network`` -> ``InferenceService`` puts compile
+    phases, serving steps and request lifecycles on a single shared
+    timeline.  Its spans also land on ``jax.profiler``'s host timeline
+    whenever a profiler records, beside the device's operations, which
+    the jitted forward names by ``jax.named_scope`` (``<layer>/<stage>``).
   * ``metrics`` — counters, gauges, and fixed-bucket histograms with
     exact sample-backed percentiles, grouped in a process-global but
     resettable :class:`~repro.obs.metrics.MetricsRegistry`, with JSON
     snapshot and Prometheus text exposition.
 
-Everything is opt-in: a ``tracer=None`` default everywhere resolves to
-the shared no-op :data:`~repro.obs.trace.NULL_TRACER`, so un-traced hot
-paths (in particular the jitted forward) are byte-identical to the
-pre-observability code.
+The ring buffer is opt-in: a ``tracer=None`` default everywhere resolves
+to the shared no-op :data:`~repro.obs.trace.NULL_TRACER`, which records
+nothing and reads no clock; a tracer never reaches the jitted forward.
 """
 
 from repro.obs.metrics import (

@@ -2,10 +2,15 @@
 
 Design constraints, in order:
 
+  * **on the profiler's clock** — every :meth:`Tracer.span`, on every
+    tracer (:data:`NULL_TRACER` included), also enters a
+    ``jax.profiler.TraceAnnotation`` of the same name and args whenever
+    JAX is loaded, so program spans land on the profiler's host timeline
+    beside the device's operations.  With no profiler running that costs
+    one check; this module never imports JAX itself.
   * **zero overhead when off** — every instrumented call site takes a
     ``tracer=None`` default that resolves to :data:`NULL_TRACER`, whose
-    methods are no-ops; nothing is recorded, no clock is read, and the
-    jitted forward keeps its exact pre-observability code path.
+    ring buffer records nothing and whose clock is never read.
   * **deterministic under test** — the clock is injectable
     (``clock=lambda: fake.t``), so span timestamps and durations are
     exact values, not wall-clock noise.
@@ -32,11 +37,12 @@ Export follows the Chrome trace-event format "JSON object" flavour:
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
 from collections import deque
 from typing import Any, Callable, Iterator
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 
 __all__ = ["SpanRecord", "Tracer", "NULL_TRACER", "get_tracer", "set_tracer"]
 
@@ -117,24 +123,27 @@ class Tracer:
     def span(self, name: str, cat: str = "", **args) -> Iterator[SpanRecord]:
         """Record a complete ('X') span around the ``with`` body.
 
-        Yields the :class:`SpanRecord`; after exit its ``dur`` holds the
-        measured duration in seconds (on the injectable clock), which
-        instrumentation can read back — e.g. the executor accumulates
-        per-layer wall time from it.  Exceptions propagate; the span is
+        The span always enters the profiler's ``TraceAnnotation`` of the
+        same name and args (see the module docstring); the ring buffer
+        records it only when the tracer is enabled.  Yields the
+        :class:`SpanRecord`; after exit its ``dur`` holds the measured
+        duration in seconds (on the injectable clock), which
+        instrumentation can read back.  Exceptions propagate; the span is
         still closed (and flagged ``error=True`` in its args).
         """
-        if not self.enabled:
-            yield _NULL_SPAN
-            return
-        rec = SpanRecord(name, cat, self._now(), self._tid(), dict(args))
-        try:
-            yield rec
-        except BaseException:
-            rec.args["error"] = True
-            raise
-        finally:
-            rec.dur = max(self._now() - rec.ts, 0.0)
-            self._record(rec)
+        with _annotation(name, args):
+            if not self.enabled:
+                yield _NULL_SPAN
+                return
+            rec = SpanRecord(name, cat, self._now(), self._tid(), dict(args))
+            try:
+                yield rec
+            except BaseException:
+                rec.args["error"] = True
+                raise
+            finally:
+                rec.dur = max(self._now() - rec.ts, 0.0)
+                self._record(rec)
 
     def instant(self, name: str, cat: str = "", **args) -> None:
         """Record an instant ('i', thread-scoped) event."""
@@ -290,6 +299,15 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.to_chrome(), f)
         return path
+
+
+def _annotation(name: str, args: dict):
+    """The profiler's host span for ``name`` while a profiler records, else
+    a no-op; JAX is looked up, never imported, so ``obs`` runs without it."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None or not profiler.TraceAnnotation.is_enabled():
+        return nullcontext()
+    return profiler.TraceAnnotation(name, **args)
 
 
 # a shared open span handed out by disabled tracers, so `with t.span(...)

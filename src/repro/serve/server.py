@@ -18,6 +18,11 @@ One process, three moving parts:
     has admitted is never dropped — shedding applies only at the front
     door.
 
+The loop's JSON parsing and admission are ``serve.parse`` and
+``serve.admit`` spans of the process-default tracer (``obs/trace.py``),
+so they land on the profiler's host timeline beside the worker's
+``service.*`` step spans whenever a profiler records.
+
 Endpoints::
 
   POST /v1/run      one request  {"image": [[[...]]]} or
@@ -42,6 +47,7 @@ import time
 import numpy as np
 
 from repro.obs.metrics import Meter
+from repro.obs.trace import get_tracer
 from repro.serve.api import Overloaded, Request, Response
 from repro.serve.session import ServeSession
 
@@ -228,13 +234,14 @@ class ServingServer:
         loop = asyncio.get_running_loop()
         fut: asyncio.Future = loop.create_future()
         self._futures[id(req)] = (fut, loop)
-        try:
-            self.session.submit(req)
-        except BaseException:
-            self._futures.pop(id(req), None)
-            raise
-        with self._work:
-            self._work.notify()
+        with get_tracer().span("serve.admit", cat="http"):
+            try:
+                self.session.submit(req)
+            except BaseException:
+                self._futures.pop(id(req), None)
+                raise
+            with self._work:
+                self._work.notify()
         return fut
 
     # ----------------------------------------------------------------- http
@@ -307,7 +314,8 @@ class ServingServer:
 
     async def _run_one(self, body: bytes, writer) -> bool:
         try:
-            req = _parse_request(self._load_json(body))
+            with get_tracer().span("serve.parse", cat="http"):
+                req = _parse_request(self._load_json(body))
             fut = await self._submit(req)
         except _HttpError as e:
             await self._plain(writer, e.status, e.message)
@@ -324,11 +332,12 @@ class ServingServer:
 
     async def _run_stream(self, body: bytes, writer) -> bool:
         try:
-            obj = self._load_json(body)
-            items = obj.get("requests") if isinstance(obj, dict) else None
-            if not isinstance(items, list) or not items:
-                raise _HttpError(400, "body needs a 'requests' list")
-            parsed = [_parse_request(o) for o in items]
+            with get_tracer().span("serve.parse", cat="http"):
+                obj = self._load_json(body)
+                items = obj.get("requests") if isinstance(obj, dict) else None
+                if not isinstance(items, list) or not items:
+                    raise _HttpError(400, "body needs a 'requests' list")
+                parsed = [_parse_request(o) for o in items]
         except _HttpError as e:
             await self._plain(writer, e.status, e.message)
             return True

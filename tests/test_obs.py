@@ -164,6 +164,65 @@ def test_slowest_aggregates_by_name():
     assert top[0][1] == pytest.approx(0.3)
 
 
+@pytest.mark.parametrize("enabled", [False, True])
+def test_span_lands_on_the_profiler_timeline(tmp_path, enabled):
+    """Every tracer's spans, the no-op tracer's included, are host events
+    of a running profiler, args and nesting kept; only an enabled tracer
+    also records them in its ring buffer."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = Tracer(enabled=enabled) if enabled else NULL_TRACER
+    with tr.span("obs.test.outside"):  # no profiler yet: not recorded
+        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("obs.test.outer", cat="serve", live=3):
+            with tr.span("obs.test.inner"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    events = {
+        ev.name: ev
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+        if ev.name.startswith("obs.test.")
+    }
+    assert set(events) == {"obs.test.outer", "obs.test.inner"}
+    outer, inner = events["obs.test.outer"], events["obs.test.inner"]
+    assert dict(outer.stats)["live"] == 3
+    assert outer.start_ns <= inner.start_ns
+    assert inner.start_ns + inner.duration_ns <= (
+        outer.start_ns + outer.duration_ns
+    )
+    recorded = [s.name for s in tr.spans()]
+    assert recorded == (
+        ["obs.test.outside", "obs.test.inner", "obs.test.outer"]
+        if enabled else []
+    )
+
+
+def test_obs_imports_without_jax():
+    import subprocess
+    import sys
+
+    code = (
+        "import sys; from repro.obs import NULL_TRACER\n"
+        "with NULL_TRACER.span('x', a=1): pass\n"
+        "assert 'jax' not in sys.modules, 'obs imported jax'\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=120,
+    )
+    assert out.returncode == 0, out.stderr
+
+
 # ---------------------------------------------------------------- metrics
 
 

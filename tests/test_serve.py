@@ -328,6 +328,8 @@ def test_http_server_end_to_end(mini_prog):
         assert status == 200
         assert "engine_service_completed_total" in text
         assert "engine_service_first_result_seconds" in text
+        assert "engine_service_step_host_seconds_count" in text
+        assert "engine_service_step_wait_seconds_count" in text
         assert "serve_http_requests_total" in text
 
         # error paths keep the server alive

@@ -294,35 +294,21 @@ def test_traced_service_keeps_single_trace_and_emits_lifecycles(mini):
 
 
 def test_instrumented_forward_matches_and_reports_drift(mini):
-    """The per-layer instrumented forward computes the same logits as the
-    jitted path, exposes per-layer mean wall-times, and those feed the
-    hardware report's predicted-vs-measured drift section."""
-    from repro.obs import Tracer
-
+    """Measured per-layer seconds — a plain dict, as a profile of the
+    jitted forward's ``<layer>/*`` scopes gives them — feed the hardware
+    report's predicted-vs-measured drift section."""
     cfg, params, bits, prog = mini
-    x = jnp.asarray(_images(4, seed=2))
-    plain = make_forward(prog, backend="xla")
-    tr = Tracer()
-    traced = make_forward(prog, backend="xla", tracer=tr)
-    np.testing.assert_allclose(
-        np.asarray(traced(x)), np.asarray(plain(x)), rtol=1e-5, atol=1e-6
-    )
-    # the instrumented path never touched the jitted function
-    assert traced.trace_count() == 0
-    times = traced.observed_times()
     layer_names = [c.name for c in prog.convs] + ["fc"]
-    assert set(times) == set(layer_names)
-    assert all(v > 0 for v in times.values())
-    spans = [s.name for s in tr.spans("execute")]
-    assert "forward" in spans
-    assert {f"layer:{c.name}" for c in prog.convs} <= set(spans)
+    times = {name: 1e-3 * (i + 1) for i, name in enumerate(layer_names)}
 
     rep = prog.hardware_report(observed=times)
     drift = rep["drift"]
     rows = {r["name"]: r for r in drift["layers"]}
     assert set(rows) == {c.name for c in prog.convs}  # fc: no cycle model
     assert drift["unpredicted"] == ["fc"]
-    for r in rows.values():
+    conv_total = sum(times[c.name] for c in prog.convs)
+    for name, r in rows.items():
+        assert r["measured_share"] == pytest.approx(times[name] / conv_total)
         assert r["share_drift"] == pytest.approx(
             r["measured_share"] - r["predicted_share"]
         )
