@@ -42,6 +42,8 @@ V206   mapping geometry is consistent with the packed operands: the OU
        fits the crossbar, a weight's cell slices fit one row, the OU can
        hold the layer's tallest pattern block, and an int8 program's
        mapping stores the cell-slice count its payload actually occupies
+V207   ``patch_order`` is ``'channel'`` or ``'tap'``, and ``'tap'`` only
+       where the layer's K (``c_in * kernel**2``) spans more than one block
 V301   inter-layer shape chaining (channels, spatial dims, fc head)
 V302   precision contract: ``precision``/``cell_bits`` agree with the
        stored payloads
@@ -85,6 +87,7 @@ from repro.core.mapping import BLOCK_ORDERS
 from repro.core.patterns import ALL_ZERO, pattern_sizes
 from repro.core.quantize import QMAX, cell_slices, compose_cell_slices
 from repro.core.sparse import REORDERS, BlockPatternWeight
+from repro.engine.lowering import PATCH_ORDERS
 
 __all__ = [
     "verify_bp",
@@ -526,6 +529,21 @@ def verify_conv(conv, cell_bits: int = 4, report: Report | None = None) -> Repor
         )
     _verify_bias(r, conv.bias, conv.c_out, name)
     _verify_mapping(r, conv)
+    order = getattr(conv, "patch_order", "channel")
+    if order not in PATCH_ORDERS:
+        r.add(
+            "V207",
+            f"unknown patch order {order!r} (known: {PATCH_ORDERS})",
+            layer=name, location="patch_order",
+        )
+    elif order == "tap" and conv.c_in * k * k <= bp.block:
+        r.add(
+            "V207",
+            f"tap-major patches on a K of {conv.c_in * k * k} rows, which "
+            f"fits one block of {bp.block}: the lowering keeps such a "
+            "layer channel-major",
+            layer=name, location="patch_order",
+        )
     return r
 
 

@@ -1,7 +1,9 @@
 """Inference engine: compile pattern-pruned CNNs into executable programs.
 
 The paper's deployment story made real: ``lowering`` turns pruned dense
-weights into compressed spmm operands (reorder -> compress -> index),
+weights into compressed spmm operands (reorder -> compress -> index; a
+conv's im2col rows are tap-major where its K spans more than one block,
+so the taps no kernel uses drop out as whole zero bricks),
 ``program`` is the compiled artifact (ops + geometry + crossbar pricing),
 ``executor`` runs it through the Pallas/XLA kernels (single-device or
 sharded over a mesh via ``partition`` — tile-parallel spmm with psum
@@ -175,7 +177,8 @@ When ``compile_network(..., verify=...)`` is on, the pass runs right
 after verification and attaches a ``RangeCertificate`` to the program:
 per-layer activation bounds plus a certified minimum cells-per-weight
 table on the layer's reference scale grid.  The certificate rides in
-manifest v4 (v1–v3 saves still load, without one),
+manifest v4 and later (v1–v3 saves still load, without one; v5 adds
+each conv's ``patch_order``, and v1–v4 saves load channel-major),
 ``hardware_report()`` prices it as a ``certified_potential`` section
 (certified-vs-stored crossbar area/energy, exactly on the simulator's
 own cost chain), and ``python -m repro.analysis ranges <dir>`` recomputes

@@ -1,7 +1,8 @@
 """Executor: run a ``CompiledNetwork`` through the Pallas/XLA spmm kernels.
 
 ``make_forward`` returns a jitted batched forward: per conv layer it
-extracts im2col patches (conv-as-spmm), dispatches through
+extracts im2col patches in the layer's ``patch_order`` (conv-as-spmm;
+tap-major where K spans more than one block), dispatches through
 ``kernels/ops.pattern_spmm`` (the Pallas TPU kernel on the chip; the XLA
 path, or the Pallas interpreter when asked for, on CPU) — which applies
 the stored inverse output permutation (the Output Indexing Unit) — then
@@ -76,22 +77,49 @@ __all__ = [
 STAGES = ("patches", "spmm", "permute", "epilogue", "stats")
 
 
-def extract_patches(x: jax.Array, k: int) -> jax.Array:
-    """im2col for stride-1 'same' convs: [B, C, H, W] -> [B, H, W, C*k*k].
+def extract_patches(
+    x: jax.Array, k: int, order: str = "channel", width: int | None = None
+) -> jax.Array:
+    """im2col for stride-1 'same' convs: [B, C, H, W] -> [B*H*W, F].
 
-    Patch layout matches ``lowering.conv_matrix``: feature index is
-    ``c * k*k + (dy*k + dx)``.
+    The feature order matches ``lowering.conv_matrix(w, order)``:
+
+    * ``'channel'`` — feature ``c * k*k + (dy*k + dx)``: the ``k*k`` taps
+      of one channel sit side by side, built as a ``[B, C, H, W, k*k]``
+      stack transposed into rows;
+    * ``'tap'`` — feature ``(dy*k + dx) * C + c``: the ``k*k`` shifted
+      slices of the padded NHWC activation, each ``C`` lanes wide,
+      concatenated along the feature axis (a plain copy).
+
+    ``F`` is ``C*k*k``, or ``width`` when given: the features are then
+    zero-padded up to the spmm's padded K (``bp.k_in``) — tap-major
+    patches as one more block of the same concatenation.
     """
     b, c, h, w = x.shape
     pad = k // 2
-    xp = jnp.pad(x, ((0, 0), (0, 0), (pad, pad), (pad, pad)))
-    taps = [
-        xp[:, :, dy : dy + h, dx : dx + w]
+    if order == "channel":
+        xp = jnp.pad(x, ((0, 0), (0, 0), (pad, pad), (pad, pad)))
+        taps = [
+            xp[:, :, dy : dy + h, dx : dx + w]
+            for dy in range(k)
+            for dx in range(k)
+        ]
+        patches = jnp.stack(taps, axis=-1)  # [B, C, H, W, k*k]
+        patches = patches.transpose(0, 2, 3, 1, 4).reshape(b * h * w, -1)
+        return patches if width is None else _pad_features(patches, width)
+    if order != "tap":
+        raise ValueError(f"unknown patch order {order!r}")
+    xp = jnp.pad(
+        x.transpose(0, 2, 3, 1), ((0, 0), (pad, pad), (pad, pad), (0, 0))
+    )
+    parts = [
+        xp[:, dy : dy + h, dx : dx + w, :]
         for dy in range(k)
         for dx in range(k)
     ]
-    patches = jnp.stack(taps, axis=-1)  # [B, C, H, W, k*k]
-    return patches.transpose(0, 2, 3, 1, 4).reshape(b, h, w, c * k * k)
+    if width is not None and width > c * k * k:
+        parts.append(jnp.zeros((b, h, w, width - c * k * k), x.dtype))
+    return jnp.concatenate(parts, axis=-1).reshape(b * h * w, -1)
 
 
 def _pad_features(x: jax.Array, to: int) -> jax.Array:
@@ -110,11 +138,14 @@ def zero_selection_counts(
     kk: int,
     masks: np.ndarray,
     row_valid: jax.Array | None = None,
+    order: str = "channel",
 ) -> jax.Array:
     """Count all-zero input selections per OU row-group.
 
-    patches: [M, c_in*kk] unpadded im2col windows; masks: [P, kk] bool,
-    the layer's pattern position masks (``skip_patterns_and_masks``).
+    patches: [M, c_in*kk] unpadded im2col windows, features in ``order``
+    (:func:`extract_patches`; the counts are the same in either order);
+    masks: [P, kk] bool, the layer's pattern position masks
+    (``skip_patterns_and_masks``).
     Returns int32 [c_in, P]: entry (c, i) is the number of windows whose
     channel-c activations at ``masks[i]``'s positions are all zero — the
     selections the Input Preprocessing Unit would skip.  The all-zero
@@ -126,6 +157,8 @@ def zero_selection_counts(
     traffic and silently inflate the measured energy win.
     """
     m = patches.shape[0]
+    if order == "tap":
+        patches = patches.reshape(m, kk, c_in).transpose(0, 2, 1)
     z = patches.reshape(m, c_in, 1, kk) == 0.0
     keep = jnp.asarray(masks)[None, None]  # [1, 1, P, kk]
     all_zero = jnp.all(z | ~keep, axis=-1)  # [M, C, P]
@@ -152,8 +185,12 @@ class _Dispatch:
             bm=self.bm,
         )
 
-    def counts(self, patches, c_in, kk, masks, row_valid=None) -> jax.Array:
-        return zero_selection_counts(patches, c_in, kk, masks, row_valid)
+    def counts(
+        self, patches, c_in, kk, masks, row_valid=None, order="channel"
+    ) -> jax.Array:
+        return zero_selection_counts(
+            patches, c_in, kk, masks, row_valid, order
+        )
 
 
 class _ShardedDispatch(_Dispatch):
@@ -235,16 +272,20 @@ class _ShardedDispatch(_Dispatch):
             y = jnp.take(y, jnp.asarray(bp.inv_order), axis=1)
             return y.astype(x2d.dtype)
 
-    def counts(self, patches, c_in, kk, masks, row_valid=None) -> jax.Array:
+    def counts(
+        self, patches, c_in, kk, masks, row_valid=None, order="channel"
+    ) -> jax.Array:
         part = self.part
         dspec = self._data_spec(patches.shape[0])
         if dspec is None:
-            return zero_selection_counts(patches, c_in, kk, masks, row_valid)
+            return zero_selection_counts(
+                patches, c_in, kk, masks, row_valid, order
+            )
 
         def local(pl, *rv):
             return jax.lax.psum(
                 zero_selection_counts(
-                    pl, c_in, kk, masks, rv[0] if rv else None
+                    pl, c_in, kk, masks, rv[0] if rv else None, order
                 ),
                 part.data_axis,
             )
@@ -274,8 +315,8 @@ def _run_conv(
 ) -> tuple[jax.Array, jax.Array | None]:
     b, c, h, w = x.shape
     with jax.named_scope("patches"):
-        patches = extract_patches(x, op.kernel)  # [B, H, W, C*k*k]
-        patches = patches.reshape(b * h * w, -1)
+        # [B*H*W, bp.k_in], features in the order the weight rows are
+        patches = extract_patches(x, op.kernel, op.patch_order, op.bp.k_in)
     counts = None
     if stat_masks is not None:
         with jax.named_scope("stats"):
@@ -283,11 +324,9 @@ def _run_conv(
             # are excluded from the skip counters
             row_valid = None if valid is None else jnp.repeat(valid, h * w)
             counts = disp.counts(
-                patches, op.c_in, op.kernel * op.kernel, stat_masks,
-                row_valid,
+                patches[:, : op.k_unpadded], op.c_in, op.kernel * op.kernel,
+                stat_masks, row_valid, op.patch_order,
             )
-    with jax.named_scope("patches"):
-        patches = _pad_features(patches, op.bp.k_in)
     y = disp.spmm(patches, op.bp, prepared)  # scopes spmm, permute
     with jax.named_scope("epilogue"):
         y = y[:, : op.c_out] + jnp.asarray(op.bias)
@@ -378,8 +417,11 @@ def make_forward(
     the conv's ``op.name`` (``conv1`` ...), then ``gap`` and ``fc``;
     ``<stage>`` is one of :data:`STAGES`:
 
-    * ``patches`` — the SAME pad, tap slices and stack, the NCHW->NHWC
-      transpose and reshape (im2col), and the K padding;
+    * ``patches`` — im2col in the layer's ``patch_order`` and the K
+      padding: channel-major, the SAME pad, tap slices, stack, the
+      transpose into rows and the pad; tap-major, the NCHW->NHWC
+      transpose, SAME pad, and one concatenation of the tap slices and
+      the zero K padding;
     * ``spmm`` — the row padding, the ``pattern_spmm*`` kernel (or the
       XLA path) and the row slice; sharded, the scatter and ``psum``;
     * ``permute`` — the inverse output permutation (Output Indexing

@@ -1,11 +1,13 @@
 """Lowering: pattern-pruned CNN params -> executable ``CompiledNetwork``.
 
 Per conv layer the dense weights ``[C_out, C_in, K, K]`` are viewed as the
-im2col matmul ``[C_in*K*K, C_out]``, zero-padded up to (block, tile)
-multiples, and compressed into a :class:`BlockPatternWeight` via the
-*exact* path of ``core/sparse.build_block_pattern``: block masks are the
-true nonzero structure (``nonzero_block_masks``), so reorder -> compress ->
-index produces real kernel operands and the compressed program computes
+im2col matmul ``[C_in*K*K, C_out]`` — rows channel-major, or tap-major
+where K spans more than one block (:func:`patch_order`) — zero-padded up
+to (block, tile) multiples, and compressed into a
+:class:`BlockPatternWeight` via the *exact* path of
+``core/sparse.build_block_pattern``: block masks are the true nonzero
+structure (``nonzero_block_masks``), so reorder -> compress -> index
+produces real kernel operands and the compressed program computes
 bit-the-same weights as the pruned dense network.  The FC head is lowered
 onto the same path.
 
@@ -40,11 +42,13 @@ from repro.engine.program import CompiledConv, CompiledFC, CompiledNetwork
 from repro.models.cnn import CNNConfig
 from repro.obs.trace import NULL_TRACER, Tracer
 
-__all__ = ["EngineConfig", "CompileOptions", "PRECISIONS", "lower_matrix",
-           "lower_conv", "lower_fc", "conv_mapping_search",
-           "compile_network"]
+__all__ = ["EngineConfig", "CompileOptions", "PRECISIONS", "PATCH_ORDERS",
+           "patch_order", "conv_matrix", "lower_matrix", "lower_conv",
+           "lower_fc", "conv_mapping_search", "compile_network"]
 
 PRECISIONS = ("fp32", "int8")
+# im2col feature orders: 'channel' (row c*k*k + tap) and 'tap' (tap*c_in + c)
+PATCH_ORDERS = ("channel", "tap")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,15 +152,37 @@ def _pad_axis(a: np.ndarray, axis: int, mult: int) -> np.ndarray:
     return np.pad(a, widths)
 
 
-def conv_matrix(w: np.ndarray) -> np.ndarray:
+def patch_order(c_in: int, kernel: int, block: int) -> str:
+    """The im2col feature order of a conv layer: ``'tap'`` where its K
+    (``c_in * kernel**2``) spans more than one ``block``, else
+    ``'channel'``.
+
+    Tap-major patches are the ``kernel**2`` shifted NHWC slices side by
+    side, a plain lane-aligned concatenation, and a K-block then holds
+    one or two taps of every channel, so a tap no kernel of the layer
+    uses leaves whole zero bricks for the lossless compression to drop.
+    A K that fits one block gains no bricks from the order, and its
+    narrow slices (3 lanes for RGB) concatenate worse than they
+    transpose, so it stays channel-major.
+    """
+    return "tap" if c_in * kernel * kernel > block else "channel"
+
+
+def conv_matrix(w: np.ndarray, order: str = "channel") -> np.ndarray:
     """[C_out, C_in, Kh, Kw] -> im2col matmul view [C_in*Kh*Kw, C_out].
 
-    Row index is ``c * Kh*Kw + (dy*Kw + dx)`` — the patch layout the
-    executor extracts.
+    The rows follow the executor's patch layout for ``order``
+    (:data:`PATCH_ORDERS`): ``'channel'`` puts weight ``(c, dy, dx)`` at
+    row ``c * Kh*Kw + (dy*Kw + dx)``, ``'tap'`` at row
+    ``(dy*Kw + dx) * C_in + c``.
     """
     w = np.asarray(w)
-    co = w.shape[0]
-    return w.reshape(co, -1).T
+    co, ci = w.shape[:2]
+    if order == "channel":
+        return w.reshape(co, -1).T
+    if order == "tap":
+        return w.reshape(co, ci, -1).transpose(2, 1, 0).reshape(-1, co)
+    raise ValueError(f"order must be one of {PATCH_ORDERS}, got {order!r}")
 
 
 def lower_matrix(
@@ -208,6 +234,7 @@ def lower_conv(
     if pattern_bits is None:
         pattern_bits = masks_to_bits(kernel_masks(w))
     reorder = mapping.reorder if mapping is not None else "pattern"
+    order = patch_order(c_in, kh, ecfg.block)
     return CompiledConv(
         name=name,
         c_in=c_in,
@@ -215,11 +242,12 @@ def lower_conv(
         kernel=kh,
         out_hw=out_hw,
         pool_after=pool_after,
-        bp=lower_matrix(conv_matrix(w), ecfg.block, ecfg.tile,
+        bp=lower_matrix(conv_matrix(w, order), ecfg.block, ecfg.tile,
                         ecfg.precision, tracer=tracer, reorder=reorder),
         bias=np.asarray(b, np.float32).copy(),
         pattern_bits=np.asarray(pattern_bits, np.int64).copy(),
         mapping=mapping,
+        patch_order=order,
     )
 
 
@@ -268,8 +296,9 @@ def conv_mapping_search(
     """Run the mapping design-space search for one conv layer.
 
     Builds exactly the search inputs ``compile_network(optimize=...)``
-    uses — the layer's pattern bits, the padded matmul view's block
-    masks, the precision-derived fixed scheme — and returns the full
+    uses — the layer's pattern bits, the block masks of the padded
+    matmul view in the rows the lowering stores (:func:`patch_order`),
+    the precision-derived fixed scheme — and returns the full
     :class:`~repro.core.mapsearch.MappingSearchResult` (benchmarks call
     this standalone to time the search and check determinism against the
     compiled program).
@@ -278,8 +307,9 @@ def conv_mapping_search(
     if pattern_bits is None:
         pattern_bits = masks_to_bits(kernel_masks(w))
     kernel_size = w.shape[2] * w.shape[3]
+    order = patch_order(w.shape[1], w.shape[2], ecfg.block)
     wp = _pad_axis(
-        _pad_axis(conv_matrix(w), 0, ecfg.block), 1, ecfg.tile
+        _pad_axis(conv_matrix(w, order), 0, ecfg.block), 1, ecfg.tile
     )
     masks = nonzero_block_masks(wp, ecfg.block)
     return search_layer_mapping(

@@ -38,7 +38,10 @@ class CompiledConv:
     ``bp`` operates on the *padded* matmul view: patches padded from
     ``c_in * kernel**2`` to ``bp.k_in`` rows, outputs padded from ``c_out``
     to ``bp.n_out`` columns (the executor slices the first ``c_out`` back
-    out after the inverse permutation).
+    out after the inverse permutation).  ``patch_order`` is the order of
+    those ``c_in * kernel**2`` rows, shared by the stored weights and the
+    executor's patches (``lowering.patch_order``): ``'channel'`` (row
+    ``c * kernel**2 + tap``) or ``'tap'`` (row ``tap * c_in + c``).
 
     ``mapping`` (optional) is the searched per-layer crossbar mapping
     (``compile_network(optimize=...)``, ``core/mapsearch.py``):
@@ -58,6 +61,7 @@ class CompiledConv:
     bias: np.ndarray  # [c_out]
     pattern_bits: np.ndarray  # [c_out, c_in] packed kernel patterns
     mapping: MappingCandidate | None = None
+    patch_order: str = "channel"
 
     @property
     def k_unpadded(self) -> int:
@@ -152,7 +156,8 @@ class CompiledNetwork:
         """Human-readable (op, detail) schedule, in execution order."""
         ops = []
         for c in self.convs:
-            d = (f"spmm[{c.bp.k_in}x{c.bp.n_out}] "
+            d = (f"spmm[{c.bp.k_in}x{c.bp.n_out}] {c.patch_order}-major "
+                 f"bricks={int(np.sum(c.bp.nnz))} k_max={c.bp.k_max} "
                  f"density={block_density(c.bp):.2f} + norm/relu")
             if c.pool_after:
                 d += " + maxpool2x2"

@@ -19,7 +19,10 @@ historical pricing.  Format v4 adds the optional range
 ``certificate`` (:class:`~repro.analysis.ranges.RangeCertificate`):
 v1-v3 programs load with ``certificate=None``, and only its structure
 is checked here (M003) — whether the certificate still matches the
-payloads is the certification pass's job (V506).
+payloads is the certification pass's job (V506).  Format v5 adds each
+conv's ``patch_order`` (``lowering.patch_order``), the row order its
+weights were lowered in and its patches must be built in: v1-v4
+programs were lowered channel-major and always load as ``'channel'``.
 """
 
 from __future__ import annotations
@@ -49,9 +52,11 @@ __all__ = [
 _MANIFEST = "program.json"
 # v2 adds precision/cell_bits + per-bp w_scales; v3 adds per-conv
 # mapping candidates + the fc reorder tag; v4 adds the optional range
-# certificate
-_FORMAT_VERSION = 4
-_SUPPORTED_VERSIONS = (1, 2, 3, 4)
+# certificate; v5 adds the per-conv patch order
+_FORMAT_VERSION = 5
+_SUPPORTED_VERSIONS = (1, 2, 3, 4, 5)
+# the first version whose convs may be tap-major (and say so)
+_PATCH_ORDER_VERSION = 5
 
 
 def _save_array(directory: str, name: str, arr) -> str:
@@ -146,6 +151,7 @@ def save_program(directory: str, program: CompiledNetwork) -> str:
                 "mapping": (
                     None if c.mapping is None else c.mapping.to_manifest()
                 ),
+                "patch_order": c.patch_order,
             }
         )
     manifest["fc"] = {
@@ -378,6 +384,14 @@ def validate_manifest(manifest: dict, directory: str) -> None:
                 )
         _check_bp_entry(e["bp"], directory, f"{where}.bp")
         _check_mapping_entry(e.get("mapping"), f"{where}.mapping")
+        if version >= _PATCH_ORDER_VERSION:
+            # which orders are valid, and where, is the verifier's V207
+            _require(e, ("patch_order",), where)
+            if not isinstance(e["patch_order"], str):
+                raise ProgramFormatError(
+                    f"program manifest {where}.patch_order must be a "
+                    "string", rule="M003",
+                )
     fce = manifest["fc"]
     if not isinstance(fce, dict):
         raise ProgramFormatError(
@@ -419,6 +433,8 @@ def load_program(directory: str, verify: bool = True) -> CompiledNetwork:
     directory = _resolve_directory(directory)
     manifest = read_manifest(directory)
     validate_manifest(manifest, directory)
+    # before v5 every conv was lowered channel-major, whatever it says
+    has_order = manifest["format_version"] >= _PATCH_ORDER_VERSION
     c = manifest["config"]
     cfg = CNNConfig(
         conv_channels=tuple(tuple(x) for x in c["conv_channels"]),
@@ -446,6 +462,7 @@ def load_program(directory: str, verify: bool = True) -> CompiledNetwork:
                     if e.get("mapping") is not None
                     else None
                 ),
+                patch_order=e["patch_order"] if has_order else "channel",
             )
             for e in manifest["convs"]
         ]

@@ -44,3 +44,41 @@ def run_virtual_devices(n_devices: int, body: str) -> dict:
     assert out.returncode == 0, f"subprocess failed:\n{out.stderr[-3000:]}"
     line = out.stdout.strip().splitlines()[-1]
     return json.loads(line)
+
+
+def channel_major(program):
+    """``program`` as format v1-v4 compilers lowered it: every conv's
+    weight rows channel-major, re-lowered from its stored weights with
+    its own reorder strategy.  The result carries no range certificate
+    (the one ``program`` had describes other bricks)."""
+    import dataclasses
+
+    from repro.engine.lowering import conv_matrix, lower_matrix
+
+    convs = []
+    for c in program.convs:
+        if c.patch_order == "tap":
+            kk = c.kernel * c.kernel
+            wm = np.asarray(c.bp.dense())[: c.k_unpadded, : c.c_out]
+            w = wm.reshape(kk, c.c_in, c.c_out).transpose(2, 1, 0)
+            w = w.reshape(c.c_out, c.c_in, c.kernel, c.kernel)
+            reorder = "pattern" if c.mapping is None else c.mapping.reorder
+            bp = lower_matrix(conv_matrix(w), c.bp.block, c.bp.tile,
+                              program.precision, reorder=reorder)
+            c = dataclasses.replace(c, bp=bp, patch_order="channel")
+        convs.append(c)
+    return dataclasses.replace(program, convs=convs, certificate=None)
+
+
+def downgrade_manifest(directory: str, version: int) -> dict:
+    """Rewrite a saved program's manifest as format ``version`` (< 5): no
+    per-conv ``patch_order``.  Returns the rewritten manifest."""
+    path = os.path.join(directory, "program.json")
+    with open(path) as f:
+        manifest = json.load(f)
+    manifest["format_version"] = version
+    for e in manifest["convs"]:
+        del e["patch_order"]
+    with open(path, "w") as f:
+        json.dump(manifest, f)
+    return manifest

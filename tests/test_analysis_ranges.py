@@ -356,20 +356,17 @@ def test_manifest_v4_carries_certificate(prog_int8, tmp_path):
     serialize.save_program(d, prog_int8)
     with open(os.path.join(d, "program.json")) as f:
         manifest = json.load(f)
-    assert manifest["format_version"] == 4
+    assert manifest["format_version"] == 5
     assert manifest["certificate"]["precision"] == "int8"
 
 
 def test_v3_manifest_loads_without_certificate(prog_int8, tmp_path):
+    from conftest import channel_major, downgrade_manifest
+
     d = str(tmp_path / "prog")
-    serialize.save_program(d, prog_int8)
-    path = os.path.join(d, "program.json")
-    with open(path) as f:
-        manifest = json.load(f)
-    manifest["format_version"] = 3
-    del manifest["certificate"]
-    with open(path, "w") as f:
-        json.dump(manifest, f)
+    # a v3 compiler lowered every conv channel-major and certified nothing
+    serialize.save_program(d, channel_major(prog_int8))
+    assert "certificate" not in downgrade_manifest(d, 3)
     loaded = serialize.load_program(d)
     assert loaded.certificate is None
     # a certificate-less save still certifies — it just can't cross-check

@@ -295,6 +295,27 @@ def test_pattern_bits_shape(prog_fp32):
     assert verify_network(prog).rules("error") == {"V201"}
 
 
+@pytest.mark.parametrize(
+    "layer,order,expected",
+    [
+        (0, "tap", {"V207"}),  # K = 9 fits one block of 16
+        (1, "zigzag", {"V207"}),
+        (1, "channel", set()),  # v1-v4 programs: channel-major everywhere
+        (1, "tap", set()),
+    ],
+    ids=["tap-on-one-block", "unknown-order", "channel-on-many-blocks",
+         "tap-on-many-blocks"],
+)
+def test_patch_order_rule(prog_fp32, layer, order, expected):
+    assert [c.patch_order for c in prog_fp32.convs] == ["channel", "tap",
+                                                         "tap"]
+    convs = list(prog_fp32.convs)
+    convs[layer] = dataclasses.replace(convs[layer], patch_order=order)
+    report = verify_network(dataclasses.replace(prog_fp32, convs=convs))
+    assert report.rules("error") == expected, report.format()
+    assert all(d.layer == convs[layer].name for d in report.errors)
+
+
 def test_bias_shape(prog_fp32):
     conv0 = prog_fp32.convs[0]
     prog = dataclasses.replace(
@@ -493,9 +514,13 @@ def test_saved_pristine_roundtrip(saved):
         (lambda p: open(
             os.path.join(p, "fc.w_comp.npy"), "wb"
         ).write(b"not-an-npy"), "M005"),
+        (lambda p: _rewrite(p, {**_manifest(p), "convs": [
+            {k: v for k, v in e.items() if k != "patch_order"}
+            for e in _manifest(p)["convs"]
+        ]}), "M003"),
     ],
     ids=["bad-version", "missing-key", "missing-payload", "truncated-json",
-         "corrupt-payload"],
+         "corrupt-payload", "missing-patch-order"],
 )
 def test_corrupt_saved_program(saved, corrupt, rule):
     corrupt(saved)
@@ -504,6 +529,16 @@ def test_corrupt_saved_program(saved, corrupt, rule):
     assert ei.value.rule == rule
     report = verify_saved(saved)
     assert report.rules("error") == {rule}, report.format()
+
+
+def test_load_rejects_unknown_patch_order(saved):
+    manifest = _manifest(saved)
+    manifest["convs"][1]["patch_order"] = "diagonal"
+    _rewrite(saved, manifest)
+    with pytest.raises(VerificationError) as ei:
+        serialize.load_program(saved)
+    assert ei.value.report.rules("error") == {"V207"}
+    assert verify_saved(saved).rules("error") == {"V207"}
 
 
 def test_load_verifies_semantic_corruption(saved):

@@ -60,7 +60,12 @@ from repro.engine import (
     make_forward,
     save_program,
 )
-from repro.engine.lowering import _pad_axis, conv_matrix, lower_matrix
+from repro.engine.lowering import (
+    _pad_axis,
+    conv_matrix,
+    lower_matrix,
+    patch_order,
+)
 from repro.models.cnn import conv_weight_names, init_cnn, mini_cnn_config
 
 
@@ -158,7 +163,8 @@ def test_visited_candidates_all_bijective(mini):
     ecfg = EngineConfig()
     for i in (1, 2, 3):
         w = np.asarray(params[f"conv{i}"]["w"], np.float32)
-        wp = _pad_axis(_pad_axis(conv_matrix(w), 0, ecfg.block), 1,
+        order = patch_order(w.shape[1], w.shape[2], ecfg.block)
+        wp = _pad_axis(_pad_axis(conv_matrix(w, order), 0, ecfg.block), 1,
                        ecfg.tile)
         masks = nonzero_block_masks(wp, ecfg.block)
         res = conv_mapping_search(w, bits[f"conv{i}"], out_hw=10)
@@ -176,7 +182,9 @@ def test_predicted_bricks_match_built(mini):
     cfg, params, bits = mini
     ecfg = EngineConfig()
     w = np.asarray(params["conv2"]["w"], np.float32)
-    wp = _pad_axis(_pad_axis(conv_matrix(w), 0, ecfg.block), 1, ecfg.tile)
+    order = patch_order(w.shape[1], w.shape[2], ecfg.block)
+    wp = _pad_axis(_pad_axis(conv_matrix(w, order), 0, ecfg.block), 1,
+                   ecfg.tile)
     masks = nonzero_block_masks(wp, ecfg.block)
     for strategy in REORDERS:
         order = reorder_columns(masks, strategy)
@@ -429,17 +437,17 @@ def test_v2_manifest_loads_as_fixed_scheme(tmp_path, progs, x8):
     """A hand-downgraded v2 manifest (no mapping keys) still loads: convs
     get ``mapping=None``, the FC reorder defaults to 'pattern', and the
     program verifies clean."""
-    fixed, _ = progs
+    from conftest import channel_major, downgrade_manifest
+
+    # a v2 compiler lowered every conv channel-major
+    fixed = channel_major(progs[0])
     d = str(tmp_path / "prog")
     save_program(d, fixed)
-    mpath = os.path.join(d, "program.json")
-    with open(mpath) as f:
-        manifest = json.load(f)
-    manifest["format_version"] = 2
+    manifest = downgrade_manifest(d, 2)
     for e in manifest["convs"]:
         del e["mapping"]
     del manifest["fc"]["reorder"]
-    with open(mpath, "w") as f:
+    with open(os.path.join(d, "program.json"), "w") as f:
         json.dump(manifest, f)
     loaded = load_program(d)
     assert all(c.mapping is None for c in loaded.convs)

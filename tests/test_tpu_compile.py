@@ -1,4 +1,5 @@
-"""The spmm kernels compile for a TPU v5e at VGG16-224 shapes.
+"""The spmm kernels and the im2col patch build compile for a TPU v5e at
+VGG16-224 shapes.
 
 The TPU compiler is installed even where no chip is attached: a described
 ``v5e:2x2`` topology compiles what the chip would compile, and refuses
@@ -10,11 +11,14 @@ several test workers only the worker given this file loads it.  Keep
 every such compile in this file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.engine.executor import extract_patches
 from repro.kernels.ops import _pick_bm
 from repro.kernels.pattern_spmm import (
     pattern_spmm_pallas,
@@ -84,3 +88,17 @@ def test_spmm_kernel_compiles_for_v5e(one_chip, layer, precision):
         lowered = pattern_spmm_pallas.lower(x, w, ids, **static)
     text = lowered.compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("order,loops", [("tap", 0), ("channel", 1)])
+def test_conv2_patches_compile_without_relayout_loop(one_chip, order, loops):
+    """conv2's im2col at the benchmark's batch of 32 (64 channels at 224,
+    K padded to 640): tap-major is one concatenation and compiles with no
+    ``while`` loop; the channel-major build compiles into the relayout
+    loop that tap-major replaces (at batch 8 it does not, so batch 8
+    would not tell the two apart)."""
+    x = jax.ShapeDtypeStruct((32, 64, 224, 224), jnp.float32,
+                             sharding=one_chip)
+    build = jax.jit(lambda x: extract_patches(x, 3, order, 640))
+    text = build.lower(x).compile().as_text()
+    assert len(re.findall(r"\swhile\(", text)) == loops
