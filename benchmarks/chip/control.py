@@ -26,14 +26,14 @@ sys.path.insert(0, str(HERE))
 import run  # noqa: E402
 import spec as cellspec  # noqa: E402
 import traffic  # noqa: E402
-from model import logits_in_blocks, make_weights  # noqa: E402
+from model import logits_in_blocks  # noqa: E402
 
 
-def control_reading(cfg, params, images, block: int) -> float:
-    """``run.compare`` of the control's answers for ``images`` with the
-    reference: the widest ``max|control - ref| / max|ref|``."""
-    ref = logits_in_blocks(cfg, params, images, block, "highest")
-    low = logits_in_blocks(cfg, params, images, block, "three_pass")
+def control_reading(net, cfg, params, images, block: int) -> float:
+    """``run.compare`` of family ``net``'s control answers for ``images``
+    with its reference: the widest ``max|control - ref| / max|ref|``."""
+    ref = logits_in_blocks(net, cfg, params, images, block, "highest")
+    low = logits_in_blocks(net, cfg, params, images, block, "three_pass")
     return run.compare(list(enumerate(low)), ref)
 
 
@@ -44,13 +44,11 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     cell = cellspec.load_cell(Path.cwd(), args.workload)
     cfg, mix = cell.config, cell.mix
-    params, _ = make_weights(cfg)
+    params, _ = cell.network.make_weights(cfg)
     block = min(int(mix["batch_slots"]), int(mix["pool"]))
     for seed in args.seeds:
-        images = traffic.make_images(
-            seed, mix["pool"], cfg.conv_channels[0][0], cfg.input_hw
-        )
-        err = control_reading(cfg, params, images, block)
+        images = traffic.make_images(seed, mix["pool"], cfg.in_channels, cfg.input_hw)
+        err = control_reading(cell.network, cfg, params, images, block)
         print(json.dumps({
             "workload": cell.name, "seed": seed, "control_logit_rel_err": err,
             "limit": cfg.logit_rel_err_limit,

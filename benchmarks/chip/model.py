@@ -1,85 +1,24 @@
-"""The benchmark's own copy of the model: weights, images and the plain reference.
+"""What every network family of the benchmark shares: the seeded draw of a
+pattern-pruned conv, the control's arithmetic and the blocked reference run.
 
-Nothing here imports the program under test. A configuration file
-(``configs/<name>.json``) states the network: conv widths, pools, input
-size, classes, and the pattern-pruning statistics of arXiv:2010.06156
-Table II. From it and the file's ``weight_seed`` this module draws the
-pruned weights (the same draw as the program's ``core/synthetic``, copied
-here so the yardstick cannot move with the program), and runs the plain
-float32 forward that decides ``correct``:
+Nothing here imports the program under test. A family module
+(``networks/<family>.py``, contract in ``spec.py``) draws its weights with
+:func:`pruned_conv` where its convs are pattern-pruned (the same draw as
+the program's ``core/synthetic``, copied here so the yardstick cannot move
+with the program) and writes its plain float32 forward with every conv and
+matmul at ``Precision.HIGHEST``, wrapped in :func:`at_precision`.
 
-    conv3x3 SAME -> + bias -> channel_norm -> ReLU [-> maxpool 2x2]
-    ... -> global average pool -> FC
-
-``channel_norm`` divides each sample's channel by its spatial standard
-deviation (a stateless stand-in for batch norm). The reference runs at
-``Precision.HIGHEST``. The control runs the same forward with every
-matmul as three bf16 passes (``hi*hi + hi*lo + lo*hi``), which is what
-``Precision.HIGH`` does on a TPU, written out so that it computes the same
-on any backend.
+The reference runs at ``"highest"``. The control, ``"three_pass"``, runs
+the same forward with every such op as three bf16 passes (``hi*hi + hi*lo
++ lo*hi``), which is what ``Precision.HIGH`` does on a TPU, written out so
+that it computes the same on any backend.
 """
 
 from __future__ import annotations
 
-import dataclasses
-import json
-from pathlib import Path
-
 import numpy as np
 
 ALL_ZERO = 0  # pattern bitmask of a pruned-away kernel
-
-
-@dataclasses.dataclass(frozen=True)
-class NetConfig:
-    """A configuration file, as it is run."""
-
-    name: str
-    conv_channels: tuple[tuple[int, int], ...]
-    pool_after: frozenset[int]  # 1-based conv indices followed by a 2x2 pool
-    input_hw: int
-    num_classes: int
-    kernel: int
-    sparsity: float
-    zero_pattern_ratio: float
-    patterns_per_layer: tuple[int, ...]
-    weight_seed: int
-    precision: str
-    logit_rel_err_limit: float
-    raw: dict
-
-    @classmethod
-    def load(cls, path: str | Path) -> "NetConfig":
-        raw = json.loads(Path(path).read_text())
-        return cls.from_dict(raw)
-
-    @classmethod
-    def from_dict(cls, raw: dict) -> "NetConfig":
-        pr = raw["pruning"]
-        return cls(
-            name=raw["name"],
-            conv_channels=tuple(tuple(c) for c in raw["conv_channels"]),
-            pool_after=frozenset(raw["pool_after"]),
-            input_hw=int(raw["input_hw"]),
-            num_classes=int(raw["num_classes"]),
-            kernel=int(raw["kernel"]),
-            sparsity=float(pr["sparsity"]),
-            zero_pattern_ratio=float(pr["zero_pattern_ratio"]),
-            patterns_per_layer=tuple(pr["patterns_per_layer"]),
-            weight_seed=int(raw["weight_seed"]),
-            precision=raw["precision"],
-            logit_rel_err_limit=float(raw["correct"]["logit_rel_err_limit"]),
-            raw=raw,
-        )
-
-    def conv_out_hw(self) -> list[int]:
-        """Output side of each conv (stride-1 SAME convs; pools halve)."""
-        out, hw = [], self.input_hw
-        for i in range(1, len(self.conv_channels) + 1):
-            out.append(hw)
-            if i in self.pool_after:
-                hw //= 2
-        return out
 
 
 # ----------------------------------------------------------------- weights
@@ -118,9 +57,10 @@ def _allocate_fractions(sizes, nonzero_frac, target_mean_size):
     return nonzero_frac * w / w.sum()
 
 
-def _pruned_layer(c_in, c_out, n_patterns, zero_ratio, sparsity, rng, k=9):
+def pruned_conv(c_in, c_out, n_patterns, zero_ratio, sparsity, rng, k=9):
     """(weights [c_out, c_in, k], pattern bits [c_out, c_in]) of one conv
-    whose kernels use ``n_patterns`` patterns (the all-zero one included)."""
+    whose kernels use ``n_patterns`` patterns (the all-zero one included),
+    drawn from ``rng``."""
     n_nonzero = max(1, n_patterns - 1)
     mean_size = k * (1.0 - sparsity) / max(1.0 - zero_ratio, 1e-9)
     mean_size = float(np.clip(mean_size, 1.0, k))
@@ -142,35 +82,6 @@ def _pruned_layer(c_in, c_out, n_patterns, zero_ratio, sparsity, rng, k=9):
     masks = ((bits[..., None] >> np.arange(k)) & 1).astype(np.float64)
     w = rng.normal(0.0, 1.0 / np.sqrt(max(c_in * k, 1)), size=(c_out, c_in, k))
     return (w * masks).astype(np.float32), bits
-
-
-def make_weights(cfg: NetConfig) -> tuple[dict, dict]:
-    """``(params, pattern_bits)`` drawn from ``cfg.weight_seed``.
-
-    ``params`` is ``{convN: {w: [c_out, c_in, 3, 3], b}, fc: {w: [feat,
-    classes], b}}`` as numpy float32; biases are zero.
-    """
-    rng = np.random.default_rng(cfg.weight_seed)
-    k = cfg.kernel * cfg.kernel
-    params, bits = {}, {}
-    for i, (c_in, c_out) in enumerate(cfg.conv_channels, start=1):
-        w, b = _pruned_layer(
-            c_in, c_out, cfg.patterns_per_layer[i - 1],
-            cfg.zero_pattern_ratio, cfg.sparsity, rng, k,
-        )
-        params[f"conv{i}"] = {
-            "w": w.reshape(c_out, c_in, cfg.kernel, cfg.kernel),
-            "b": np.zeros((c_out,), np.float32),
-        }
-        bits[f"conv{i}"] = b
-    feat = cfg.conv_channels[-1][1]
-    fc_rng = np.random.default_rng([cfg.weight_seed, 1])
-    params["fc"] = {
-        "w": fc_rng.normal(0.0, np.sqrt(1.0 / feat), (feat, cfg.num_classes))
-        .astype(np.float32),
-        "b": np.zeros((cfg.num_classes,), np.float32),
-    }
-    return params, bits
 
 
 # ------------------------------------------------------------- reference
@@ -196,60 +107,25 @@ def _three_pass(op, x, w):
     return (op(xh, wh) + op(xh, wl) + op(xl, wh)).astype(jnp.float32)
 
 
-def forward(cfg: NetConfig, params: dict, x, precision: str = "highest"):
-    """Logits ``[B, classes]`` of images ``x [B, C, H, W]``.
-
-    ``precision``: ``"highest"`` (the reference) or ``"three_pass"`` (the
-    control).
-    """
-    import jax
-    import jax.numpy as jnp
-
-    def conv(a, w):
-        return jax.lax.conv_general_dilated(
-            a, w, (1, 1), "SAME",
-            dimension_numbers=("NCHW", "OIHW", "NCHW"),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32,
-        )
-
-    def matmul(a, w):
-        return jnp.matmul(
-            a, w, precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32,
-        )
-
+def at_precision(op, precision: str):
+    """``op(activations, weights)``, a float32 op at ``Precision.HIGHEST``,
+    as the reference (``"highest"``) or the control (``"three_pass"``)
+    computes it."""
+    if precision == "highest":
+        return op
     if precision == "three_pass":
-        conv = _partial3(conv)
-        matmul = _partial3(matmul)
-    elif precision != "highest":
-        raise ValueError(f"unknown precision {precision!r}")
-
-    for i in range(1, len(cfg.conv_channels) + 1):
-        p = params[f"conv{i}"]
-        x = conv(x, p["w"]) + p["b"][None, :, None, None]
-        x = x / (jnp.std(x, axis=(2, 3), keepdims=True) + 1e-5)
-        x = jax.nn.relu(x)
-        if i in cfg.pool_after:
-            x = jax.lax.reduce_window(
-                x, -jnp.inf, jax.lax.max, (1, 1, 2, 2), (1, 1, 2, 2), "VALID"
-            )
-    x = x.mean(axis=(2, 3))
-    return matmul(x, params["fc"]["w"]) + params["fc"]["b"]
+        return lambda a, w: _three_pass(op, a, w)
+    raise ValueError(f"unknown precision {precision!r}")
 
 
-def _partial3(op):
-    return lambda a, w: _three_pass(op, a, w)
-
-
-def logits_in_blocks(cfg, params, images: np.ndarray, block: int,
+def logits_in_blocks(net, cfg, params, images: np.ndarray, block: int,
                      precision: str = "highest") -> np.ndarray:
-    """``forward`` over ``images`` in blocks of ``block`` rows (the last
-    block zero-padded, so one shape compiles)."""
+    """Family ``net``'s ``forward`` over ``images`` in blocks of ``block``
+    rows (the last block zero-padded, so one shape compiles)."""
     import jax
     import jax.numpy as jnp
 
-    fn = jax.jit(lambda p, x: forward(cfg, p, x, precision))
+    fn = jax.jit(lambda p, x: net.forward(cfg, p, x, precision))
     dev_params = jax.device_put(params)
     out = []
     for i in range(0, len(images), block):

@@ -33,11 +33,10 @@ def main(names: list[str]) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    import model
+    import run as harness
     import spec as cellspec
-    from repro.engine import CompileOptions, compile_network, make_forward
+    from repro.engine import make_forward
     from repro.kernels import ops
-    from repro.models.cnn import CNNConfig
 
     # a compile for a described chip cannot be read back from the
     # persistent cache: keep it out
@@ -52,16 +51,9 @@ def main(names: list[str]) -> int:
     for name in names or [w["name"] for w in bench["workloads"]]:
         cell = cellspec.load_cell(ROOT, name)
         cfg, b = cell.config, int(cell.mix["batch_slots"])
-        params, bits = model.make_weights(cfg)
-        net = CNNConfig(
-            conv_channels=cfg.conv_channels, pool_after=cfg.pool_after,
-            num_classes=cfg.num_classes, input_hw=cfg.input_hw,
-            kernel=cfg.kernel,
-        )
-        prog = compile_network(net, params, bits, options=CompileOptions(
-            precision=cfg.precision, **cfg.raw["compile"]))
+        params, prog = harness.build_program(cell)
         fwd = make_forward(prog, backend="pallas")
-        shape = (b, cfg.conv_channels[0][0], cfg.input_hw, cfg.input_hw)
+        shape = (b, cfg.in_channels, cfg.input_hw, cfg.input_hw)
         x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
         valid = jax.ShapeDtypeStruct((b,), jnp.bool_, sharding=chip)
         t = time.perf_counter()
@@ -76,7 +68,9 @@ def main(names: list[str]) -> int:
         pr = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), params
         )
-        ref = jax.jit(lambda p, a: model.forward(cfg, p, a)).lower(pr, xr).compile()
+        ref = jax.jit(
+            lambda p, a: cell.network.forward(cfg, p, a)
+        ).lower(pr, xr).compile()
         print(f"{name}: reference at block {rb}: {ref.memory_analysis()}", flush=True)
     return 0
 

@@ -12,11 +12,12 @@ shape the window uses; the window then runs for ``--seconds``. With
 metrics are reported; with ``--trace 0`` the end-to-end metrics.
 
 Every answer the window produced is then compared with the plain float32
-reference (``model.py``) on the same pruned weights; ``correct`` says
-whether all of them lie within the configuration's limit. The last lines
-on stderr give each compared number beside its limit, and the last stdout
-line is the result as JSON. Off a TPU, or with fewer chips than the cell
-asks for, it exits non-zero and prints no result.
+reference (the family's ``forward``, ``spec.py``) on the same pruned
+weights; ``correct`` says whether all of them lie within the
+configuration's limit. The last lines on stderr give each compared number
+beside its limit, and the last stdout line is the result as JSON. Off a
+TPU, or with fewer chips than the cell asks for, it exits non-zero and
+prints no result.
 """
 
 from __future__ import annotations
@@ -47,7 +48,7 @@ sys.path.insert(0, str(HERE))
 import spec as cellspec  # noqa: E402
 import traffic  # noqa: E402
 import work  # noqa: E402
-from model import logits_in_blocks, make_weights  # noqa: E402
+from model import logits_in_blocks  # noqa: E402
 
 CACHE_DIR = ROOT / ".jax_cache"
 # host spans the benchmark puts around its calls into the program; they
@@ -210,17 +211,29 @@ def drive_offline(session, run: Run, images: np.ndarray, seed: int,
     step, submit = session.step, top_up
     if trace:
         step, submit = _annotate(step, "step"), _annotate(top_up, "submit")
-    done = []
+    done, submitted, ends = [], [], []
     batches0 = session.backend.batches_run
     run.setup_s = time.monotonic() - T_START
     with _profiled(trace, run):
         t0 = time.perf_counter()
         while True:
             submit()
+            submitted.append(time.perf_counter())
             done.extend(step())
-            if time.perf_counter() - t0 >= run.seconds:
+            ends.append(time.perf_counter())
+            if ends[-1] - t0 >= run.seconds:
                 break
         run.window_s = time.perf_counter() - t0
+    # a slow step shows without a trace: the harness's submit, or the program
+    ends, submitted = np.array(ends), np.array(submitted)
+    submit_ms = (submitted - np.append(t0, ends[:-1])) * 1e3
+    program_ms = (ends - submitted) * 1e3
+    step_ms = submit_ms + program_ms
+    slow = int(np.argmax(step_ms))
+    _log(f"window steps: {len(step_ms)}, ms each: median {np.median(step_ms):.2f}, "
+         f"{int(np.sum(step_ms > 1.5 * np.median(step_ms)))} over 1.5x the median; "
+         f"slowest, step {slow}: submit {submit_ms[slow]:.2f}, session.step "
+         f"{program_ms[slow]:.2f}; submit at most {submit_ms.max():.2f}")
     run.forwards = session.backend.batches_run - batches0
     run.images = len(done)
     run.scheduler = session.scheduler.metrics
@@ -236,7 +249,7 @@ def start_loadgen(cell: cellspec.Cell, seed: int, seconds: float):
         stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True,
     )
     child.stdin.write(json.dumps({
-        "seed": seed, "pool": mix["pool"], "channels": cfg.conv_channels[0][0],
+        "seed": seed, "pool": mix["pool"], "channels": cfg.in_channels,
         "hw": cfg.input_hw, "arrivals": mix["arrivals"], "seconds": seconds,
         "connections": mix["connections"], "warm": mix["warm"],
         "timeout_s": ANSWER_TIMEOUT_S,
@@ -313,23 +326,25 @@ def compare(answers: list, ref: np.ndarray) -> float:
     return worst
 
 
-def build_session(cell: cellspec.Cell):
-    """The cell's pruned weights and its program's serving session: the
-    host compile as a user runs it, then ``classify_session`` at the mix's
-    batch."""
-    from repro.engine import CompileOptions, compile_network
-    from repro.models.cnn import CNNConfig
-    from repro.serve import classify_session
+def build_program(cell: cellspec.Cell):
+    """The cell's pruned weights and its program, compiled on the host as a
+    user compiles it."""
+    from repro.engine import CompileOptions
 
-    cfg = cell.config
-    params, bits = make_weights(cfg)
-    net = CNNConfig(
-        conv_channels=cfg.conv_channels, pool_after=cfg.pool_after,
-        num_classes=cfg.num_classes, input_hw=cfg.input_hw, kernel=cfg.kernel,
-    )
-    prog = compile_network(net, params, bits, options=CompileOptions(
+    net, cfg = cell.network, cell.config
+    params, inputs = net.make_weights(cfg)
+    prog = net.build_program(cfg, params, inputs, CompileOptions(
         precision=cfg.precision, **cfg.raw["compile"],
     ))
+    return params, prog
+
+
+def build_session(cell: cellspec.Cell):
+    """The cell's pruned weights and its program's serving session:
+    ``classify_session`` at the mix's batch."""
+    from repro.serve import classify_session
+
+    params, prog = build_program(cell)
     return params, classify_session(prog, batch_slots=cell.mix["batch_slots"])
 
 
@@ -362,9 +377,7 @@ def _run_cell(cell, seed, seconds, trace, require_chip, child) -> dict:
     session.warmup()
     run.warmup_s = time.monotonic() - t
     _log(f"set-up: warmup {run.warmup_s:.2f} s")
-    images = traffic.make_images(
-        seed, mix["pool"], cfg.conv_channels[0][0], cfg.input_hw
-    )
+    images = traffic.make_images(seed, mix["pool"], cfg.in_channels, cfg.input_hw)
     if mix["mode"] == "offline":
         answers = drive_offline(session, run, images, seed, trace)
         attempted, failed, unanswered = len(answers), 0, 0
@@ -384,7 +397,8 @@ def _run_cell(cell, seed, seconds, trace, require_chip, child) -> dict:
     gc.collect()
     jax.clear_caches()
     t = time.monotonic()
-    ref = logits_in_blocks(cfg, params, images, min(REF_BLOCK, len(images)))
+    ref = logits_in_blocks(cell.network, cfg, params, images,
+                           min(REF_BLOCK, len(images)))
     _log(f"reference over {len(images)} images: {time.monotonic() - t:.1f} s")
     err = compare(answers, ref)
     limit = cfg.logit_rel_err_limit
@@ -396,7 +410,7 @@ def _run_cell(cell, seed, seconds, trace, require_chip, child) -> dict:
     }
     correct = err <= limit and unanswered == 0 and len(answers) >= 1
 
-    run.layers = work.network_work(cfg, params, cfg.precision)
+    run.layers = cell.network.network_work(cfg, params, cfg.precision)
     run.peak = work.peak_for(kind) if require_chip else None
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):

@@ -244,7 +244,7 @@ def main(argv=None) -> int:
     devtrace.reduce_dir = reduce_and_keep
     run = harness.Run(cell=cell, seconds=args.seconds)
     images = traffic.make_images(
-        args.seed, cell.mix["pool"], cell.config.conv_channels[0][0],
+        args.seed, cell.mix["pool"], cell.config.in_channels,
         cell.config.input_hw,
     )
     harness.drive_offline(session, run, images, args.seed, trace=True)

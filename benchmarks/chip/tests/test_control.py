@@ -20,14 +20,14 @@ import pytest
 HERE = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(HERE))
 import control  # noqa: E402
-import model  # noqa: E402
+import spec  # noqa: E402
 import traffic  # noqa: E402
 
 
 @pytest.mark.parametrize("config,n", [("vgg16_imagenet", 2)])
 def test_control_reads_above_the_limit(config, n):
-    cfg = model.NetConfig.load(HERE / "configs" / f"{config}.json")
-    params, _ = model.make_weights(cfg)
-    images = traffic.make_images(2**31 + 3, n, cfg.conv_channels[0][0], cfg.input_hw)
-    err = control.control_reading(cfg, params, images, block=n)
+    net, cfg = spec.load_config(HERE / "configs" / f"{config}.json")
+    params, _ = net.make_weights(cfg)
+    images = traffic.make_images(2**31 + 3, n, cfg.in_channels, cfg.input_hw)
+    err = control.control_reading(net, cfg, params, images, block=n)
     assert err > cfg.logit_rel_err_limit

@@ -11,8 +11,8 @@ inference cell can have:
 * ``stale``: a step that returns the previous step's answers unchanged;
 * ``nan``: one logit of one answer made NaN where it is produced;
 * ``control``: every answer computed by the control, the reference at
-  three bf16 passes (``model.forward(..., "three_pass")``), in the
-  program's place.
+  three bf16 passes (the family's ``forward(..., "three_pass")``), in
+  the program's place.
 
 (The fault of an exchange between chips left out has no place here: every
 cell runs on one chip.) Run from the repository root:
@@ -20,7 +20,6 @@ cell runs on one chip.) Run from the repository root:
     PYTHONPATH=src JAX_PLATFORMS=cpu python -m pytest -q benchmarks/chip/tests
 """
 
-import json
 import sys
 from pathlib import Path
 
@@ -29,7 +28,7 @@ import pytest
 
 HERE = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(HERE))
-import model  # noqa: E402
+import cut  # noqa: E402
 import run  # noqa: E402
 import spec  # noqa: E402
 
@@ -47,41 +46,38 @@ MIXES = {
 def _cell(mode):
     """VGG16's layout at small widths and size, with the configuration's
     limit."""
-    raw = json.loads((HERE / "configs" / "vgg16_imagenet.json").read_text())
-    raw = dict(raw, conv_channels=[[3, 8], [8, 16], [16, 16]], pool_after=[2],
-               input_hw=8, num_classes=10,
-               pruning=dict(raw["pruning"], patterns_per_layer=[2, 4, 4]))
-    return spec.Cell(name=f"small.{mode}", chips=1,
-                     config=model.NetConfig.from_dict(raw), mix=MIXES[mode], end_to_end=[], per_layer=[])
+    net, cfg = cut.load("vgg16_imagenet", "small")
+    return spec.Cell(name=f"small.{mode}", chips=1, network=net, config=cfg,
+                     mix=MIXES[mode], end_to_end=[], per_layer=[])
 
 
-def _altered(out, last, x, cfg):
+def _altered(out, last, x, cell):
     return out.at[0, 0].add(0.5)
 
 
-def _half_batch(out, last, x, cfg):
+def _half_batch(out, last, x, cell):
     half = out.shape[0] // 2
     return jnp.concatenate([out[:half], out[:half]])
 
 
-def _stale(out, last, x, cfg):
+def _stale(out, last, x, cell):
     return out if last is None else last
 
 
-def _nan(out, last, x, cfg):
+def _nan(out, last, x, cell):
     return out.at[0, 0].set(jnp.nan)
 
 
-def _control(out, last, x, cfg):
-    params, _ = model.make_weights(cfg)
-    return model.forward(cfg, params, x, "three_pass")
+def _control(out, last, x, cell):
+    params, _ = cell.network.make_weights(cell.config)
+    return cell.network.forward(cell.config, params, x, "three_pass")
 
 
 FAULTS = {"altered": _altered, "half_batch": _half_batch, "stale": _stale,
           "nan": _nan, "control": _control}
 
 
-def _break(monkeypatch, fault, cfg):
+def _break(monkeypatch, fault, cell):
     make = service.make_forward
 
     def broken_make_forward(*a, **k):
@@ -90,7 +86,7 @@ def _break(monkeypatch, fault, cfg):
 
         def fn(x, valid=None):
             out = fwd(x, valid)
-            got = FAULTS[fault](out, last[0], x, cfg)
+            got = FAULTS[fault](out, last[0], x, cell)
             last[0] = out
             return got
 
@@ -119,7 +115,7 @@ def test_sound_program_is_correct(mode):
 @pytest.mark.parametrize("mode", sorted(MIXES))
 def test_broken_program_is_not_correct(monkeypatch, mode, fault):
     cell = _cell(mode)
-    _break(monkeypatch, fault, cell.config)
+    _break(monkeypatch, fault, cell)
     res = run.run_cell(cell, seed=2**31 + 7, seconds=1.0, trace=False,
                        require_chip=False)
     assert not res["correct"], res["checks"]

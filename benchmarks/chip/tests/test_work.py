@@ -12,32 +12,22 @@ import pytest
 
 HERE = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(HERE))
-import model  # noqa: E402
+import cut  # noqa: E402
+import spec  # noqa: E402
 import work  # noqa: E402
 
-from repro.engine import CompileOptions, compile_network  # noqa: E402
-from repro.models.cnn import CNNConfig, synthetic_vgg16  # noqa: E402
-
-
-def _cfg(name):
-    return model.NetConfig.load(HERE / "configs" / f"{name}.json")
+from repro.engine import CompileOptions  # noqa: E402
+from repro.models.cnn import synthetic_vgg16  # noqa: E402
 
 
 def _small():
     """VGG16's first three convs, at their published widths, on 16x16."""
-    raw = dict(_cfg("vgg16_imagenet").raw)
-    raw.update(conv_channels=raw["conv_channels"][:3], pool_after=[2],
-               input_hw=16)
-    raw["pruning"] = dict(raw["pruning"],
-                          patterns_per_layer=raw["pruning"]["patterns_per_layer"][:3])
-    return model.NetConfig.from_dict(raw)
+    return cut.load("vgg16_imagenet", "first3")
 
 
-def _lower(cfg, params, bits, block, tile):
-    net = CNNConfig(conv_channels=cfg.conv_channels, pool_after=cfg.pool_after,
-                    num_classes=cfg.num_classes, input_hw=cfg.input_hw)
-    return compile_network(net, params, bits,
-                           options=CompileOptions(block=block, tile=tile))
+def _lower(net, cfg, params, bits, block, tile):
+    return net.build_program(cfg, params, bits,
+                             CompileOptions(block=block, tile=tile))
 
 
 def _params_of(prog):
@@ -52,22 +42,22 @@ def _params_of(prog):
 
 
 def test_two_lowerings_give_the_same_work():
-    cfg = _small()
-    params, bits = model.make_weights(cfg)
-    a = _lower(cfg, params, bits, block=128, tile=128)
-    b = _lower(cfg, params, bits, block=32, tile=64)
+    net, cfg = _small()
+    params, bits = net.make_weights(cfg)
+    a = _lower(net, cfg, params, bits, block=128, tile=128)
+    b = _lower(net, cfg, params, bits, block=32, tile=64)
     stored = [sum(op.bp.w_comp.size for op in p.convs) for p in (a, b)]
     assert stored[0] != stored[1]  # the bricks differ ...
-    want = work.network_work(cfg, params)
+    want = net.network_work(cfg, params)
     for prog in (a, b):
         # ... and the count does not
-        assert work.network_work(cfg, _params_of(prog)) == want
+        assert net.network_work(cfg, _params_of(prog)) == want
 
 
 def test_counts_are_useful_work():
-    cfg = _small()
-    params, _ = model.make_weights(cfg)
-    layers = work.network_work(cfg, params)
+    net, cfg = _small()
+    params, _ = net.make_weights(cfg)
+    layers = net.network_work(cfg, params)
     conv1 = params["conv1"]["w"]
     nnz = np.count_nonzero(conv1)
     assert 0 < nnz < conv1.size
@@ -95,8 +85,8 @@ def test_unknown_device_kind_raises():
 
 @pytest.mark.parametrize("config,dataset", [("vgg16_imagenet", "imagenet")])
 def test_weights_match_the_programs_synthetic_vgg16(config, dataset):
-    cfg = _cfg(config)
-    params, bits = model.make_weights(cfg)
+    net, cfg = spec.load_config(HERE / "configs" / f"{config}.json")
+    params, bits = net.make_weights(cfg)
     _, theirs, their_bits = synthetic_vgg16(dataset, seed=cfg.weight_seed,
                                             num_classes=cfg.num_classes)
     for name in theirs:

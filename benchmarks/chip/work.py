@@ -1,7 +1,8 @@
 """Useful work, minimal bytes and the chip's peaks.
 
-The count is the work any implementation of the pruned network must do,
-taken from the model and never from its lowering:
+Each network family counts its layers (``network_work`` in
+``networks/<family>.py``) as the work any implementation of the pruned
+network must do, taken from the model and never from its lowering:
 
 * useful FLOPs of a conv: 2 x output positions x nonzero weights; of the
   FC: 2 x inputs x outputs (it is dense);
@@ -19,8 +20,6 @@ from __future__ import annotations
 import dataclasses
 import json
 from pathlib import Path
-
-import numpy as np
 
 PEAKS_FILE = Path(__file__).with_name("peaks.json")
 ACT_BYTES = 4  # float32 activations
@@ -42,31 +41,6 @@ class LayerWork:
             "memory": (self.act_bytes_per_image * batch + self.weight_bytes)
             / peak["hbm_bytes_per_s"],
         }
-
-
-def network_work(cfg, params: dict, precision: str = "fp32") -> list[LayerWork]:
-    """One :class:`LayerWork` per spmm layer (every conv, then the FC) of
-    the pruned weights ``params`` (``{convN: {w}, fc: {w}}``)."""
-    wbytes = WEIGHT_BYTES[precision]
-    out = []
-    for i, ((c_in, c_out), hw) in enumerate(
-        zip(cfg.conv_channels, cfg.conv_out_hw()), start=1
-    ):
-        nnz = int(np.count_nonzero(np.asarray(params[f"conv{i}"]["w"])))
-        out.append(LayerWork(
-            f"conv{i}",
-            flops_per_image=2.0 * hw * hw * nnz,
-            act_bytes_per_image=float((c_in + c_out) * hw * hw * ACT_BYTES),
-            weight_bytes=float(nnz * wbytes),
-        ))
-    feat, classes = np.asarray(params["fc"]["w"]).shape
-    out.append(LayerWork(
-        "fc",
-        flops_per_image=2.0 * feat * classes,
-        act_bytes_per_image=float((feat + classes) * ACT_BYTES),
-        weight_bytes=float(feat * classes * wbytes),
-    ))
-    return out
 
 
 def peak_for(device_kind: str) -> dict:
