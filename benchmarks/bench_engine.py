@@ -184,7 +184,7 @@ def _bench_network(name: str, cfg: CNNConfig, batch: int,
                    sparsities=SPARSITIES) -> dict:
     x = jax.random.normal(
         jax.random.PRNGKey(0),
-        (batch, cfg.conv_channels[0][0], cfg.input_hw, cfg.input_hw),
+        (batch, cfg.in_channels, cfg.input_hw, cfg.input_hw),
     )
     entries = []
     dense_fn = jax.jit(lambda p, xx: cnn_apply(cfg, p, xx))
@@ -267,7 +267,7 @@ def _service_throughput(batch_slots: int = SERVICE_SLOTS,
                            collect_stats=True, tracer=tracer)
     n = sum(SERVICE_BURSTS)
     images = np.array(jax.random.normal(
-        jax.random.PRNGKey(3), (n, cfg.conv_channels[0][0],
+        jax.random.PRNGKey(3), (n, cfg.in_channels,
                                 cfg.input_hw, cfg.input_hw)
     ), np.float32)
 
@@ -379,7 +379,7 @@ def _http_service_throughput(batch_slots: int = SERVICE_SLOTS) -> dict:
     prog = compile_network(cfg, params, bits)
     n = sum(SERVICE_BURSTS)
     images = np.array(jax.random.normal(
-        jax.random.PRNGKey(3), (n, cfg.conv_channels[0][0],
+        jax.random.PRNGKey(3), (n, cfg.in_channels,
                                 cfg.input_hw, cfg.input_hw)
     ), np.float32)
     payloads = [{"image": img.tolist()} for img in images]

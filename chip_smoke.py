@@ -77,7 +77,7 @@ def log(msg: str) -> None:
 def make_images(cfg, n: int, seed: int) -> np.ndarray:
     """8-bit pixels scaled to [-2, 2): exact in float32 and short in JSON."""
     rng = np.random.default_rng(seed)
-    shape = (n, cfg.conv_channels[0][0], cfg.input_hw, cfg.input_hw)
+    shape = (n, cfg.in_channels, cfg.input_hw, cfg.input_hw)
     return ((rng.integers(0, 256, shape) - 128) / 64.0).astype(np.float32)
 
 

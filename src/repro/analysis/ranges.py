@@ -3,8 +3,9 @@
 An execution-free abstract interpreter over
 :class:`~repro.engine.program.CompiledNetwork`: starting from a declared
 input interval it pushes interval bounds through every op of the
-compiled schedule — conv-as-spmm + bias, ``channel_norm``, ReLU, 2x2
-maxpool, global average pool, the FC head — and, for quantized
+compiled schedule — conv-as-spmm + bias, ``channel_norm``, the residual
+add, ReLU, max pools, global average pool, the FC head — following each
+conv's ``src``/``residual`` edges — and, for quantized
 programs, derives activation-independent worst-case extrema of the int8
 spmm's accumulators straight from the stored bricks and scales.  Where
 ``analysis/verify.py`` proves the program is *structurally* sound (the
@@ -27,8 +28,14 @@ interval by the half-step round-off ``amax / (2 * QMAX)``):
   under adversarial inputs, which the certificate records as
   ``fp32_safe`` and a V504 warning rather than an error — only
   non-finite (genuinely divergent) bounds are an error.
-* ReLU / maxpool / global average pool map ``[lo, hi]`` to
-  ``[max(lo, 0), max(hi, 0)]`` / identity / identity.
+* folded batch norm (``norm == 'none'``): the per-channel scale and
+  shift are the stored weights and bias, so the spmm + bias bound above
+  is already its image; no further transfer.
+* residual add: the interval sum ``[lo_a + lo_b, hi_a + hi_b]`` of the
+  conv's bound and the residual tensor's.
+* ReLU / max pools (their ``-inf`` padding never wins) / global average
+  pool map ``[lo, hi]`` to ``[max(lo, 0), max(hi, 0)]`` / identity /
+  identity.
 
 Accumulator model (int8 path, mirrors
 ``core/sparse.pattern_spmm_xla_quant``): each scan step contracts one
@@ -434,19 +441,30 @@ def analyze_network(
     diverged = False
     fp32_edge: str | None = None
 
+    tensors = {"input": (lo, hi)}  # certified bound of each kept tensor
     for conv in program.convs:
-        # 'same' conv padding inserts zeros into the patches, so the
-        # spmm input interval always contains 0
+        if conv.src is not None:
+            lo, hi = tensors[conv.src]
+        # k // 2 conv padding inserts zeros into the patches, so the spmm
+        # input interval contains 0 (for a 1x1 conv, a sound widening)
         in_lo, in_hi = min(lo, 0.0), max(hi, 0.0)
         if conv.bp.w_scales is not None:
             in_lo, in_hi = _quantized_interval(in_lo, in_hi)
         pre_lo, pre_hi = _spmm_bounds(
             conv.bp, conv.bias, conv.c_out, in_lo, in_hi
         )
-        # channel_norm (divisor in [eps, inf)) then ReLU; maxpool is the
-        # identity on intervals
-        act_lo = max(min(pre_lo, 0.0) / NORM_EPS, 0.0)
-        act_hi = max(max(pre_hi, 0.0) / NORM_EPS, 0.0)
+        # channel_norm (divisor in [eps, inf)) where the layer keeps one
+        # (folded batch norm is already in the bound), the residual add,
+        # ReLU; max pools are the identity on intervals
+        act_lo, act_hi = pre_lo, pre_hi
+        if conv.norm == "channel":
+            act_lo = min(pre_lo, 0.0) / NORM_EPS
+            act_hi = max(pre_hi, 0.0) / NORM_EPS
+        if conv.residual is not None:
+            r_lo, r_hi = tensors[conv.residual]
+            act_lo, act_hi = act_lo + r_lo, act_hi + r_hi
+        if conv.relu:
+            act_lo, act_hi = max(act_lo, 0.0), max(act_hi, 0.0)
         facts = _analyze_operand(conv.bp, conv.name, program.cell_bits, r) \
             if quantized else {}
         layers.append(LayerRanges(
@@ -467,7 +485,7 @@ def analyze_network(
         elif fp32_safe and max(abs(b) for b in bounds) > _F32_MAX:
             fp32_safe = False
             fp32_edge = conv.name
-        lo, hi = act_lo, act_hi
+        lo, hi = tensors[conv.name] = act_lo, act_hi
 
     # global average pool preserves the interval; the FC head is a plain
     # spmm + bias (its pre and act bounds coincide — the logits)

@@ -44,7 +44,9 @@ V206   mapping geometry is consistent with the packed operands: the OU
        mapping stores the cell-slice count its payload actually occupies
 V207   ``patch_order`` is ``'channel'`` or ``'tap'``, and ``'tap'`` only
        where the layer's K (``c_in * kernel**2``) spans more than one block
-V301   inter-layer shape chaining (channels, spatial dims, fc head)
+V301   inter-layer shapes along each conv's ``src``/``residual`` edges
+       (channels, spatial dims, fc head) and agreement with the config's
+       layer list
 V302   precision contract: ``precision``/``cell_bits`` agree with the
        stored payloads
 V303   program block/tile geometry agrees with every operand
@@ -88,6 +90,7 @@ from repro.core.patterns import ALL_ZERO, pattern_sizes
 from repro.core.quantize import QMAX, cell_slices, compose_cell_slices
 from repro.core.sparse import REORDERS, BlockPatternWeight
 from repro.engine.lowering import PATCH_ORDERS
+from repro.models.cnn import POOLS, conv_out_hw, pool_out_hw
 
 __all__ = [
     "verify_bp",
@@ -675,49 +678,79 @@ def verify_network(program, report: Report | None = None) -> Report:
                     layer=conv.name, location="mapping.cells_per_weight",
                 )
 
-    # V301 inter-layer chain
-    if len(program.convs) != cfg.num_convs:
+    # V301 inter-layer shapes, along each conv's src / residual edges
+    specs = cfg.layers()
+    if len(program.convs) != len(specs):
         r.add(
             "V301",
             f"{len(program.convs)} compiled convs != config's "
-            f"{cfg.num_convs}",
+            f"{len(specs)}",
             location="convs",
         )
-    hw = cfg.input_hw
-    prev_out = cfg.conv_channels[0][0] if cfg.conv_channels else None
-    for i, conv in enumerate(program.convs, start=1):
-        if conv.c_in != prev_out:
+    # tensor name -> (channels, spatial side) of what the forward keeps
+    tensors = {"input": (cfg.in_channels, cfg.input_hw)}
+    prev = "input"
+    fields = ("name", "c_in", "c_out", "kernel", "stride", "src",
+              "residual", "relu", "norm", "pool")
+    for conv, spec in zip(program.convs, specs):
+        got = tuple(getattr(conv, f) for f in fields)
+        want = tuple(getattr(spec, f) for f in fields)
+        if got != want:
+            diff = [f"{f}={g!r} (config: {w!r})"
+                    for f, g, w in zip(fields, got, want) if g != w]
             r.add(
                 "V301",
-                f"c_in={conv.c_in} does not chain from previous layer's "
-                f"c_out={prev_out}",
+                "layer disagrees with the config's: " + ", ".join(diff),
+                layer=conv.name, location="spec",
+            )
+        src = prev if conv.src is None else conv.src
+        if src not in tensors:
+            r.add(
+                "V301",
+                f"reads tensor {src!r}, which no earlier op produces",
+                layer=conv.name, location="src",
+            )
+            break
+        c_src, hw_src = tensors[src]
+        if conv.c_in != c_src:
+            r.add(
+                "V301",
+                f"c_in={conv.c_in} does not chain from {src}'s "
+                f"c_out={c_src}",
                 layer=conv.name, location="c_in",
             )
-        if i <= cfg.num_convs and (conv.c_in, conv.c_out) != tuple(
-            cfg.conv_channels[i - 1]
-        ):
-            r.add(
-                "V301",
-                f"(c_in, c_out)=({conv.c_in}, {conv.c_out}) != config "
-                f"channels {tuple(cfg.conv_channels[i - 1])}",
-                layer=conv.name, location="channels",
-            )
+        hw = conv_out_hw(hw_src, conv.kernel, conv.stride)
         if conv.out_hw != hw:
             r.add(
                 "V301",
                 f"out_hw={conv.out_hw} != chained spatial size {hw}",
                 layer=conv.name, location="out_hw",
             )
-        if conv.pool_after != (i in cfg.pool_after):
+        if conv.residual is not None:
+            if conv.residual not in tensors:
+                r.add(
+                    "V301",
+                    f"adds tensor {conv.residual!r}, which no earlier op "
+                    "produces",
+                    layer=conv.name, location="residual",
+                )
+            elif tensors[conv.residual] != (conv.c_out, hw):
+                r.add(
+                    "V301",
+                    f"residual {conv.residual} is (channels, side) "
+                    f"{tensors[conv.residual]}, the conv's output "
+                    f"{(conv.c_out, hw)}",
+                    layer=conv.name, location="residual",
+                )
+        if conv.pool not in (None, *POOLS):
             r.add(
                 "V301",
-                f"pool_after={conv.pool_after} disagrees with config "
-                f"pool_after={sorted(cfg.pool_after)}",
-                layer=conv.name, location="pool_after",
+                f"unknown pool {conv.pool!r} (known: {sorted(POOLS)})",
+                layer=conv.name, location="pool",
             )
-        if conv.pool_after:
-            hw //= 2
-        prev_out = conv.c_out
+            break
+        tensors[conv.name] = (conv.c_out, pool_out_hw(hw, conv.pool))
+        prev = conv.name
     if program.convs and program.fc.d_in != program.convs[-1].c_out:
         r.add(
             "V301",

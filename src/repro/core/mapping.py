@@ -272,21 +272,18 @@ def _blocks_for_channel(
     sizes_c: np.ndarray,
 ) -> list[PatternBlock]:
     """Group one input channel's kernels by pattern (paper Fig 4 reorder)."""
-    blocks: dict[int, list[int]] = {}
-    for out_ch, b in enumerate(bits_c):
-        b = int(b)
+    bits_c = np.asarray(bits_c)
+    out = []
+    for b in np.unique(bits_c).tolist():
         if b == ALL_ZERO:
             continue
-        blocks.setdefault(b, []).append(out_ch)
-    out = [
-        PatternBlock(
+        kernels = np.flatnonzero(bits_c == b)
+        out.append(PatternBlock(
             channel=channel,
             pattern=b,
             height=int(sizes_c[kernels[0]]),
-            kernel_ids=tuple(kernels),
-        )
-        for b, kernels in blocks.items()
-    ]
+            kernel_ids=tuple(kernels.tolist()),
+        ))
     # sort by pattern size descending (paper Fig 5), stable by pattern id
     out.sort(key=lambda blk: (-blk.height, blk.pattern))
     return out

@@ -48,23 +48,25 @@ class OUSchedule:
 
 def pattern_ou_schedule(mapping: LayerMapping) -> OUSchedule:
     """OUs of a pattern-pruned mapping: each placement tiles its columns
-    into ou_cols-wide OUs; every OU stays inside its pattern block."""
+    into ou_cols-wide OUs; every OU stays inside its pattern block.  A
+    block taller than ``ou_rows`` (a dense 7x7 kernel's 49 rows) is tiled
+    into ``ou_rows``-tall bands of the same channel and pattern too."""
     cfg = mapping.config
     xbars, wls, bls, chans, pats = [], [], [], [], []
     for p in mapping.placements:
-        if p.height > cfg.ou_rows:
-            # patterns are <= 9 nonzeros for 3x3 kernels; guard for generality
-            raise ValueError("pattern block taller than an OU is unsupported")
         n_full, rem = divmod(p.width_cells, cfg.ou_cols)
         n = n_full + (1 if rem else 0)
-        xbars.append(np.full(n, p.crossbar, dtype=np.int32))
-        wls.append(np.full(n, p.height, dtype=np.int32))
+        bands = -(-p.height // cfg.ou_rows)
+        heights = np.full(bands, cfg.ou_rows, dtype=np.int32)
+        heights[-1] = p.height - cfg.ou_rows * (bands - 1)
+        xbars.append(np.full(n * bands, p.crossbar, dtype=np.int32))
+        wls.append(np.repeat(heights, n))
         b = np.full(n, cfg.ou_cols, dtype=np.int32)
         if rem:
             b[-1] = rem
-        bls.append(b)
-        chans.append(np.full(n, p.block.channel, dtype=np.int32))
-        pats.append(np.full(n, p.block.pattern, dtype=np.int64))
+        bls.append(np.tile(b, bands))
+        chans.append(np.full(n * bands, p.block.channel, dtype=np.int32))
+        pats.append(np.full(n * bands, p.block.pattern, dtype=np.int64))
     if not xbars:
         z = np.zeros(0, dtype=np.int32)
         return OUSchedule(z, z, z, z, z.astype(np.int64))

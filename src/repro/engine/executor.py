@@ -6,9 +6,11 @@ tap-major where K spans more than one block), dispatches through
 ``kernels/ops.pattern_spmm`` (the Pallas TPU kernel on the chip; the XLA
 path, or the Pallas interpreter when asked for, on CPU) — which applies
 the stored inverse output permutation (the Output Indexing Unit) — then
-bias + shared ``channel_norm``/ReLU and the 2x2 maxpool where the
-schedule says so, matching ``cnn_apply`` on the pruned weights to
-numerical tolerance.
+the layer's epilogue: bias, the shared ``channel_norm`` where the layer
+keeps one, the residual add, ReLU and the pool, matching ``cnn_apply``
+(``resnet_apply`` for a ResNet) on the pruned weights to numerical
+tolerance.  The program is a graph in execution order: the forward keeps
+each layer's output by name, for a later layer's ``src`` or ``residual``.
 
 With ``collect_stats=True`` the forward additionally counts, per layer
 and per OU row-group (= (input channel, pattern) pair), how many input
@@ -66,7 +68,7 @@ from repro.engine.stats import (
 )
 from repro.kernels.ops import pattern_spmm, pattern_spmm_raw
 from repro.kernels.ops import _pad_to as _pad_axis_to_mult
-from repro.models.cnn import channel_norm, max_pool_2x2
+from repro.models.cnn import POOLS, channel_norm, conv_out_hw, out_sizes
 from repro.parallel.sharding import shard_block_pattern
 
 __all__ = [
@@ -74,22 +76,37 @@ __all__ = [
 ]
 
 # the stages of a layer, as the forward's ``jax.named_scope``s name them
-STAGES = ("patches", "spmm", "permute", "epilogue", "stats")
+STAGES = ("patches", "spmm", "permute", "epilogue", "residual", "stats")
+
+
+def _tap(xp: jax.Array, dy: int, dx: int, ho: int, wo: int, stride: int,
+         axes: tuple[int, int]) -> jax.Array:
+    """The ``(dy, dx)`` tap of the padded ``xp``: ``ho x wo`` positions
+    ``stride`` apart on the spatial ``axes``."""
+    start = [0] * xp.ndim
+    limit = list(xp.shape)
+    strides = [1] * xp.ndim
+    for ax, d, n in ((axes[0], dy, ho), (axes[1], dx, wo)):
+        start[ax], limit[ax], strides[ax] = d, d + stride * (n - 1) + 1, stride
+    return jax.lax.slice(xp, start, limit, strides)
 
 
 def extract_patches(
-    x: jax.Array, k: int, order: str = "channel", width: int | None = None
+    x: jax.Array, k: int, order: str = "channel", width: int | None = None,
+    stride: int = 1,
 ) -> jax.Array:
-    """im2col for stride-1 'same' convs: [B, C, H, W] -> [B*H*W, F].
+    """im2col for convs padded by ``k // 2`` on every side: [B, C, H, W]
+    -> [B*Ho*Wo, F], ``Ho = (H - 1) // stride + 1`` (likewise ``Wo``).
 
     The feature order matches ``lowering.conv_matrix(w, order)``:
 
     * ``'channel'`` — feature ``c * k*k + (dy*k + dx)``: the ``k*k`` taps
-      of one channel sit side by side, built as a ``[B, C, H, W, k*k]``
+      of one channel sit side by side, built as a ``[B, C, Ho, Wo, k*k]``
       stack transposed into rows;
     * ``'tap'`` — feature ``(dy*k + dx) * C + c``: the ``k*k`` shifted
-      slices of the padded NHWC activation, each ``C`` lanes wide,
-      concatenated along the feature axis (a plain copy).
+      (and strided) slices of the padded NHWC activation, each ``C`` lanes
+      wide, concatenated along the feature axis (a plain copy).  A 1x1
+      conv's patches are its input's strided NHWC view.
 
     ``F`` is ``C*k*k``, or ``width`` when given: the features are then
     zero-padded up to the spmm's padded K (``bp.k_in``) — tap-major
@@ -97,15 +114,16 @@ def extract_patches(
     """
     b, c, h, w = x.shape
     pad = k // 2
+    ho, wo = conv_out_hw(h, k, stride), conv_out_hw(w, k, stride)
     if order == "channel":
         xp = jnp.pad(x, ((0, 0), (0, 0), (pad, pad), (pad, pad)))
         taps = [
-            xp[:, :, dy : dy + h, dx : dx + w]
+            _tap(xp, dy, dx, ho, wo, stride, (2, 3))
             for dy in range(k)
             for dx in range(k)
         ]
-        patches = jnp.stack(taps, axis=-1)  # [B, C, H, W, k*k]
-        patches = patches.transpose(0, 2, 3, 1, 4).reshape(b * h * w, -1)
+        patches = jnp.stack(taps, axis=-1)  # [B, C, Ho, Wo, k*k]
+        patches = patches.transpose(0, 2, 3, 1, 4).reshape(b * ho * wo, -1)
         return patches if width is None else _pad_features(patches, width)
     if order != "tap":
         raise ValueError(f"unknown patch order {order!r}")
@@ -113,13 +131,13 @@ def extract_patches(
         x.transpose(0, 2, 3, 1), ((0, 0), (pad, pad), (pad, pad), (0, 0))
     )
     parts = [
-        xp[:, dy : dy + h, dx : dx + w, :]
+        _tap(xp, dy, dx, ho, wo, stride, (1, 2))
         for dy in range(k)
         for dx in range(k)
     ]
     if width is not None and width > c * k * k:
-        parts.append(jnp.zeros((b, h, w, width - c * k * k), x.dtype))
-    return jnp.concatenate(parts, axis=-1).reshape(b * h * w, -1)
+        parts.append(jnp.zeros((b, ho, wo, width - c * k * k), x.dtype))
+    return jnp.concatenate(parts, axis=-1).reshape(b * ho * wo, -1)
 
 
 def _pad_features(x: jax.Array, to: int) -> jax.Array:
@@ -312,11 +330,17 @@ def _run_conv(
     prepared,
     stat_masks: np.ndarray | None = None,
     valid: jax.Array | None = None,
+    residual: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array | None]:
     b, c, h, w = x.shape
+    h, w = conv_out_hw(h, op.kernel, op.stride), conv_out_hw(
+        w, op.kernel, op.stride
+    )
     with jax.named_scope("patches"):
-        # [B*H*W, bp.k_in], features in the order the weight rows are
-        patches = extract_patches(x, op.kernel, op.patch_order, op.bp.k_in)
+        # [B*Ho*Wo, bp.k_in], features in the order the weight rows are
+        patches = extract_patches(
+            x, op.kernel, op.patch_order, op.bp.k_in, op.stride
+        )
     counts = None
     if stat_masks is not None:
         with jax.named_scope("stats"):
@@ -331,9 +355,16 @@ def _run_conv(
     with jax.named_scope("epilogue"):
         y = y[:, : op.c_out] + jnp.asarray(op.bias)
         y = y.reshape(b, h, w, op.c_out).transpose(0, 3, 1, 2)
-        y = jax.nn.relu(channel_norm(y))
-        if op.pool_after:
-            y = max_pool_2x2(y)
+        if op.norm == "channel":
+            y = channel_norm(y)
+    if residual is not None:
+        with jax.named_scope("residual"):
+            y = y + residual
+    with jax.named_scope("epilogue"):
+        if op.relu:
+            y = jax.nn.relu(y)
+        if op.pool is not None:
+            y = POOLS[op.pool](y)
     return y, counts
 
 
@@ -353,7 +384,8 @@ def _run_fc(
 def _layer_windows(
     program: CompiledNetwork, x_shape, live_rows: int | None = None
 ) -> dict[str, int]:
-    """Windows (input positions) each conv layer sees for this input.
+    """Windows (output positions, ``batch * Ho * Wo``) each conv layer
+    sees for this input.
 
     ``live_rows`` overrides the batch size when some rows are dead slots
     (serving validity mask): only live samples contribute windows, so the
@@ -362,12 +394,9 @@ def _layer_windows(
     b, _, h, w = x_shape
     if live_rows is not None:
         b = live_rows
-    windows = {}
-    for op in program.convs:
-        windows[op.name] = b * h * w
-        if op.pool_after:
-            h, w = h // 2, w // 2
-    return windows
+    rows, cols = out_sizes(program.convs, h), out_sizes(program.convs, w)
+    return {op.name: b * rows[op.name][0] * cols[op.name][0]
+            for op in program.convs}
 
 
 def make_forward(
@@ -414,20 +443,24 @@ def make_forward(
     Names on the device: every operation the forward traces sits under
     the ``jax.named_scope`` pair ``<layer>/<stage>``, so its ``op_name``
     metadata reads ``jit(forward)/<layer>/<stage>/...``.  ``<layer>`` is
-    the conv's ``op.name`` (``conv1`` ...), then ``gap`` and ``fc``;
-    ``<stage>`` is one of :data:`STAGES`:
+    the conv's ``op.name`` (``conv1`` ..., or a ResNet's ``stem``,
+    ``layer1.0.conv1`` ..., ``layer1.0.downsample``), then ``gap`` and
+    ``fc``; ``<stage>`` is one of :data:`STAGES`:
 
     * ``patches`` — im2col in the layer's ``patch_order`` and the K
-      padding: channel-major, the SAME pad, tap slices, stack, the
-      transpose into rows and the pad; tap-major, the NCHW->NHWC
-      transpose, SAME pad, and one concatenation of the tap slices and
-      the zero K padding;
+      padding: channel-major, the ``k // 2`` pad, (strided) tap slices,
+      stack, the transpose into rows and the pad; tap-major, the
+      NCHW->NHWC transpose, the pad, and one concatenation of the tap
+      slices and the zero K padding;
     * ``spmm`` — the row padding, the ``pattern_spmm*`` kernel (or the
       XLA path) and the row slice; sharded, the scatter and ``psum``;
     * ``permute`` — the inverse output permutation (Output Indexing
       Unit);
     * ``epilogue`` — the column slice, bias, NHWC->NCHW transpose,
-      ``channel_norm``, ReLU and max-pool; for ``gap``, the mean;
+      ``channel_norm`` (where ``norm == 'channel'``), ReLU and the pool;
+      for ``gap``, the mean;
+    * ``residual`` — the add of the layer's ``residual`` tensor, between
+      the two halves of its epilogue (a ResNet block's conv3);
     * ``stats`` — the ``collect_stats`` skip counters.
 
     Profiles attribute device time by these names; they are a contract.
@@ -457,12 +490,15 @@ def make_forward(
     def forward(x: jax.Array, valid: jax.Array | None = None):
         traces["n"] += 1  # python side effect: runs once per trace
         counts = {}
+        tensors = {"input": x}  # each layer's output, for src / residual
         for op in program.convs:
             with jax.named_scope(op.name):
                 x, cnt = _run_conv(
-                    op, x, disp, prepared[op.name], stat_masks.get(op.name),
-                    valid,
+                    op, x if op.src is None else tensors[op.src], disp,
+                    prepared[op.name], stat_masks.get(op.name), valid,
+                    None if op.residual is None else tensors[op.residual],
                 )
+            tensors[op.name] = x
             if cnt is not None:
                 counts[op.name] = cnt
         with jax.named_scope("gap"), jax.named_scope("epilogue"):
@@ -509,7 +545,7 @@ def warmup_forward(fn, program: CompiledNetwork, batch_slots: int):
     """
     cfg = program.config
     x = jnp.zeros(
-        (batch_slots, cfg.conv_channels[0][0], cfg.input_hw, cfg.input_hw),
+        (batch_slots, cfg.in_channels, cfg.input_hw, cfg.input_hw),
         jnp.float32,
     )
     valid = np.zeros(batch_slots, bool)

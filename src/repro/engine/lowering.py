@@ -9,7 +9,13 @@ to (block, tile) multiples, and compressed into a
 structure (``nonzero_block_masks``), so reorder -> compress -> index
 produces real kernel operands and the compressed program computes
 bit-the-same weights as the pruned dense network.  The FC head is lowered
-onto the same path.
+onto the same path, and so are dense convs (a ResNet's stem, 1x1 convs and
+shortcuts): their every brick is nonzero and stored.
+
+A layer whose params carry batch-norm statistics (``{w, bn: {gamma, beta,
+mean, var}}``) is folded into one weight and bias first
+(:func:`fold_bn`); a per-output-channel scale keeps every zero weight zero,
+so the pattern bits hold.
 
 Pattern bits (``core/pruning.PruneResult.pattern_bits``) ride along per
 layer so the compiled artifact can be priced on the crossbar model
@@ -39,16 +45,20 @@ from repro.core.sparse import (
     nonzero_block_masks,
 )
 from repro.engine.program import CompiledConv, CompiledFC, CompiledNetwork
-from repro.models.cnn import CNNConfig
+from repro.models.cnn import CNNConfig, ConvSpec, out_sizes
+from repro.models.resnet import ResNetConfig
 from repro.obs.trace import NULL_TRACER, Tracer
 
 __all__ = ["EngineConfig", "CompileOptions", "PRECISIONS", "PATCH_ORDERS",
-           "patch_order", "conv_matrix", "lower_matrix", "lower_conv",
+           "patch_order", "conv_matrix", "fold_bn", "lower_matrix",
+           "lower_conv",
            "lower_fc", "conv_mapping_search", "compile_network"]
 
 PRECISIONS = ("fp32", "int8")
 # im2col feature orders: 'channel' (row c*k*k + tap) and 'tap' (tap*c_in + c)
 PATCH_ORDERS = ("channel", "tap")
+# fewest input channels a tap-major layer may have (see patch_order)
+MIN_TAP_CHANNELS = 8
 
 
 @dataclasses.dataclass(frozen=True)
@@ -154,8 +164,8 @@ def _pad_axis(a: np.ndarray, axis: int, mult: int) -> np.ndarray:
 
 def patch_order(c_in: int, kernel: int, block: int) -> str:
     """The im2col feature order of a conv layer: ``'tap'`` where its K
-    (``c_in * kernel**2``) spans more than one ``block``, else
-    ``'channel'``.
+    (``c_in * kernel**2``) spans more than one ``block`` and it has at
+    least :data:`MIN_TAP_CHANNELS` input channels, else ``'channel'``.
 
     Tap-major patches are the ``kernel**2`` shifted NHWC slices side by
     side, a plain lane-aligned concatenation, and a K-block then holds
@@ -163,8 +173,13 @@ def patch_order(c_in: int, kernel: int, block: int) -> str:
     uses leaves whole zero bricks for the lossless compression to drop.
     A K that fits one block gains no bricks from the order, and its
     narrow slices (3 lanes for RGB) concatenate worse than they
-    transpose, so it stays channel-major.
+    transpose, so it stays channel-major.  So does a layer on a
+    few-channel input at any K: on the chip each of its ``kernel**2``
+    slices is padded to 128 lanes, which made the tap-major 7x7 stem of
+    ResNet-50 (3 channels, K = 147) ask for 38 GB at batch 128.
     """
+    if c_in < MIN_TAP_CHANNELS:
+        return "channel"
     return "tap" if c_in * kernel * kernel > block else "channel"
 
 
@@ -216,21 +231,43 @@ def lower_matrix(
     return bp
 
 
+def fold_bn(w, bn: dict, eps: float) -> tuple[np.ndarray, np.ndarray]:
+    """Inference batch norm after a bias-free conv, folded into the conv:
+    ``(w * s, beta - mean * s)`` per output channel, ``s = gamma /
+    sqrt(var + eps)``, computed in float64 and stored as float32."""
+    scale = np.asarray(bn["gamma"], np.float64) / np.sqrt(
+        np.asarray(bn["var"], np.float64) + eps
+    )
+    w = np.asarray(w, np.float64) * scale[:, None, None, None]
+    b = np.asarray(bn["beta"], np.float64) - np.asarray(
+        bn["mean"], np.float64
+    ) * scale
+    return w.astype(np.float32), b.astype(np.float32)
+
+
 def lower_conv(
-    name: str,
+    spec: ConvSpec,
     w: np.ndarray,
     b: np.ndarray,
     pattern_bits: np.ndarray | None,
     out_hw: int,
-    pool_after: bool,
     ecfg: EngineConfig,
     tracer: Tracer | None = None,
     mapping: MappingCandidate | None = None,
 ) -> CompiledConv:
+    """One conv ``spec`` with weight ``w`` and bias ``b`` as an im2col
+    spmm.  Pattern bits not given are read off the weights' nonzero
+    masks: a dense kernel of any size gets its all-ones pattern."""
+    name = spec.name
     w = np.asarray(w, np.float32)
     c_out, c_in, kh, kw = w.shape
     if kh != kw:
         raise ValueError(f"{name}: non-square kernel {kh}x{kw}")
+    if (c_in, c_out, kh) != (spec.c_in, spec.c_out, spec.kernel):
+        raise ValueError(
+            f"{name}: weight {w.shape} does not match the layer's "
+            f"(c_out, c_in, k) = ({spec.c_out}, {spec.c_in}, {spec.kernel})"
+        )
     if pattern_bits is None:
         pattern_bits = masks_to_bits(kernel_masks(w))
     reorder = mapping.reorder if mapping is not None else "pattern"
@@ -241,13 +278,18 @@ def lower_conv(
         c_out=c_out,
         kernel=kh,
         out_hw=out_hw,
-        pool_after=pool_after,
         bp=lower_matrix(conv_matrix(w, order), ecfg.block, ecfg.tile,
                         ecfg.precision, tracer=tracer, reorder=reorder),
         bias=np.asarray(b, np.float32).copy(),
         pattern_bits=np.asarray(pattern_bits, np.int64).copy(),
         mapping=mapping,
         patch_order=order,
+        stride=spec.stride,
+        src=spec.src,
+        residual=spec.residual,
+        relu=spec.relu,
+        norm=spec.norm,
+        pool=spec.pool,
     )
 
 
@@ -324,7 +366,7 @@ def conv_mapping_search(
 
 
 def compile_network(
-    cfg: CNNConfig,
+    cfg: CNNConfig | ResNetConfig,
     params: dict,
     pattern_bits: dict[str, np.ndarray] | None = None,
     ecfg: EngineConfig | None = None,
@@ -338,11 +380,15 @@ def compile_network(
     """Lower a (pruned) CNN end-to-end into a :class:`CompiledNetwork`.
 
     Args:
-      cfg: network geometry (``models.cnn.CNNConfig``).
-      params: parameter pytree ``{conv1: {w, b}, ..., fc: {w, b}}``.
-      pattern_bits: per-conv packed 3x3 pattern bitmasks
+      cfg: network geometry (``models.cnn.CNNConfig`` or
+        ``models.resnet.ResNetConfig``): its ``layers()`` are lowered in
+        order, one loop for every network.
+      params: parameter pytree ``{<layer>: {w, b} or {w, bn}, ..., fc: {w,
+        b}}``; a layer with ``bn`` statistics is folded (:func:`fold_bn`,
+        eps ``cfg.bn_eps``).
+      pattern_bits: per-conv packed pattern bitmasks
         (``PruneResult.pattern_bits``); recovered from the weights' nonzero
-        structure for layers not listed.
+        structure for layers not listed (dense layers: all-ones).
       options: a :class:`CompileOptions` carrying the lowering geometry
         and every compile-pass switch.  This is the preferred form; the
         loose keyword arguments below are deprecated aliases kept for one
@@ -423,21 +469,24 @@ def compile_network(
     tracer = options.tracer or NULL_TRACER
     pattern_bits = pattern_bits or {}
     convs = []
-    hw = cfg.input_hw
+    layers = cfg.layers()
+    sizes = out_sizes(layers, cfg.input_hw)
     with tracer.span(
         "compile_network", cat="compile",
-        layers=cfg.num_convs + 1, precision=ecfg.precision,
+        layers=len(layers) + 1, precision=ecfg.precision,
         optimize=search_cfg is not None,
     ):
-        for i in range(1, cfg.num_convs + 1):
-            name = f"conv{i}"
-            pool = i in cfg.pool_after
+        for spec in layers:
+            name = spec.name
+            hw = sizes[name][0]
+            p = params[name]
+            w, b = (fold_bn(p["w"], p["bn"], cfg.bn_eps) if "bn" in p
+                    else (p["w"], p["b"]))
             mapping = None
             if search_cfg is not None:
                 with tracer.span(f"search:{name}", cat="compile") as sp:
                     res = conv_mapping_search(
-                        params[name]["w"], pattern_bits.get(name), hw,
-                        ecfg, search_cfg,
+                        w, pattern_bits.get(name), hw, ecfg, search_cfg,
                     )
                     mapping = res.chosen
                     sp.args.update(
@@ -450,19 +499,16 @@ def compile_network(
             with tracer.span(f"lower:{name}", cat="compile"):
                 convs.append(
                     lower_conv(
-                        name,
-                        params[name]["w"],
-                        params[name]["b"],
+                        spec,
+                        w,
+                        b,
                         pattern_bits.get(name),
                         out_hw=hw,
-                        pool_after=pool,
                         ecfg=ecfg,
                         tracer=tracer,
                         mapping=mapping,
                     )
                 )
-            if pool:
-                hw //= 2
         fc_reorder = "pattern"
         if search_cfg is not None:
             with tracer.span("search:fc", cat="compile") as sp:

@@ -1,8 +1,8 @@
 """The ``CompiledNetwork`` artifact: what the engine compiler emits.
 
 A compiled program is an ordered op list — one ``CompiledConv`` per conv
-layer (im2col conv-as-spmm + norm/ReLU + optional 2x2 maxpool), a global
-average pool, and a ``CompiledFC`` head — each carrying real kernel
+layer (im2col conv-as-spmm + bias, norm, residual add, ReLU, pool), a
+global average pool, and a ``CompiledFC`` head — each carrying real kernel
 operands (a :class:`~repro.core.sparse.BlockPatternWeight` with
 ``w_comp`` / ``block_ids`` / ``inv_order``) rather than placement
 statistics.  ``executor.py`` runs it, ``serialize.py`` persists it, and
@@ -10,6 +10,11 @@ statistics.  ``executor.py`` runs it, ``serialize.py`` persists it, and
 crossbar model by reusing ``core/mapping.map_layer`` +
 ``core/simulator.simulate_layer``, so every compiled program also knows
 its crossbar area / energy / cycle budget.
+
+The op list is a graph in execution order: a conv reads the previous
+op's output unless its ``src`` names another tensor, and may add a
+``residual`` tensor before its ReLU (``models.cnn.ConvSpec``).  A chain
+(VGG) is the case where every conv keeps the defaults.
 """
 
 from __future__ import annotations
@@ -27,6 +32,7 @@ from repro.core.sparse import BlockPatternWeight, block_density
 from repro.core.synthetic import LayerSpec, SyntheticLayer
 from repro.engine.partition import NetworkPartition, tile_assignment
 from repro.models.cnn import CNNConfig
+from repro.models.resnet import ResNetConfig
 
 __all__ = ["CompiledConv", "CompiledFC", "CompiledNetwork"]
 
@@ -43,6 +49,13 @@ class CompiledConv:
     executor's patches (``lowering.patch_order``): ``'channel'`` (row
     ``c * kernel**2 + tap``) or ``'tap'`` (row ``tap * c_in + c``).
 
+    ``stride``, ``src``, ``residual``, ``relu``, ``norm`` and ``pool``
+    are the layer's :class:`~repro.models.cnn.ConvSpec` fields: im2col at
+    ``stride`` with ``kernel // 2`` padding over tensor ``src`` (``None``:
+    the previous op's output), then the epilogue bias -> ``channel_norm``
+    (``norm == 'channel'``) -> ``+ residual`` -> ReLU -> ``pool``.
+    ``out_hw`` is the conv's output side, before its pool.
+
     ``mapping`` (optional) is the searched per-layer crossbar mapping
     (``compile_network(optimize=...)``, ``core/mapsearch.py``):
     ``hardware_report`` prices the layer at that candidate's geometry and
@@ -56,12 +69,17 @@ class CompiledConv:
     c_out: int
     kernel: int  # spatial kernel side (3 for 3x3)
     out_hw: int  # output feature-map side at compile-time input_hw
-    pool_after: bool
     bp: BlockPatternWeight
     bias: np.ndarray  # [c_out]
     pattern_bits: np.ndarray  # [c_out, c_in] packed kernel patterns
     mapping: MappingCandidate | None = None
     patch_order: str = "channel"
+    stride: int = 1
+    src: str | None = None
+    residual: str | None = None
+    relu: bool = True
+    norm: str = "channel"
+    pool: str | None = None
 
     @property
     def k_unpadded(self) -> int:
@@ -110,7 +128,7 @@ class CompiledNetwork:
     (manifest v4).
     """
 
-    config: CNNConfig
+    config: CNNConfig | ResNetConfig
     convs: list[CompiledConv]
     fc: CompiledFC
     block: int
@@ -158,9 +176,20 @@ class CompiledNetwork:
         for c in self.convs:
             d = (f"spmm[{c.bp.k_in}x{c.bp.n_out}] {c.patch_order}-major "
                  f"bricks={int(np.sum(c.bp.nnz))} k_max={c.bp.k_max} "
-                 f"density={block_density(c.bp):.2f} + norm/relu")
-            if c.pool_after:
-                d += " + maxpool2x2"
+                 f"density={block_density(c.bp):.2f}")
+            if c.kernel != 3 or c.stride != 1:
+                d = f"{c.kernel}x{c.kernel}/{c.stride} " + d
+            if c.src is not None:
+                d = f"({c.src}) " + d
+            if c.norm == "channel":
+                d += " + norm"
+            if c.residual is not None:
+                d += f" + {c.residual}"
+            if c.relu:
+                d += " + relu"
+            if c.pool is not None:
+                d += {"max2": " + maxpool2x2",
+                      "max3s2": " + maxpool3x3/2"}[c.pool]
             ops.append((c.name, d))
         ops.append(("gap", "global average pool"))
         ops.append(("fc", f"spmm[{self.fc.bp.k_in}x{self.fc.bp.n_out}]"))
@@ -348,7 +377,8 @@ class CompiledNetwork:
         """Price the compiled convs on the paper's crossbar model.
 
         Reuses ``core/mapping.map_layer`` (via ``simulate_layer``) on each
-        layer's 3x3 pattern bits, so crossbar counts agree exactly with
+        layer's own ``kernel x kernel`` pattern bits (a dense layer's are
+        all ones) at its own ``out_hw``, so crossbar counts agree exactly with
         ``core/simulator.simulate_dataset`` for the same bits.
 
         Energy/cycle pricing comes in up to three flavours:

@@ -23,6 +23,11 @@ payloads is the certification pass's job (V506).  Format v5 adds each
 conv's ``patch_order`` (``lowering.patch_order``), the row order its
 weights were lowered in and its patches must be built in: v1-v4
 programs were lowered channel-major and always load as ``'channel'``.
+
+Only chain programs (``CNNConfig``: stride-1 convs with ``channel_norm``,
+ReLU and optional 2x2 max pools) have a manifest; saving a graph program
+(a ResNet's strides, residual adds, folded batch norm, other pools) raises
+``NotImplementedError``.
 """
 
 from __future__ import annotations
@@ -106,7 +111,17 @@ def _load_bp(entry: dict, directory: str) -> BlockPatternWeight:
 
 
 def save_program(directory: str, program: CompiledNetwork) -> str:
-    """Atomically write ``program`` under ``directory``.  Returns the path."""
+    """Atomically write ``program`` under ``directory``.  Returns the path.
+
+    Raises ``NotImplementedError`` for a graph program (the manifest holds
+    chains only)."""
+    if not isinstance(program.config, CNNConfig):
+        raise NotImplementedError(
+            "save_program: the manifest format holds chain programs "
+            f"(CNNConfig) only, not a {type(program.config).__name__} "
+            "network: strides, src/residual edges, folded batch norm and "
+            "3x3/2 pools are not in it"
+        )
     parent = os.path.dirname(os.path.abspath(directory))
     os.makedirs(parent, exist_ok=True)
     tmp = directory.rstrip("/") + ".tmp"
@@ -142,7 +157,7 @@ def save_program(directory: str, program: CompiledNetwork) -> str:
                 "c_out": c.c_out,
                 "kernel": c.kernel,
                 "out_hw": c.out_hw,
-                "pool_after": c.pool_after,
+                "pool_after": c.pool == "max2",
                 "bias": _save_array(tmp, f"{c.name}.bias", c.bias),
                 "pattern_bits": _save_array(
                     tmp, f"{c.name}.pattern_bits", c.pattern_bits
@@ -451,7 +466,7 @@ def load_program(directory: str, verify: bool = True) -> CompiledNetwork:
                 c_out=e["c_out"],
                 kernel=e["kernel"],
                 out_hw=e["out_hw"],
-                pool_after=e["pool_after"],
+                pool="max2" if e["pool_after"] else None,
                 bp=_load_bp(e["bp"], directory),
                 bias=np.load(os.path.join(directory, e["bias"])),
                 pattern_bits=np.load(

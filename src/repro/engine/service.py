@@ -114,7 +114,7 @@ class InferenceService:
 
     def _input_shape(self) -> tuple[int, int, int]:
         cfg = self.program.config
-        return (cfg.conv_channels[0][0], cfg.input_hw, cfg.input_hw)
+        return (cfg.in_channels, cfg.input_hw, cfg.input_hw)
 
     def trace_count(self) -> int:
         """How many times the underlying forward has been traced."""

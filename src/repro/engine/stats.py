@@ -63,7 +63,8 @@ class LayerSkipStats:
 
     counts[c, i]: windows whose channel-``c`` input selection under
     ``patterns[i]`` was entirely zero, out of ``windows`` observed windows
-    (= batch * H * W input positions, summed over every batch merged in).
+    (= batch * Ho * Wo output positions, summed over every batch merged
+    in).
     The all-zero pattern (bits == 0) selects nothing and therefore always
     counts as skippable, mirroring ``core/simulator._skip_fractions``.
     """

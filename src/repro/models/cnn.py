@@ -5,6 +5,11 @@ stack reduced to a single layer (§V-A) so the evaluation is dominated by
 the convolutions the mapping scheme targets.  Params are plain pytrees
 (dict of arrays); conv weights use layout [C_out, C_in, Kh, Kw] to line up
 with ``repro.core`` mapping code.
+
+Every network config the compiler takes (``CNNConfig`` here,
+``models.resnet.ResNetConfig``) describes itself as a list of
+:class:`ConvSpec` in execution order (``layers()``), followed by a global
+average pool and one FC; :func:`out_sizes` walks that list's geometry.
 """
 
 from __future__ import annotations
@@ -19,6 +24,11 @@ import numpy as np
 from repro.core.synthetic import TABLE_II, VGG16_CONV_CHANNELS, synthesize_network
 
 __all__ = [
+    "ConvSpec",
+    "POOLS",
+    "conv_out_hw",
+    "pool_out_hw",
+    "out_sizes",
     "CNNConfig",
     "vgg16_config",
     "synthetic_vgg16",
@@ -27,8 +37,66 @@ __all__ = [
     "cnn_apply",
     "channel_norm",
     "max_pool_2x2",
+    "max_pool_3x3s2",
     "conv_weight_names",
 ]
+
+
+@dataclasses.dataclass(frozen=True)
+class ConvSpec:
+    """One conv of a network, as the compiler lowers it.
+
+    A chain is the case where every field past ``kernel`` keeps its
+    default.  ``src`` and ``residual`` name tensors: ``"input"`` (the
+    image) or an earlier conv's ``name``, whose output (after its pool)
+    they read.  The epilogue is bias, ``channel_norm`` where ``norm ==
+    "channel"`` (``"none"``: batch norm folded into weight and bias),
+    the ``residual`` add, ReLU where ``relu``, then ``pool``
+    (:data:`POOLS`).  Padding is ``kernel // 2`` on every side.
+    """
+
+    name: str
+    c_in: int
+    c_out: int
+    kernel: int
+    stride: int = 1
+    src: str | None = None  # None: the previous op's output
+    residual: str | None = None  # tensor added before the ReLU
+    relu: bool = True
+    norm: str = "channel"  # 'channel' or 'none'
+    pool: str | None = None  # None, 'max2' or 'max3s2'
+
+
+def conv_out_hw(hw: int, kernel: int, stride: int = 1) -> int:
+    """Output side of a conv padded by ``kernel // 2`` on every side."""
+    return (hw + 2 * (kernel // 2) - kernel) // stride + 1
+
+
+def pool_out_hw(hw: int, pool: str | None) -> int:
+    """Output side of a ``pool`` (:data:`POOLS`, or ``None``)."""
+    if pool is None:
+        return hw
+    if pool == "max2":
+        return hw // 2
+    if pool == "max3s2":
+        return (hw + 2 - 3) // 2 + 1
+    raise ValueError(f"unknown pool {pool!r}")
+
+
+def out_sizes(layers, input_hw: int) -> dict[str, tuple[int, int]]:
+    """``{name: (conv output side, side after its pool)}`` of each
+    :class:`ConvSpec` in ``layers`` for an ``input_hw`` image."""
+    sides = {"input": input_hw}
+    prev = input_hw
+    out = {}
+    for spec in layers:
+        hw = conv_out_hw(
+            sides[spec.src] if spec.src is not None else prev,
+            spec.kernel, spec.stride,
+        )
+        prev = sides[spec.name] = pool_out_hw(hw, spec.pool)
+        out[spec.name] = (hw, prev)
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,6 +110,21 @@ class CNNConfig:
     @property
     def num_convs(self) -> int:
         return len(self.conv_channels)
+
+    @property
+    def in_channels(self) -> int:
+        return self.conv_channels[0][0]
+
+    def layers(self) -> list[ConvSpec]:
+        """The chain as :class:`ConvSpec` s: 3x3-style convs with
+        ``channel_norm``, a 2x2 max pool after each ``pool_after``."""
+        return [
+            ConvSpec(
+                f"conv{i}", ci, co, self.kernel,
+                pool="max2" if i in self.pool_after else None,
+            )
+            for i, (ci, co) in enumerate(self.conv_channels, start=1)
+        ]
 
 
 def vgg16_config(num_classes: int = 10, input_hw: int = 32) -> CNNConfig:
@@ -156,6 +239,17 @@ def max_pool_2x2(x: jax.Array) -> jax.Array:
     return jax.lax.reduce_window(
         x, -jnp.inf, jax.lax.max, (1, 1, 2, 2), (1, 1, 2, 2), "VALID"
     )
+
+
+def max_pool_3x3s2(x: jax.Array) -> jax.Array:
+    """3x3 stride-2 max pool padded by 1 with -inf.  x: [B, C, H, W]."""
+    return jax.lax.reduce_window(
+        x, -jnp.inf, jax.lax.max, (1, 1, 3, 3), (1, 1, 2, 2),
+        ((0, 0), (0, 0), (1, 1), (1, 1)),
+    )
+
+
+POOLS = {"max2": max_pool_2x2, "max3s2": max_pool_3x3s2}
 
 
 def cnn_apply(cfg: CNNConfig, params: dict, x: jax.Array) -> jax.Array:

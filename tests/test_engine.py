@@ -85,7 +85,10 @@ def test_patch_order_rule():
     assert patch_order(14, 3, 126) == "channel"  # K == block
     assert patch_order(15, 3, 128) == "tap"
     assert patch_order(64, 3, 128) == "tap"
-    assert patch_order(2, 3, 9) == "tap"  # small blocks follow the rule too
+    assert patch_order(8, 3, 9) == "tap"  # small blocks follow the rule too
+    # a few-channel input stays channel-major at any K (a 7x7 RGB stem)
+    assert patch_order(2, 3, 9) == "channel"
+    assert patch_order(3, 7, 128) == "channel"
     cfg = vgg16_config(num_classes=10, input_hw=32)
     orders = [patch_order(ci, 3, 128) for ci, _ in cfg.conv_channels]
     assert orders == ["channel"] + ["tap"] * 12
@@ -230,9 +233,9 @@ def test_serialize_roundtrip_bit_exact(mini, tmp_path):
     assert prog2.config == cfg
     assert (prog2.block, prog2.tile) == (prog.block, prog.tile)
     for a, b in zip(prog.convs, prog2.convs):
-        assert (a.name, a.c_in, a.c_out, a.kernel, a.out_hw, a.pool_after,
+        assert (a.name, a.c_in, a.c_out, a.kernel, a.out_hw, a.pool,
                 a.patch_order) \
-            == (b.name, b.c_in, b.c_out, b.kernel, b.out_hw, b.pool_after,
+            == (b.name, b.c_in, b.c_out, b.kernel, b.out_hw, b.pool,
                 b.patch_order)
         np.testing.assert_array_equal(np.asarray(a.bp.w_comp),
                                       np.asarray(b.bp.w_comp))

@@ -138,3 +138,33 @@ def test_ou_schedules(rng):
     total_cells = naive.rows_total * naive.cols_total
     covered = int((ns.wordlines * ns.bitlines).sum())
     assert covered == total_cells
+
+
+def _blocks_by_loop(channel, bits_c, sizes_c):
+    """The per-kernel loop ``_blocks_for_channel`` replaced: the reference
+    its grouping must equal."""
+    from repro.core.mapping import PatternBlock
+
+    groups = {}
+    for out_ch, b in enumerate(bits_c):
+        if int(b) != P.ALL_ZERO:
+            groups.setdefault(int(b), []).append(out_ch)
+    out = [PatternBlock(channel, b, int(sizes_c[ks[0]]), tuple(ks))
+           for b, ks in groups.items()]
+    out.sort(key=lambda blk: (-blk.height, blk.pattern))
+    return out
+
+
+@pytest.mark.parametrize("k", [1, 9, 49])
+def test_blocks_for_channel_matches_the_loop(k):
+    """Grouping one channel's kernels by pattern with numpy gives exactly
+    the blocks, order and kernel ids of the per-kernel loop, for 1x1,
+    3x3 and dense 7x7 patterns."""
+    from repro.core.mapping import _blocks_for_channel
+
+    rng = np.random.default_rng(k)
+    bits = _random_bits(rng, 40, 6, n_pat=min(5, 2**k - 1), k=k)
+    sizes = P.pattern_sizes(bits)
+    for c in range(bits.shape[1]):
+        assert _blocks_for_channel(c, bits[:, c], sizes[:, c]) \
+            == _blocks_by_loop(c, bits[:, c], sizes[:, c])

@@ -29,6 +29,7 @@ from repro.core.pruning import (
 from repro.engine import InferenceService, compile_network
 from repro.engine.executor import STAGES
 from repro.models.cnn import conv_weight_names, init_cnn, mini_cnn_config
+from repro.models.resnet import init_resnet, resnet_small_config
 from repro.serve import Request, ServingServer, classify_session
 
 PHASES = ("service.refill", "service.dispatch", "service.wait",
@@ -86,6 +87,33 @@ def test_every_forward_op_carries_a_layer_and_stage(prog, collect_stats):
         want |= {(c, "stats") for c in layers[:-2]}
     assert want <= seen
     assert all(s != "stats" for _, s in seen) or collect_stats
+
+
+def test_every_resnet_forward_op_carries_a_layer_and_stage():
+    """A ResNet's layers are torchvision's names; each block's conv3 adds
+    its shortcut under ``<layer>/residual``."""
+    cfg = resnet_small_config(input_hw=32)
+    prog = compile_network(cfg, init_resnet(cfg, jax.random.PRNGKey(0)))
+    layers = [op.name for op in prog.convs] + ["gap", "fc"]
+    assert layers[:2] == ["stem", "layer1.0.downsample"]
+    scope = re.compile(
+        rf"^jit\(forward\)/({'|'.join(map(re.escape, layers))})/"
+        rf"({'|'.join(STAGES)})(/|$)"
+    )
+    op_names = re.findall(r'op_name="([^"]*)"', _served_hlo(prog, False))
+    traced = [n for n in op_names if n.startswith("jit(forward)/")]
+    assert traced
+    assert [n for n in traced if not scope.match(n)] == []
+    seen = {scope.match(n).group(1, 2) for n in traced}
+    residual = {op.name for op in prog.convs if op.residual is not None}
+    assert residual == {"layer1.0.conv3", "layer2.0.conv3", "layer2.1.conv3"}
+    assert {(n, "residual") for n in residual} <= seen
+    assert {n for n, s in seen if s == "residual"} == residual
+    want = {(c, s) for c in layers[:-2] for s in
+            ("patches", "spmm", "permute", "epilogue")}
+    # layer1.0.downsample's patches are layer1.0.conv1's (the same 1x1
+    # view of the same input), which the compiled program builds once
+    assert want - {("layer1.0.downsample", "patches")} <= seen
 
 
 @pytest.mark.parametrize("collect_stats", [False, True])
