@@ -14,7 +14,7 @@ Steps:
   5. compile the pruned network into an executable crossbar program and
      serve a batch of requests through the engine's classification service
      — then recompile with ``optimize='auto'`` to let the per-layer
-     mapping design-space search shrink crossbar area at identical logits,
+     mapping design-space search shrink crossbar area at the same logits,
   6.-7. measured-vs-assumed energy pricing, sharded execution over a mesh,
   8. cell precision: recompile the same pruned network quantized.
 
@@ -195,14 +195,16 @@ print(f"  hardware: {rep['crossbars']} crossbars "
 # for every layer; optimize='auto' searches per layer over crossbar dims
 # and packing/reorder strategies, priced by the simulator's own cost
 # model, and never chooses a candidate worse than the fixed scheme on
-# area or energy.  fp32 logits are bit-identical — layout only.
+# area or energy.  A reorder is layout only: the fp32 logits agree to
+# rounding (each layer's order also orders the next layer's sums).
 program_opt = compile_network(
     cfg, res.params, res.pattern_bits,
     options=CompileOptions(optimize="auto", tracer=tracer),
 )
 rep_opt = program_opt.hardware_report()
 logits_opt = make_forward(program_opt)(x)
-assert bool(jnp.array_equal(logits_opt, logits_eng)), "layout changed math"
+assert bool(jnp.allclose(logits_opt, logits_eng, rtol=1e-5, atol=1e-5)), \
+    "layout changed math"
 print(f"[{time.time()-t0:5.1f}s] optimize='auto' mapping search:")
 for name, m_entry in rep_opt["mapping"]["per_layer"].items():
     print(f"  {name}: {m_entry['rows']}x{m_entry['cols']} crossbars, "
@@ -211,7 +213,7 @@ for name, m_entry in rep_opt["mapping"]["per_layer"].items():
 print(f"  area {rep_opt['area_cells']} cells vs fixed {rep['area_cells']} "
       f"({rep['area_cells']/max(rep_opt['area_cells'],1):.1f}x win), "
       f"energy {rep_opt['energy_pj']/1e3:.1f} nJ/img "
-      f"(fixed {rep['energy_pj']/1e3:.1f}), logits bit-identical")
+      f"(fixed {rep['energy_pj']/1e3:.1f}), logits equal to fp32 rounding")
 
 service = InferenceService(program, batch_slots=16, collect_stats=True,
                            tracer=tracer)

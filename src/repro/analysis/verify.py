@@ -44,6 +44,10 @@ V206   mapping geometry is consistent with the packed operands: the OU
        mapping stores the cell-slice count its payload actually occupies
 V207   ``patch_order`` is ``'channel'`` or ``'tap'``, and ``'tap'`` only
        where the layer's K (``c_in * kernel**2``) spans more than one block
+V208   a recorded ``in_order`` (conv or FC) is a bijection over its input
+       channels, and equals the channel order its source tensor carries
+       (``CompiledNetwork.layout``): a program that records the order its
+       rows read claims the forward gathers nothing at that input
 V301   inter-layer shapes along each conv's ``src``/``residual`` edges
        (channels, spatial dims, fc head) and agreement with the config's
        layer list
@@ -547,7 +551,23 @@ def verify_conv(conv, cell_bits: int = 4, report: Report | None = None) -> Repor
             "layer channel-major",
             layer=name, location="patch_order",
         )
+    _verify_in_order(r, getattr(conv, "in_order", None), conv.c_in, name)
     return r
+
+
+def _verify_in_order(r: Report, order, n: int, layer: str) -> None:
+    """V208, first half: a recorded input order is a bijection."""
+    if order is None:
+        return
+    order = np.asarray(order)
+    if not (np.issubdtype(order.dtype, np.integer)
+            and _is_permutation(order, n)):
+        r.add(
+            "V208",
+            f"in_order (shape {order.shape}, dtype {order.dtype}) is not "
+            f"a permutation of the layer's {n} input channels",
+            layer=layer, location="in_order",
+        )
 
 
 def verify_fc(fc, cell_bits: int = 4, report: Report | None = None) -> Report:
@@ -575,6 +595,7 @@ def verify_fc(fc, cell_bits: int = 4, report: Report | None = None) -> Report:
         r.add("V203", f"bp.n_out={bp.n_out} != padded d_out = {want_n}",
               layer="fc", location="bp.n_out")
     _verify_bias(r, fc.bias, fc.d_out, "fc")
+    _verify_in_order(r, getattr(fc, "in_order", None), fc.d_in, "fc")
     return r
 
 
@@ -766,8 +787,28 @@ def verify_network(program, report: Report | None = None) -> Report:
             layer="fc", location="d_out",
         )
 
+    if r.ok:
+        _verify_folded_orders(program, r)
     verify_partition(program, report=r)
     return r
+
+
+def _verify_folded_orders(program, r: Report) -> None:
+    """V208, second half (on a structurally sound program): a recorded
+    ``in_order`` is the order its source tensor carries."""
+    layout = program.layout()
+    for op, name in [(c, c.name) for c in program.convs] + [
+        (program.fc, "fc")
+    ]:
+        recorded = getattr(op, "in_order", None) is not None
+        if recorded and layout[name].gather_in is not None:
+            r.add(
+                "V208",
+                "in_order differs from the channel order its source "
+                "tensor carries: the weight rows were lowered for another "
+                "input order",
+                layer=name, location="in_order",
+            )
 
 
 def verify_manifest(directory: str, report: Report | None = None) -> Report:

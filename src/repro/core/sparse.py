@@ -190,7 +190,10 @@ def reorder_columns(masks: np.ndarray, strategy: str = "pattern") -> np.ndarray:
     Returns ``new_order`` (int32 [N], new position -> original column).
     Every strategy groups equal-mask columns adjacently — only the order
     of the *groups* differs, so the compressed operand stays exact and
-    the inverse permutation restores the original semantics:
+    the inverse permutation restores the original semantics.  In every
+    strategy the all-zero columns (outputs pruned away, and the padding
+    of a layer up to whole tiles) go last, in index order, so a layer's
+    ``n`` real outputs are its first ``n`` stored columns:
 
       'pattern'    — groups in lexicographic mask order (the paper's
                      kernel reordering; the historical default).
@@ -203,24 +206,26 @@ def reorder_columns(masks: np.ndarray, strategy: str = "pattern") -> np.ndarray:
     masks = np.asarray(masks, bool)
     if masks.ndim != 2:
         raise ValueError(f"masks must be [N, n_blocks], got {masks.shape}")
-    if strategy == "pattern":
-        mask_keys = np.array([m.tobytes() for m in masks])
-        return np.argsort(mask_keys, kind="stable").astype(np.int32)
     if strategy not in REORDERS:
         raise ValueError(f"unknown reorder strategy {strategy!r}")
     if masks.shape[0] == 0:
         return np.zeros(0, np.int32)
-    uniq, inverse = np.unique(masks, axis=0, return_inverse=True)
-    chain = _mask_similarity_rank(uniq)
-    if strategy == "similarity":
-        rank = chain
-    else:  # hybrid
-        order_u = np.lexsort((chain, -uniq.sum(1)))
-        rank = np.empty(len(uniq), np.int64)
-        rank[order_u] = np.arange(len(uniq))
-    return np.argsort(rank[inverse.reshape(-1)], kind="stable").astype(
-        np.int32
-    )
+    if strategy == "pattern":
+        mask_keys = np.array([m.tobytes() for m in masks])
+        order = np.argsort(mask_keys, kind="stable")
+    else:
+        uniq, inverse = np.unique(masks, axis=0, return_inverse=True)
+        chain = _mask_similarity_rank(uniq)
+        if strategy == "similarity":
+            rank = chain
+        else:  # hybrid
+            order_u = np.lexsort((chain, -uniq.sum(1)))
+            rank = np.empty(len(uniq), np.int64)
+            rank[order_u] = np.arange(len(uniq))
+        order = np.argsort(rank[inverse.reshape(-1)], kind="stable")
+    # stable within each part, so the all-zero columns keep index order
+    zero = ~masks[order].any(axis=1)
+    return np.concatenate([order[~zero], order[zero]]).astype(np.int32)
 
 
 def predicted_tile_nnz(

@@ -71,8 +71,9 @@ How it composes:
   the crossbar model default), so a quantized program's searched area is
   the quantized area.  Note int8 logits are only tolerance-equal across
   reorder strategies: per-brick quantization scales depend on column
-  grouping.  fp32 logits are bit-identical — reordering changes layout,
-  never semantics.
+  grouping.  fp32 logits agree to rounding — reordering changes layout,
+  never semantics, but a layer's order also orders the sums of the
+  layer that reads it (its rows are lowered in that order).
 * **verify=** — searched programs pass the same static verifier;
   the candidate itself is checked by rules V205 (strategy tags) and
   V206 (geometry consistent with the packed operands).

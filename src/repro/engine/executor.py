@@ -3,14 +3,22 @@
 ``make_forward`` returns a jitted batched forward: per conv layer it
 extracts im2col patches in the layer's ``patch_order`` (conv-as-spmm;
 tap-major where K spans more than one block), dispatches through
-``kernels/ops.pattern_spmm`` (the Pallas TPU kernel on the chip; the XLA
-path, or the Pallas interpreter when asked for, on CPU) — which applies
-the stored inverse output permutation (the Output Indexing Unit) — then
-the layer's epilogue: bias, the shared ``channel_norm`` where the layer
-keeps one, the residual add, ReLU and the pool, matching ``cnn_apply``
-(``resnet_apply`` for a ResNet) on the pruned weights to numerical
-tolerance.  The program is a graph in execution order: the forward keeps
-each layer's output by name, for a later layer's ``src`` or ``residual``.
+``kernels/ops.pattern_spmm_raw`` (the Pallas TPU kernel on the chip; the
+XLA path, or the Pallas interpreter when asked for, on CPU), then the
+layer's epilogue: the real output columns, bias, the shared
+``channel_norm`` where the layer keeps one, the residual add, ReLU and the
+pool, matching ``cnn_apply`` (``resnet_apply`` for a ResNet) on the pruned
+weights to numerical tolerance.  The program is a graph in execution
+order: the forward keeps each layer's output by name, for a later layer's
+``src`` or ``residual``.
+
+Each kept tensor stays in the channel order its producer's kernel wrote
+it in (the reordered columns of ``bp.new_order``): the next layer's
+weight rows were lowered in that order, and bias, ``channel_norm``, ReLU
+and the pools act per channel.  The Output Indexing Unit's gather runs
+only where ``CompiledNetwork.layout`` finds two orders that differ — at
+the logits, on programs from manifests before format 6, and across a
+residual add whose addends disagree.
 
 With ``collect_stats=True`` the forward additionally counts, per layer
 and per OU row-group (= (input channel, pattern) pair), how many input
@@ -43,8 +51,8 @@ With ``mesh=`` the same program executes *sharded* across a device mesh
 (``engine/partition.py``): each spmm runs tile-parallel under
 ``shard_map`` — every ``model``-axis device computes the output columns
 of its contiguous slab of (zero-padded) tiles, scatters them into full
-width, and a ``psum`` combines the partial outputs before the global
-inverse permutation — while batch rows and the skip counters split over
+width, and a ``psum`` combines the partial outputs in the stored column
+order — while batch rows and the skip counters split over
 the ``data`` axis (counters ``psum``-reduced back to the global count).
 Padding tiles multiply zeros, so sharded and unsharded execution agree to
 fp32 tolerance and the measured statistics agree exactly.
@@ -60,13 +68,18 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.engine.partition import pad_bp_tiles, partition_from_mesh
-from repro.engine.program import CompiledConv, CompiledFC, CompiledNetwork
+from repro.engine.program import (
+    CompiledConv,
+    CompiledFC,
+    CompiledNetwork,
+    OpLayout,
+)
 from repro.engine.stats import (
     ActivationStats,
     skip_patterns_and_masks,
     stats_from_counts,
 )
-from repro.kernels.ops import pattern_spmm, pattern_spmm_raw
+from repro.kernels.ops import pattern_spmm_raw
 from repro.kernels.ops import _pad_to as _pad_axis_to_mult
 from repro.models.cnn import POOLS, channel_norm, conv_out_hw, out_sizes
 from repro.parallel.sharding import shard_block_pattern
@@ -161,7 +174,9 @@ def zero_selection_counts(
     """Count all-zero input selections per OU row-group.
 
     patches: [M, c_in*kk] unpadded im2col windows, features in ``order``
-    (:func:`extract_patches`; the counts are the same in either order);
+    (:func:`extract_patches`; the counts are the same in either order),
+    input channels in the order of the tensor they were built from (entry
+    ``c`` of the result counts its ``c``-th channel);
     masks: [P, kk] bool, the layer's pattern position masks
     (``skip_patterns_and_masks``).
     Returns int32 [c_in, P]: entry (c, i) is the number of windows whose
@@ -198,10 +213,13 @@ class _Dispatch:
         return bp
 
     def spmm(self, x2d: jax.Array, bp, prepared) -> jax.Array:
-        return pattern_spmm(
-            x2d, bp, backend=self.backend, interpret=self.interpret,
-            bm=self.bm,
-        )
+        """``x2d @ W`` in ``bp``'s stored column order: [M, tiles*tile]."""
+        with jax.named_scope("spmm"):
+            return pattern_spmm_raw(
+                x2d, bp.w_comp, bp.block_ids, bp.block,
+                backend=self.backend, interpret=self.interpret, bm=self.bm,
+                w_scales=bp.w_scales,
+            ).astype(x2d.dtype)
 
     def counts(
         self, patches, c_in, kk, masks, row_valid=None, order="channel"
@@ -276,19 +294,16 @@ class _ShardedDispatch(_Dispatch):
         if quantized:
             args += (prepared.w_scales,)
             in_specs += (P(mspec),)
+        # the stored column order, padded tiles last (past every real
+        # column and every inv_order entry)
         with jax.named_scope("spmm"):
-            y = jax.shard_map(
+            return jax.shard_map(
                 local,
                 mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=P(dspec, None),
                 check_vma=False,
-            )(*args)
-        # Output Indexing Unit: global inverse permutation after the psum
-        # (padded columns sit past every inv_order entry and are dropped)
-        with jax.named_scope("permute"):
-            y = jnp.take(y, jnp.asarray(bp.inv_order), axis=1)
-            return y.astype(x2d.dtype)
+            )(*args).astype(x2d.dtype)
 
     def counts(
         self, patches, c_in, kk, masks, row_valid=None, order="channel"
@@ -323,11 +338,28 @@ class _ShardedDispatch(_Dispatch):
         )(*args)
 
 
+def _gather(x: jax.Array, idx, axis: int) -> jax.Array:
+    """The Output Indexing Unit: ``x``'s entries ``idx`` along ``axis``."""
+    with jax.named_scope("permute"):
+        return jnp.take(x, jnp.asarray(idx), axis=axis)
+
+
+def _outputs(y: jax.Array, bp, n: int, lay: OpLayout) -> jax.Array:
+    """The ``n`` real outputs of a kernel's stored columns ``y``, in
+    ``lay.out_order``: the leading columns, or gathered by
+    ``bp.inv_order`` into channel order."""
+    if lay.gather_out:
+        return _gather(y, np.asarray(bp.inv_order)[:n], 1)
+    with jax.named_scope("epilogue"):
+        return y[:, :n]
+
+
 def _run_conv(
     op: CompiledConv,
     x: jax.Array,
     disp: _Dispatch,
     prepared,
+    lay: OpLayout,
     stat_masks: np.ndarray | None = None,
     valid: jax.Array | None = None,
     residual: jax.Array | None = None,
@@ -336,6 +368,8 @@ def _run_conv(
     h, w = conv_out_hw(h, op.kernel, op.stride), conv_out_hw(
         w, op.kernel, op.stride
     )
+    if lay.gather_in is not None:
+        x = _gather(x, lay.gather_in, 1)
     with jax.named_scope("patches"):
         # [B*Ho*Wo, bp.k_in], features in the order the weight rows are
         patches = extract_patches(
@@ -351,13 +385,16 @@ def _run_conv(
                 patches[:, : op.k_unpadded], op.c_in, op.kernel * op.kernel,
                 stat_masks, row_valid, op.patch_order,
             )
-    y = disp.spmm(patches, op.bp, prepared)  # scopes spmm, permute
+    y = _outputs(disp.spmm(patches, op.bp, prepared), op.bp, op.c_out, lay)
     with jax.named_scope("epilogue"):
-        y = y[:, : op.c_out] + jnp.asarray(op.bias)
+        # bias, and every op after it, in the output's channel order
+        y = y + jnp.asarray(op.bias[lay.out_order])
         y = y.reshape(b, h, w, op.c_out).transpose(0, 3, 1, 2)
         if op.norm == "channel":
             y = channel_norm(y)
     if residual is not None:
+        if lay.gather_residual is not None:
+            residual = _gather(residual, lay.gather_residual, 1)
         with jax.named_scope("residual"):
             y = y + residual
     with jax.named_scope("epilogue"):
@@ -373,12 +410,15 @@ def _run_fc(
     x: jax.Array,
     disp: _Dispatch,
     prepared,
+    lay: OpLayout,
 ) -> jax.Array:
+    if lay.gather_in is not None:
+        x = _gather(x, lay.gather_in, 1)
     with jax.named_scope("patches"):
         xf = _pad_features(x, op.bp.k_in)
-    y = disp.spmm(xf, op.bp, prepared)  # scopes spmm, permute
+    y = _outputs(disp.spmm(xf, op.bp, prepared), op.bp, op.d_out, lay)
     with jax.named_scope("epilogue"):
-        return y[:, : op.d_out] + jnp.asarray(op.bias)
+        return y + jnp.asarray(op.bias[lay.out_order])
 
 
 def _layer_windows(
@@ -454,11 +494,18 @@ def make_forward(
       slices and the zero K padding;
     * ``spmm`` — the row padding, the ``pattern_spmm*`` kernel (or the
       XLA path) and the row slice; sharded, the scatter and ``psum``;
-    * ``permute`` — the inverse output permutation (Output Indexing
-      Unit);
-    * ``epilogue`` — the column slice, bias, NHWC->NCHW transpose,
-      ``channel_norm`` (where ``norm == 'channel'``), ReLU and the pool;
-      for ``gap``, the mean;
+    * ``permute`` — a channel gather (the Output Indexing Unit), traced
+      only where ``program.layout()`` finds two channel orders that
+      differ: the layer's input against the order its weight rows
+      assume, its output when its real columns do not lead the kernel's
+      (taken back into channel order by ``bp.inv_order``), its residual
+      against its output, and the logits against class order.  A program
+      this code compiled gathers at most at the logits
+      (``program.gathers()``; the ``compile_network`` span's ``gathers``
+      arg);
+    * ``epilogue`` — the column slice, bias (in the output's channel
+      order), NHWC->NCHW transpose, ``channel_norm`` (where ``norm ==
+      'channel'``), ReLU and the pool; for ``gap``, the mean;
     * ``residual`` — the add of the layer's ``residual`` tensor, between
       the two halves of its epilogue (a ResNet block's conv3);
     * ``stats`` — the ``collect_stats`` skip counters.
@@ -476,6 +523,7 @@ def make_forward(
 
     prepared = {op.name: disp.prepare(op.bp) for op in program.convs}
     prepared["fc"] = disp.prepare(program.fc.bp)
+    layout = program.layout()
 
     stat_masks = {}
     if collect_stats:
@@ -495,7 +543,8 @@ def make_forward(
             with jax.named_scope(op.name):
                 x, cnt = _run_conv(
                     op, x if op.src is None else tensors[op.src], disp,
-                    prepared[op.name], stat_masks.get(op.name), valid,
+                    prepared[op.name], layout[op.name],
+                    stat_masks.get(op.name), valid,
                     None if op.residual is None else tensors[op.residual],
                 )
             tensors[op.name] = x
@@ -504,7 +553,8 @@ def make_forward(
         with jax.named_scope("gap"), jax.named_scope("epilogue"):
             x = x.mean(axis=(2, 3))  # global average pool
         with jax.named_scope("fc"):
-            logits = _run_fc(program.fc, x, disp, prepared["fc"])
+            logits = _run_fc(program.fc, x, disp, prepared["fc"],
+                             layout["fc"])
         return (logits, counts) if collect_stats else logits
 
     jitted = jax.jit(forward)

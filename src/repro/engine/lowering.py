@@ -21,6 +21,13 @@ Pattern bits (``core/pruning.PruneResult.pattern_bits``) ride along per
 layer so the compiled artifact can be priced on the crossbar model
 (``CompiledNetwork.hardware_report``); when absent they are recovered from
 the weights' nonzero masks.
+
+Channel orders: a layer's kernel writes its outputs in its reordered
+column order, and the forward keeps them so.  Each conv's weight rows (and
+the FC's) are therefore built in the channel order of the tensor they
+read — the image's, or the producing conv's stored order — and recorded
+as its ``in_order``, which leaves the forward no gather between layers
+(``program.CompiledNetwork.layout``).
 """
 
 from __future__ import annotations
@@ -44,7 +51,12 @@ from repro.core.sparse import (
     build_block_pattern,
     nonzero_block_masks,
 )
-from repro.engine.program import CompiledConv, CompiledFC, CompiledNetwork
+from repro.engine.program import (
+    CompiledConv,
+    CompiledFC,
+    CompiledNetwork,
+    leading_order,
+)
 from repro.models.cnn import CNNConfig, ConvSpec, out_sizes
 from repro.models.resnet import ResNetConfig
 from repro.obs.trace import NULL_TRACER, Tracer
@@ -183,15 +195,21 @@ def patch_order(c_in: int, kernel: int, block: int) -> str:
     return "tap" if c_in * kernel * kernel > block else "channel"
 
 
-def conv_matrix(w: np.ndarray, order: str = "channel") -> np.ndarray:
+def conv_matrix(
+    w: np.ndarray, order: str = "channel", in_order=None
+) -> np.ndarray:
     """[C_out, C_in, Kh, Kw] -> im2col matmul view [C_in*Kh*Kw, C_out].
 
     The rows follow the executor's patch layout for ``order``
     (:data:`PATCH_ORDERS`): ``'channel'`` puts weight ``(c, dy, dx)`` at
     row ``c * Kh*Kw + (dy*Kw + dx)``, ``'tap'`` at row
-    ``(dy*Kw + dx) * C_in + c``.
+    ``(dy*Kw + dx) * C_in + c``, where ``c`` is the position of the input
+    channel in ``in_order`` (position ``i`` holds channel
+    ``in_order[i]``; None: channel order).
     """
     w = np.asarray(w)
+    if in_order is not None:
+        w = w[:, np.asarray(in_order)]
     co, ci = w.shape[:2]
     if order == "channel":
         return w.reshape(co, -1).T
@@ -254,10 +272,13 @@ def lower_conv(
     ecfg: EngineConfig,
     tracer: Tracer | None = None,
     mapping: MappingCandidate | None = None,
+    in_order: np.ndarray | None = None,
 ) -> CompiledConv:
     """One conv ``spec`` with weight ``w`` and bias ``b`` as an im2col
-    spmm.  Pattern bits not given are read off the weights' nonzero
-    masks: a dense kernel of any size gets its all-ones pattern."""
+    spmm, its rows in ``in_order``, the channel order of the tensor it
+    reads (None: channel order).  Pattern bits not given are read off the
+    weights' nonzero masks: a dense kernel of any size gets its all-ones
+    pattern."""
     name = spec.name
     w = np.asarray(w, np.float32)
     c_out, c_in, kh, kw = w.shape
@@ -272,14 +293,16 @@ def lower_conv(
         pattern_bits = masks_to_bits(kernel_masks(w))
     reorder = mapping.reorder if mapping is not None else "pattern"
     order = patch_order(c_in, kh, ecfg.block)
+    in_order = _order_or_identity(in_order, c_in)
     return CompiledConv(
         name=name,
         c_in=c_in,
         c_out=c_out,
         kernel=kh,
         out_hw=out_hw,
-        bp=lower_matrix(conv_matrix(w, order), ecfg.block, ecfg.tile,
-                        ecfg.precision, tracer=tracer, reorder=reorder),
+        bp=lower_matrix(conv_matrix(w, order, in_order), ecfg.block,
+                        ecfg.tile, ecfg.precision, tracer=tracer,
+                        reorder=reorder),
         bias=np.asarray(b, np.float32).copy(),
         pattern_bits=np.asarray(pattern_bits, np.int64).copy(),
         mapping=mapping,
@@ -290,22 +313,34 @@ def lower_conv(
         relu=spec.relu,
         norm=spec.norm,
         pool=spec.pool,
+        in_order=in_order,
     )
+
+
+def _order_or_identity(order, n: int) -> np.ndarray:
+    if order is None:
+        return np.arange(n, dtype=np.int32)
+    return np.asarray(order, np.int32).copy()
 
 
 def lower_fc(
     w: np.ndarray, b: np.ndarray, ecfg: EngineConfig,
     tracer: Tracer | None = None, reorder: str = "pattern",
+    in_order: np.ndarray | None = None,
 ) -> CompiledFC:
+    """The FC head ``w`` [d_in, d_out], its rows in ``in_order`` (None:
+    channel order)."""
     w = np.asarray(w, np.float32)
     d_in, d_out = w.shape
+    in_order = _order_or_identity(in_order, d_in)
     return CompiledFC(
         d_in=d_in,
         d_out=d_out,
-        bp=lower_matrix(w, ecfg.block, ecfg.tile, ecfg.precision,
-                        tracer=tracer, reorder=reorder),
+        bp=lower_matrix(w[in_order], ecfg.block, ecfg.tile,
+                        ecfg.precision, tracer=tracer, reorder=reorder),
         bias=np.asarray(b, np.float32).copy(),
         reorder=reorder,
+        in_order=in_order,
     )
 
 
@@ -339,8 +374,10 @@ def conv_mapping_search(
 
     Builds exactly the search inputs ``compile_network(optimize=...)``
     uses — the layer's pattern bits, the block masks of the padded
-    matmul view in the rows the lowering stores (:func:`patch_order`),
-    the precision-derived fixed scheme — and returns the full
+    matmul view in the row order the lowering stores (:func:`patch_order`;
+    input channels in channel order, before the layer's ``in_order``
+    permutes them), the precision-derived fixed scheme — and returns the
+    full
     :class:`~repro.core.mapsearch.MappingSearchResult` (benchmarks call
     this standalone to time the search and check determinism against the
     compiled program).
@@ -402,7 +439,9 @@ def compile_network(
         ``compile_network`` span containing one ``lower:<name>`` span per
         layer, each wrapping its phase spans
         (prune -> reorder -> pack -> quantize), so a Perfetto load of the
-        trace shows exactly where compile time goes.
+        trace shows exactly where compile time goes.  The
+        ``compile_network`` span's ``gathers`` arg counts the channel
+        gathers the forward will trace (``CompiledNetwork.gathers``).
       verify: deprecated alias of ``CompileOptions(verify=...)``:
         post-condition check of the compiled program via
         ``repro.analysis.verify`` — ``'strict'`` raises
@@ -471,11 +510,15 @@ def compile_network(
     convs = []
     layers = cfg.layers()
     sizes = out_sizes(layers, cfg.input_hw)
+    # the channel order of each tensor the forward keeps; None: channel
+    # order (the image's)
+    orders: dict[str, np.ndarray | None] = {"input": None}
+    prev = "input"
     with tracer.span(
         "compile_network", cat="compile",
         layers=len(layers) + 1, precision=ecfg.precision,
         optimize=search_cfg is not None,
-    ):
+    ) as compile_span:
         for spec in layers:
             name = spec.name
             hw = sizes[name][0]
@@ -497,18 +540,21 @@ def compile_network(
                         fixed_area_cells=res.fixed_cost.area_cells,
                     )
             with tracer.span(f"lower:{name}", cat="compile"):
-                convs.append(
-                    lower_conv(
-                        spec,
-                        w,
-                        b,
-                        pattern_bits.get(name),
-                        out_hw=hw,
-                        ecfg=ecfg,
-                        tracer=tracer,
-                        mapping=mapping,
-                    )
+                conv = lower_conv(
+                    spec,
+                    w,
+                    b,
+                    pattern_bits.get(name),
+                    out_hw=hw,
+                    ecfg=ecfg,
+                    tracer=tracer,
+                    mapping=mapping,
+                    in_order=orders[prev if spec.src is None else spec.src],
                 )
+            convs.append(conv)
+            # a residual add keeps the conv's own order
+            orders[name] = leading_order(conv.bp, conv.c_out)
+            prev = name
         fc_reorder = "pattern"
         if search_cfg is not None:
             with tracer.span("search:fc", cat="compile") as sp:
@@ -525,12 +571,17 @@ def compile_network(
                 )
                 sp.args.update(chosen=fc_reorder, bricks=counts)
         with tracer.span("lower:fc", cat="compile"):
+            # the global average pool keeps the last conv's order
             fc = lower_fc(params["fc"]["w"], params["fc"]["b"], ecfg,
-                          tracer=tracer, reorder=fc_reorder)
-    program = CompiledNetwork(
-        config=cfg, convs=convs, fc=fc, block=ecfg.block, tile=ecfg.tile,
-        precision=ecfg.precision, cell_bits=ecfg.cell_bits,
-    )
+                          tracer=tracer, reorder=fc_reorder,
+                          in_order=orders[prev])
+        program = CompiledNetwork(
+            config=cfg, convs=convs, fc=fc, block=ecfg.block,
+            tile=ecfg.tile, precision=ecfg.precision,
+            cell_bits=ecfg.cell_bits,
+        )
+        # the channel gathers the forward will trace
+        compile_span.args.update(gathers=program.gathers())
     if verify is not None:
         from repro.analysis.ranges import analyze_network
         from repro.analysis.verify import verify_network

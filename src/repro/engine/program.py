@@ -15,6 +15,20 @@ The op list is a graph in execution order: a conv reads the previous
 op's output unless its ``src`` names another tensor, and may add a
 ``residual`` tensor before its ReLU (``models.cnn.ConvSpec``).  A chain
 (VGG) is the case where every conv keeps the defaults.
+
+Channel orders.  The kernel writes a layer's outputs in its stored
+(reordered) column order, and every tensor the forward keeps stays in the
+order its producer stored it: the image in channel order, a conv's output
+in its ``bp.new_order`` restricted to the real channels.  The lowering
+builds each consumer's weight rows in the order of the tensor it reads
+(``CompiledConv.in_order``, ``CompiledFC.in_order``), so nothing has to be
+put back into channel order between layers.  :meth:`CompiledNetwork.layout`
+derives, from those orders alone, where the forward still gathers: where
+a tensor's order differs from the one its consumer's rows assume (a
+program loaded from a manifest before format 6, whose rows are in channel
+order), where two addends of a residual add differ, where a layer's real
+outputs are not its leading stored columns, and at the logits, which are
+in class order.
 """
 
 from __future__ import annotations
@@ -34,7 +48,27 @@ from repro.engine.partition import NetworkPartition, tile_assignment
 from repro.models.cnn import CNNConfig
 from repro.models.resnet import ResNetConfig
 
-__all__ = ["CompiledConv", "CompiledFC", "CompiledNetwork"]
+__all__ = ["CompiledConv", "CompiledFC", "CompiledNetwork", "OpLayout",
+           "leading_order"]
+
+
+def leading_order(bp: BlockPatternWeight, n: int) -> np.ndarray | None:
+    """The channels held by the first ``n`` stored columns of ``bp``, or
+    None where those are not exactly its ``n`` real outputs (a lowering
+    that sorted padding columns first, as manifests before format 6 hold:
+    the forward then gathers the output back into channel order)."""
+    head = np.asarray(bp.new_order)[:n]
+    if np.array_equal(np.sort(head), np.arange(n)):
+        return head
+    return None
+
+
+def _regather(have: np.ndarray, want: np.ndarray) -> np.ndarray | None:
+    """Indices that take a tensor in channel order ``have`` to ``want``
+    (``x_want = x_have[..., idx]``), or None where the two are equal."""
+    if np.array_equal(have, want):
+        return None
+    return np.argsort(have)[want].astype(np.int32)
 
 
 @dataclasses.dataclass
@@ -62,6 +96,14 @@ class CompiledConv:
     packing order instead of the report-wide defaults.  ``None`` (the
     fixed scheme, and every v1/v2-loaded program) keeps the historical
     pricing.
+
+    ``in_order`` is the channel order the weight rows assume for the
+    input tensor: row ``tap * c_in + i`` (channel-major: ``i * kernel**2
+    + tap``) holds input channel ``in_order[i]``.  The lowering records
+    the order its ``src`` tensor carries, so the forward reads that tensor
+    as it is; ``None`` (every program loaded from a manifest before format
+    6) means channel order.  ``bias`` and ``pattern_bits`` stay in channel
+    order either way.
     """
 
     name: str
@@ -80,6 +122,7 @@ class CompiledConv:
     relu: bool = True
     norm: str = "channel"
     pool: str | None = None
+    in_order: np.ndarray | None = None
 
     @property
     def k_unpadded(self) -> int:
@@ -92,7 +135,9 @@ class CompiledFC:
 
     ``reorder`` records the column-reorder strategy the head was lowered
     with (``core/sparse.REORDERS``) — the FC has no crossbar mapping, so
-    its searchable space is the reorder alone.
+    its searchable space is the reorder alone.  ``in_order`` is the
+    channel order its rows assume, as on :class:`CompiledConv`: the last
+    conv's, through the global average pool.
     """
 
     d_in: int
@@ -100,6 +145,61 @@ class CompiledFC:
     bp: BlockPatternWeight
     bias: np.ndarray  # [d_out]
     reorder: str = "pattern"
+    in_order: np.ndarray | None = None
+
+
+@dataclasses.dataclass(frozen=True)
+class OpLayout:
+    """How the forward handles one op's channel orders
+    (:meth:`CompiledNetwork.layout`; derived, never stored).
+
+    ``gather_in`` / ``gather_residual``: the indices that take the input
+    / residual tensor to the order the op needs (None: no gather).
+    ``gather_out``: the real outputs are taken from the kernel's output
+    by ``bp.inv_order`` (back into channel order) instead of sliced off
+    its leading columns.  ``out_order``: the channel order of the op's
+    output tensor.
+    """
+
+    gather_in: np.ndarray | None
+    gather_out: bool
+    gather_residual: np.ndarray | None
+    out_order: np.ndarray
+
+    @property
+    def gathers(self) -> int:
+        return (int(self.gather_in is not None) + int(self.gather_out)
+                + int(self.gather_residual is not None))
+
+
+def _op_layout(bp, n: int, src_order: np.ndarray, in_order,
+               residual_order: np.ndarray | None = None,
+               logits: bool = False) -> OpLayout:
+    """One op's :class:`OpLayout`: it reads a tensor in ``src_order``
+    with rows in ``in_order`` (None: channel order) and writes ``n`` real
+    outputs; ``logits`` must leave in class order."""
+    want = np.arange(len(src_order)) if in_order is None else in_order
+    lead = leading_order(bp, n)
+    if logits and lead is not None and not np.array_equal(
+        lead, np.arange(n)
+    ):
+        lead = None
+    out = np.arange(n) if lead is None else lead
+    return OpLayout(
+        gather_in=_regather(np.asarray(src_order), np.asarray(want)),
+        gather_out=lead is None,
+        gather_residual=(None if residual_order is None
+                         else _regather(residual_order, out)),
+        out_order=out,
+    )
+
+
+def _gather_note(lay: OpLayout) -> str:
+    what = [name for name, on in (
+        ("src", lay.gather_in is not None), ("out", lay.gather_out),
+        ("residual", lay.gather_residual is not None),
+    ) if on]
+    return f" gather({'|'.join(what)})" if what else ""
 
 
 @dataclasses.dataclass
@@ -170,8 +270,35 @@ class CompiledNetwork:
             report.raise_if_errors("CompiledNetwork.verify")
         return report
 
+    def layout(self) -> dict[str, OpLayout]:
+        """Each op's :class:`OpLayout` (convs by name, then ``'fc'``):
+        which channel order every tensor carries and where the forward
+        gathers.  The executor traces exactly these gathers, and
+        :meth:`gathers` counts them."""
+        orders = {"input": np.arange(self.config.in_channels)}
+        prev = "input"
+        out = {}
+        for c in self.convs:
+            lay = _op_layout(
+                c.bp, c.c_out, orders[prev if c.src is None else c.src],
+                c.in_order,
+                None if c.residual is None else orders[c.residual],
+            )
+            out[c.name] = lay
+            orders[c.name] = lay.out_order
+            prev = c.name
+        out["fc"] = _op_layout(self.fc.bp, self.fc.d_out, orders[prev],
+                               self.fc.in_order, logits=True)
+        return out
+
+    def gathers(self) -> int:
+        """The number of channel gathers the forward traces."""
+        return sum(lay.gathers for lay in self.layout().values())
+
     def op_list(self) -> list[tuple[str, str]]:
-        """Human-readable (op, detail) schedule, in execution order."""
+        """Human-readable (op, detail) schedule, in execution order; an op
+        that gathers names what (``gather(src|out|residual)``)."""
+        layout = self.layout()
         ops = []
         for c in self.convs:
             d = (f"spmm[{c.bp.k_in}x{c.bp.n_out}] {c.patch_order}-major "
@@ -190,9 +317,10 @@ class CompiledNetwork:
             if c.pool is not None:
                 d += {"max2": " + maxpool2x2",
                       "max3s2": " + maxpool3x3/2"}[c.pool]
-            ops.append((c.name, d))
+            ops.append((c.name, d + _gather_note(layout[c.name])))
         ops.append(("gap", "global average pool"))
-        ops.append(("fc", f"spmm[{self.fc.bp.k_in}x{self.fc.bp.n_out}]"))
+        ops.append(("fc", f"spmm[{self.fc.bp.k_in}x{self.fc.bp.n_out}]"
+                          + _gather_note(layout["fc"])))
         return ops
 
     def weight_bytes(self) -> tuple[int, int]:

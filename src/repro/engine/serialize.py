@@ -23,6 +23,11 @@ payloads is the certification pass's job (V506).  Format v5 adds each
 conv's ``patch_order`` (``lowering.patch_order``), the row order its
 weights were lowered in and its patches must be built in: v1-v4
 programs were lowered channel-major and always load as ``'channel'``.
+Format v6 adds each conv's and the FC's ``in_order`` (an int32 payload):
+the channel order of the tensor its weight rows read, which the lowering
+takes from the producing layer's stored column order.  v1-v5 programs
+were lowered with rows in channel order and load with ``in_order=None``,
+so their forward gathers as it always did.
 
 Only chain programs (``CNNConfig``: stride-1 convs with ``channel_norm``,
 ReLU and optional 2x2 max pools) have a manifest; saving a graph program
@@ -57,11 +62,13 @@ __all__ = [
 _MANIFEST = "program.json"
 # v2 adds precision/cell_bits + per-bp w_scales; v3 adds per-conv
 # mapping candidates + the fc reorder tag; v4 adds the optional range
-# certificate; v5 adds the per-conv patch order
-_FORMAT_VERSION = 5
-_SUPPORTED_VERSIONS = (1, 2, 3, 4, 5)
+# certificate; v5 adds the per-conv patch order; v6 the input orders
+_FORMAT_VERSION = 6
+_SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6)
 # the first version whose convs may be tap-major (and say so)
 _PATCH_ORDER_VERSION = 5
+# the first version whose weight rows may read a stored channel order
+_IN_ORDER_VERSION = 6
 
 
 def _save_array(directory: str, name: str, arr) -> str:
@@ -108,6 +115,20 @@ def _load_bp(entry: dict, directory: str) -> BlockPatternWeight:
         dict_masks=arr("dict_masks"),
         w_scales=jnp.asarray(arr("w_scales")) if has_scales else None,
     )
+
+
+def _save_order(directory: str, name: str, order) -> str | None:
+    if order is None:
+        return None
+    return _save_array(directory, f"{name}.in_order",
+                       np.asarray(order, np.int32))
+
+
+def _load_order(entry: dict, directory: str, has_order: bool):
+    fname = entry.get("in_order") if has_order else None
+    if fname is None:
+        return None
+    return np.load(os.path.join(directory, fname))
 
 
 def save_program(directory: str, program: CompiledNetwork) -> str:
@@ -167,6 +188,7 @@ def save_program(directory: str, program: CompiledNetwork) -> str:
                     None if c.mapping is None else c.mapping.to_manifest()
                 ),
                 "patch_order": c.patch_order,
+                "in_order": _save_order(tmp, c.name, c.in_order),
             }
         )
     manifest["fc"] = {
@@ -175,6 +197,7 @@ def save_program(directory: str, program: CompiledNetwork) -> str:
         "bias": _save_array(tmp, "fc.bias", program.fc.bias),
         "bp": _bp_manifest("fc", program.fc.bp, tmp),
         "reorder": program.fc.reorder,
+        "in_order": _save_order(tmp, "fc", program.fc.in_order),
     }
     with open(os.path.join(tmp, _MANIFEST), "w") as f:
         json.dump(manifest, f, indent=1)
@@ -332,6 +355,22 @@ def _check_certificate_entry(entry, where: str) -> None:
             )
 
 
+def _check_order_entry(entry: dict, directory: str, where: str) -> None:
+    """A v6 ``in_order``: null (channel order) or an existing payload;
+    whether it is a permutation is the verifier's V208."""
+    _require(entry, ("in_order",), where)
+    fname = entry["in_order"]
+    if fname is None:
+        return
+    if not isinstance(fname, str) or not os.path.exists(
+        os.path.join(directory, fname)
+    ):
+        raise ProgramFormatError(
+            f"payload file for {where}.in_order missing: {fname!r}",
+            rule="M004",
+        )
+
+
 def _check_bp_entry(entry: dict, directory: str, where: str) -> None:
     if not isinstance(entry, dict):
         raise ProgramFormatError(
@@ -407,6 +446,8 @@ def validate_manifest(manifest: dict, directory: str) -> None:
                     f"program manifest {where}.patch_order must be a "
                     "string", rule="M003",
                 )
+        if version >= _IN_ORDER_VERSION:
+            _check_order_entry(e, directory, where)
     fce = manifest["fc"]
     if not isinstance(fce, dict):
         raise ProgramFormatError(
@@ -425,6 +466,8 @@ def validate_manifest(manifest: dict, directory: str) -> None:
             f"payload file for fc.bias missing: {fname!r}", rule="M004"
         )
     _check_bp_entry(fce["bp"], directory, "fc.bp")
+    if version >= _IN_ORDER_VERSION:
+        _check_order_entry(fce, directory, "fc")
     part = manifest.get("partition")
     if part is not None:
         _require(part, ("data", "model", "data_axis", "model_axis"),
@@ -448,8 +491,10 @@ def load_program(directory: str, verify: bool = True) -> CompiledNetwork:
     directory = _resolve_directory(directory)
     manifest = read_manifest(directory)
     validate_manifest(manifest, directory)
-    # before v5 every conv was lowered channel-major, whatever it says
+    # before v5 every conv was lowered channel-major, whatever it says;
+    # before v6 every weight row read its input in channel order
     has_order = manifest["format_version"] >= _PATCH_ORDER_VERSION
+    has_in_order = manifest["format_version"] >= _IN_ORDER_VERSION
     c = manifest["config"]
     cfg = CNNConfig(
         conv_channels=tuple(tuple(x) for x in c["conv_channels"]),
@@ -478,6 +523,7 @@ def load_program(directory: str, verify: bool = True) -> CompiledNetwork:
                     else None
                 ),
                 patch_order=e["patch_order"] if has_order else "channel",
+                in_order=_load_order(e, directory, has_in_order),
             )
             for e in manifest["convs"]
         ]
@@ -488,6 +534,7 @@ def load_program(directory: str, verify: bool = True) -> CompiledNetwork:
             bp=_load_bp(fce["bp"], directory),
             bias=np.load(os.path.join(directory, fce["bias"])),
             reorder=str(fce.get("reorder", "pattern")),
+            in_order=_load_order(fce, directory, has_in_order),
         )
     except (OSError, ValueError) as e:
         raise ProgramFormatError(

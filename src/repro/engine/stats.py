@@ -159,7 +159,9 @@ def stats_from_counts(
 
     convs: the program's ``CompiledConv`` list (pattern_bits source);
     counts / windows: per layer name, as returned by the jitted forward and
-    as computed from the actual input geometry.
+    as computed from the actual input geometry.  The forward counts input
+    channels in the order the layer's rows read them (``op.in_order``);
+    they are put back into channel order here.
     """
     layers = {}
     for op in convs:
@@ -176,7 +178,18 @@ def stats_from_counts(
             kernel_size=kk,
             patterns=patterns,
             windows=int(windows[op.name]),
-            counts=np.asarray(counts[op.name], np.int64),
+            counts=_channel_order(np.asarray(counts[op.name], np.int64),
+                                  op.in_order),
             occurrences=occ,
         )
     return ActivationStats(layers=layers)
+
+
+def _channel_order(counts: np.ndarray, in_order) -> np.ndarray:
+    """``counts`` [c_in, P], row ``i`` for channel ``in_order[i]``, as rows
+    in channel order."""
+    if in_order is None:
+        return counts
+    out = np.empty_like(counts)
+    out[np.asarray(in_order)] = counts
+    return out

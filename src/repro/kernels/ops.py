@@ -100,11 +100,11 @@ def pattern_spmm_raw(
 ) -> jax.Array:
     """Compressed spmm in *reordered* column order (no inverse permutation).
 
-    xm: [M, K]; returns [M, T*tile] where T = w_comp.shape[0].  This is
-    the per-shard building block of the tile-parallel executor: each
-    device runs it on its slab of tiles and the partial outputs are
-    psum-combined *before* the Output Indexing Unit un-permutes columns.
-    ``pattern_spmm`` is this plus the inverse permutation.
+    xm: [M, K]; returns [M, T*tile] where T = w_comp.shape[0].  The
+    executor runs it on every layer (per shard of tiles when sharded) and
+    keeps the stored column order, which the next layer's weight rows
+    were lowered in.  ``pattern_spmm`` is this plus the inverse
+    permutation.
 
     With ``w_scales`` (int8 ``w_comp`` + per-brick row-group scales,
     ``core/quantize.py``) the activations are dynamically quantized per

@@ -1,8 +1,10 @@
+import contextlib
 import json
 import os
 import subprocess
 import sys
 import textwrap
+import unittest.mock
 
 import numpy as np
 import pytest
@@ -46,39 +48,89 @@ def run_virtual_devices(n_devices: int, body: str) -> dict:
     return json.loads(line)
 
 
-def channel_major(program):
-    """``program`` as format v1-v4 compilers lowered it: every conv's
-    weight rows channel-major, re-lowered from its stored weights with
-    its own reorder strategy.  The result carries no range certificate
-    (the one ``program`` had describes other bricks)."""
+def unfolded(program, channel_major: bool = False):
+    """``program`` as compilers before format 6 lowered it: every weight
+    row (the FC's too) reads its input in channel order (``in_order``
+    None), re-lowered from the stored weights with the layer's own reorder
+    strategy; with ``channel_major`` every conv's rows also channel-major,
+    as format v1-v4 compilers lowered them.  The result carries no range
+    certificate (the one ``program`` had describes other bricks)."""
     import dataclasses
 
     from repro.engine.lowering import conv_matrix, lower_matrix
 
+    def relower(wm, reorder):
+        return lower_matrix(wm, program.block, program.tile,
+                            program.precision, reorder=reorder)
+
     convs = []
     for c in program.convs:
-        if c.patch_order == "tap":
+        order = "channel" if channel_major else c.patch_order
+        if c.in_order is not None or order != c.patch_order:
             kk = c.kernel * c.kernel
             wm = np.asarray(c.bp.dense())[: c.k_unpadded, : c.c_out]
-            w = wm.reshape(kk, c.c_in, c.c_out).transpose(2, 1, 0)
+            if c.patch_order == "tap":
+                w = wm.reshape(kk, c.c_in, c.c_out).transpose(2, 1, 0)
+            else:
+                w = wm.reshape(c.c_in, kk, c.c_out).transpose(2, 0, 1)
+            if c.in_order is not None:  # rows by position -> by channel
+                w = w[:, np.argsort(c.in_order)]
             w = w.reshape(c.c_out, c.c_in, c.kernel, c.kernel)
             reorder = "pattern" if c.mapping is None else c.mapping.reorder
-            bp = lower_matrix(conv_matrix(w), c.bp.block, c.bp.tile,
-                              program.precision, reorder=reorder)
-            c = dataclasses.replace(c, bp=bp, patch_order="channel")
+            c = dataclasses.replace(
+                c, bp=relower(conv_matrix(w, order), reorder),
+                patch_order=order, in_order=None,
+            )
         convs.append(c)
-    return dataclasses.replace(program, convs=convs, certificate=None)
+    fc = program.fc
+    if fc.in_order is not None:
+        wm = np.asarray(fc.bp.dense())[: fc.d_in, : fc.d_out]
+        fc = dataclasses.replace(
+            fc, bp=relower(wm[np.argsort(fc.in_order)], fc.reorder),
+            in_order=None,
+        )
+    return dataclasses.replace(program, convs=convs, fc=fc,
+                               certificate=None)
+
+
+def channel_major(program):
+    """``program`` as format v1-v4 compilers lowered it (:func:`unfolded`
+    with every conv channel-major)."""
+    return unfolded(program, channel_major=True)
 
 
 def downgrade_manifest(directory: str, version: int) -> dict:
-    """Rewrite a saved program's manifest as format ``version`` (< 5): no
-    per-conv ``patch_order``.  Returns the rewritten manifest."""
+    """Rewrite a saved program's manifest as format ``version`` (< 6): no
+    ``in_order`` (and, below 5, no per-conv ``patch_order``).  Returns the
+    rewritten manifest."""
     path = os.path.join(directory, "program.json")
     with open(path) as f:
         manifest = json.load(f)
     manifest["format_version"] = version
-    for e in manifest["convs"]:
-        del e["patch_order"]
+    for e in [*manifest["convs"], manifest["fc"]]:
+        del e["in_order"]
+    if version < 5:
+        for e in manifest["convs"]:
+            del e["patch_order"]
     with open(path, "w") as f:
         json.dump(manifest, f)
     return manifest
+
+
+@contextlib.contextmanager
+def pre_v6_columns():
+    """Lower as compilers before format 6 did: under the ``'pattern'``
+    reorder the all-zero columns (a narrow layer's padding) sort first, so
+    a layer's real outputs are not its leading stored columns."""
+    from repro.core import sparse
+
+    real = sparse.reorder_columns
+
+    def legacy(masks, strategy="pattern"):
+        if strategy != "pattern":
+            return real(masks, strategy)
+        keys = np.array([m.tobytes() for m in np.asarray(masks, bool)])
+        return np.argsort(keys, kind="stable").astype(np.int32)
+
+    with unittest.mock.patch.object(sparse, "reorder_columns", legacy):
+        yield

@@ -356,7 +356,7 @@ def test_manifest_v4_carries_certificate(prog_int8, tmp_path):
     serialize.save_program(d, prog_int8)
     with open(os.path.join(d, "program.json")) as f:
         manifest = json.load(f)
-    assert manifest["format_version"] == 5
+    assert manifest["format_version"] == 6
     assert manifest["certificate"]["precision"] == "int8"
 
 

@@ -100,7 +100,8 @@ def test_lowering_is_lossless(mini):
 
     cfg, params, bits, prog = mini
     for i, op in enumerate(prog.convs, start=1):
-        wm = conv_matrix(np.asarray(params[f"conv{i}"]["w"]), op.patch_order)
+        wm = conv_matrix(np.asarray(params[f"conv{i}"]["w"]), op.patch_order,
+                         op.in_order)
         dense = np.asarray(op.bp.dense())[: wm.shape[0], : wm.shape[1]]
         np.testing.assert_array_equal(dense.astype(np.float32), wm)
         assert 0.0 < block_density(op.bp) <= 1.0
@@ -258,12 +259,13 @@ def test_serialize_roundtrip_bit_exact(mini, tmp_path):
 
 
 def test_manifest_v5_carries_patch_order(mini, tmp_path):
-    """A save is format v5 and names each conv's patch order."""
+    """A save (format v6 since the input orders) names each conv's patch
+    order, as v5 did."""
     *_, prog = mini
     path = save_program(str(tmp_path / "prog"), prog)
     with open(os.path.join(path, "program.json")) as f:
         manifest = json.load(f)
-    assert manifest["format_version"] == 5
+    assert manifest["format_version"] == 6
     assert [e["patch_order"] for e in manifest["convs"]] \
         == [c.patch_order for c in prog.convs] == ["channel", "channel", "tap"]
 

@@ -176,11 +176,15 @@ def test_sharded_matrix_subprocess():
     params, bits = project_params(params, dicts)
     x = jax.random.normal(jax.random.PRNGKey(5), (8, 1, 12, 12))
 
-    out = {"diffs": {}, "n_tiles": {}}
+    out = {"diffs": {}, "n_tiles": {}, "folds": {}}
     for gname, ecfg in [("mxu", EngineConfig()),
                         ("fine", EngineConfig(block=9, tile=8))]:
         prog = compile_network(cfg, params, bits, ecfg=ecfg)
         out["n_tiles"][gname] = [c.bp.n_tiles for c in prog.convs]
+        # some layer's rows read a stored order that is not channel order
+        out["folds"][gname] = any(
+            not np.array_equal(op.in_order, np.arange(len(op.in_order)))
+            for op in [*prog.convs, prog.fc])
         ref = np.asarray(make_forward(prog, backend="xla")(x))
         for n in (1, 2, 4, 8):
             mesh = make_mesh((1, n), ("data", "model"),
@@ -228,6 +232,7 @@ def test_sharded_matrix_subprocess():
     print(json.dumps(out))
     """)
     assert res["n_tiles"]["fine"] == [1, 2, 3]  # uneven vs 2/4/8-way meshes
+    assert res["folds"]["fine"]  # the sharded path reads stored orders
     for key, diff in res["diffs"].items():
         assert diff < 1e-4, (key, diff)
     for key, val in res.items():

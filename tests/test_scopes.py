@@ -80,13 +80,38 @@ def test_every_forward_op_carries_a_layer_and_stage(prog, collect_stats):
     assert [n for n in traced if not scope.match(n)] == []
     seen = {scope.match(n).group(1, 2) for n in traced}
     want = {(c, s) for c in layers[:-2] for s in
-            ("patches", "spmm", "permute", "epilogue")}
-    want |= {("gap", "epilogue"), ("fc", "spmm"), ("fc", "permute"),
-             ("fc", "epilogue")}
+            ("patches", "spmm", "epilogue")}
+    want |= {("gap", "epilogue"), ("fc", "spmm"), ("fc", "epilogue")}
     if collect_stats:
         want |= {(c, "stats") for c in layers[:-2]}
     assert want <= seen
     assert all(s != "stats" for _, s in seen) or collect_stats
+    assert _permutes(seen) == _gathering(prog)
+
+
+def _permutes(seen) -> set[str]:
+    return {layer for layer, stage in seen if stage == "permute"}
+
+
+def _gathering(prog) -> set[str]:
+    """The layers whose channel orders the forward gathers: a ``permute``
+    scope exists there and nowhere else."""
+    return {name for name, lay in prog.layout().items() if lay.gathers}
+
+
+def test_permute_scopes_only_where_orders_differ(prog):
+    """A program lowered as before format 6 (rows in channel order,
+    padding first) gathers at every narrow layer's output and at the
+    logits; each of those, and only those, has a ``permute`` scope."""
+    from conftest import pre_v6_columns, unfolded
+
+    with pre_v6_columns():
+        old = unfolded(prog)
+    op_names = re.findall(r'op_name="([^"]*)"', _served_hlo(old, False))
+    seen = {tuple(n.split("/")[1:3]) for n in op_names
+            if n.startswith("jit(forward)/")}
+    assert _permutes(seen) == _gathering(old) == {
+        op.name for op in prog.convs} | {"fc"}
 
 
 def test_every_resnet_forward_op_carries_a_layer_and_stage():
@@ -110,10 +135,11 @@ def test_every_resnet_forward_op_carries_a_layer_and_stage():
     assert {(n, "residual") for n in residual} <= seen
     assert {n for n, s in seen if s == "residual"} == residual
     want = {(c, s) for c in layers[:-2] for s in
-            ("patches", "spmm", "permute", "epilogue")}
+            ("patches", "spmm", "epilogue")}
     # layer1.0.downsample's patches are layer1.0.conv1's (the same 1x1
     # view of the same input), which the compiled program builds once
     assert want - {("layer1.0.downsample", "patches")} <= seen
+    assert _permutes(seen) == _gathering(prog)
 
 
 @pytest.mark.parametrize("collect_stats", [False, True])

@@ -6,11 +6,12 @@ compiled and its jitted forward lowered at a batch of 4. The pins are the
 sha256 of the lowered HLO with its metadata stripped (the instructions
 the chain traces, before any compiler pass) and the sha256 of its logits
 on four seeded images, each on the XLA path and in the Pallas
-interpreter. They were recorded before the executor learned strides,
-residual adds and folded batch norm, so a chain whose every layer keeps
-the defaults traces the same program as before and computes the same
-bits. The logits are computed in a child process held to one core, since
-XLA's CPU kernels may split their sums by the number of cores.
+interpreter. They were recorded when each layer's stored output order
+was first folded into the next layer's weight rows (manifest format 6),
+so the forward gathers no channels; the program a format-5 manifest
+loads, which gathers after every layer, gives the same logits to fp32
+rounding. The logits are computed in a child process held to one core,
+since XLA's CPU kernels may split their sums by the number of cores.
 """
 
 import json
@@ -18,18 +19,25 @@ import os
 import subprocess
 import sys
 
+import jax
+import numpy as np
 import pytest
+from conftest import downgrade_manifest, pre_v6_columns, unfolded
+
+from repro.core.pruning import build_dictionaries, magnitude_prune, project_params
+from repro.engine import compile_network, load_program, make_forward, save_program
+from repro.models.cnn import conv_weight_names, init_cnn, mini_cnn_config
 
 SRC = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
 
 PINS = {
     "xla": {
-        "hlo": "928e5af0724d7282a75a449f19cbe017286c80c3152540bad584b2c6600d9cc6",
-        "logits": "f21e166a6b467e332b6169de3cdc91a5e767a2bb5619e7846e595f072399650f",
+        "hlo": "0f9c9b824d6f05dadae22866a6c0879f4b27d39237d1ee4923de7809773934ee",
+        "logits": "8fe775ac449524c133912614c472c5aef3abc907eaf1db9b87052e1d1400df21",
     },
     "pallas": {
-        "hlo": "dd93c7af0aba2a27f70cd9ea17b550ee235d7863ae9018c307c5270053544e0c",
-        "logits": "8c2b67e93775809c9e53f5e14cda7f2373e5287261235472fd89feaa37cff8cd",
+        "hlo": "cad983103162ae50c98bb344d4ec61f97783ec864300b6d3e746cc6bc4bd60b0",
+        "logits": "2794bc09ec8b3b12b4b2747952db2db180344e76b47027b37e0f32a2ea946ad1",
     },
 }
 
@@ -86,3 +94,30 @@ def digests():
 @pytest.mark.parametrize("what", ["hlo", "logits"])
 def test_vgg_chain_forward_is_pinned(digests, backend, what):
     assert digests[backend][what] == PINS[backend][what]
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_folded_chain_matches_its_format5_copy(backend, tmp_path):
+    """The pinned chain gathers nothing; the same program saved as format 5
+    (rows in channel order, padding first) gathers after every layer and
+    at the logits, and gives the same logits to fp32 rounding."""
+    cfg = mini_cnn_config(num_classes=4, input_hw=12, widths=(8, 16, 16))
+    params = init_cnn(cfg, jax.random.PRNGKey(0))
+    names = conv_weight_names(cfg)
+    params = magnitude_prune(params, names, 0.7)
+    params, bits = project_params(params, build_dictionaries(params, names, 4))
+    prog = compile_network(cfg, params, bits)
+    with pre_v6_columns():
+        path = save_program(str(tmp_path / "v5"), unfolded(prog))
+    downgrade_manifest(path, 5)
+    old = load_program(path)
+    assert prog.gathers() == 0
+    assert old.gathers() == len(old.convs) + 1
+    kw = {"backend": "pallas", "interpret": True} if backend == "pallas" \
+        else {"backend": "xla"}
+    x = np.asarray(jax.random.normal(jax.random.PRNGKey(3), (4, 1, 12, 12)),
+                   np.float32)
+    np.testing.assert_allclose(
+        np.asarray(make_forward(prog, **kw)(x)),
+        np.asarray(make_forward(old, **kw)(x)), rtol=1e-5, atol=1e-5,
+    )
