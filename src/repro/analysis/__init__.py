@@ -5,7 +5,7 @@ Three passes, one diagnostics currency:
 * :mod:`repro.analysis.verify` — an execution-free program verifier over
   ``BlockPatternWeight`` / ``CompiledNetwork`` / ``NetworkPartition`` /
   serialized manifests (rules ``V1xx``–``V4xx``, ``M0xx``).  Runs at the
-  trust boundaries: ``compile_network(verify=...)``,
+  trust boundaries: ``CompileOptions(verify=...)``,
   ``load_program(verify=True)``, ``partition_network``.
 * :mod:`repro.analysis.ranges` — the range & bit-width certification
   pass (rules ``V5xx``): an abstract interpreter that propagates

@@ -10,7 +10,7 @@ re-running the verifier.
 
 :class:`Report` collects diagnostics and is the single currency between
 the rule passes (``analysis/verify.py``, ``analysis/lint.py``), their
-call sites at the trust boundaries (``compile_network(verify=...)``,
+call sites at the trust boundaries (``CompileOptions(verify=...)``,
 ``serialize.load_program(verify=...)``, ``partition_network``), and the
 ``python -m repro.analysis`` CLI (which renders it as text or JSON).
 

@@ -20,11 +20,6 @@ L004   timing code must synchronize before reading the clock: a function
        that reads the clock twice and launches jax work in between must
        call ``block_until_ready``/``device_get``, else it times dispatch
        instead of execution
-L005   no new internal imports of the deprecated serving request types
-       (``repro.engine.service.ClassifyRequest``,
-       ``repro.runtime.serve.Request``) — internal code uses the unified
-       ``repro.serve.Request``; the shims exist only for external
-       callers during the deprecation window
 L006   lock discipline: in a class that holds a ``threading.Lock`` /
        ``RLock`` attribute, every method that mutates shared instance
        state (attributes assigned in ``__init__``) must do so inside a
@@ -480,34 +475,6 @@ def _rule_l004(r: Report, mod: _Module, f: _Func):
     )
 
 
-# deprecated name -> the modules it must no longer be imported from
-_DEPRECATED_IMPORTS = {
-    ("repro.engine.service", "ClassifyRequest"),
-    ("engine.service", "ClassifyRequest"),
-    ("repro.engine", "ClassifyRequest"),
-    ("repro.runtime.serve", "Request"),
-    ("runtime.serve", "Request"),
-}
-
-
-def _rule_l005(r: Report, mod: _Module):
-    for node in ast.walk(mod.tree):
-        if not isinstance(node, ast.ImportFrom) or not node.module:
-            continue
-        for a in node.names:
-            if (node.module, a.name) not in _DEPRECATED_IMPORTS:
-                continue
-            if _allowed(mod, "L005", node.lineno):
-                continue
-            r.add(
-                "L005",
-                f"import of deprecated {node.module}.{a.name} — use "
-                "repro.serve.Request (the shim is for external callers "
-                "only)",
-                layer=mod.name, location=_loc(mod, node.lineno),
-            )
-
-
 _LOCK_CTORS = {"Lock", "RLock"}
 
 
@@ -672,7 +639,6 @@ def lint_paths(paths: list[str]) -> Report:
         in_obs = f"{os.sep}obs{os.sep}" in mod.path or mod.name.startswith(
             "repro.obs"
         )
-        _rule_l005(r, mod)
         _rule_l006(r, mod)
         for f in mod.funcs.values():
             if f.key in reachable:

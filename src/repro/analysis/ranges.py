@@ -82,7 +82,7 @@ bit-deterministic across processes, and rides in manifest v4
 (``engine/serialize.py``).
 
 Entry points mirror the verifier's: :func:`analyze_network` (in-memory,
-wired into ``compile_network(verify=...)`` as the ``ranges`` compile
+wired into ``CompileOptions(verify=...)`` as the ``ranges`` compile
 span) and :func:`analyze_saved` (serialized directories; the ``python
 -m repro.analysis ranges <dir>`` CLI wraps it).
 """

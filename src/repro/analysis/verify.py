@@ -72,7 +72,7 @@ Entry points:
 * :func:`verify_manifest` / :func:`verify_saved` — serialized directories
   (static manifest checks first, payload checks only if those pass).
 
-Trust-boundary wiring: ``compile_network(..., verify='strict')`` runs
+Trust-boundary wiring: ``CompileOptions(verify='strict')`` runs
 :func:`verify_network` as a post-condition, ``load_program(verify=True)``
 (the default) verifies untrusted files after loading, and
 ``partition_network`` validates its partition cover.  The ``python -m
@@ -84,7 +84,6 @@ from __future__ import annotations
 import numpy as np
 
 from repro.analysis.diagnostics import (
-    ERROR,
     WARNING,
     ProgramFormatError,
     Report,

@@ -24,7 +24,7 @@ packing.  This module searches that space:
     than fixed by construction, which ``check_baseline.py`` gates.
 
 ``engine/lowering.py`` drives this per layer under
-``compile_network(optimize='auto')``; the chosen candidate rides on
+``CompileOptions(optimize='auto')``; the chosen candidate rides on
 ``CompiledConv.mapping`` into ``hardware_report`` pricing and the saved
 manifest (format v3).
 """

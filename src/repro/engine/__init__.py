@@ -27,8 +27,8 @@ free when off (``tracer=None`` resolves to a shared no-op tracer; a
 tracer never reaches the jitted forward).
 
 * **Tracing.** Pass one ``obs.Tracer`` through the layers you care
-  about: ``compile_network(..., tracer=tr)`` records the lowering
-  phases (``prune -> reorder -> pack -> quantize`` under per-layer
+  about: ``CompileOptions(tracer=tr)`` makes ``compile_network``
+  record the lowering phases (``prune -> reorder -> pack -> quantize`` under per-layer
   ``lower:<name>`` spans), and ``InferenceService(..., tracer=tr)``
   emits per-request async lifecycles (enqueue ``b`` -> admit ``n`` ->
   done ``e``) plus queue-depth/slot-occupancy counter tracks.
@@ -54,8 +54,8 @@ tracer never reaches the jitted forward).
 
 Mapping optimization
 --------------------
-``compile_network(optimize='auto')`` (or ``optimize=MappingSearchConfig(
-...)``) runs the per-layer mapping design-space search
+``CompileOptions(optimize='auto')`` (or ``optimize=MappingSearchConfig(
+...)``) makes ``compile_network`` run the per-layer mapping design-space search
 (``core/mapsearch.py``) before lowering each conv: a seeded greedy
 descent with restarts over crossbar dims x packing order
 (``block_order``) x column-reorder strategy, priced by the simulator's
@@ -81,9 +81,8 @@ How it composes:
   the same ``BlockPatternWeight`` contract, so ``partition_network``
   and the mesh executor apply unchanged.
 * **serialization** — the chosen ``MappingCandidate`` per conv and the
-  FC reorder tag ride in the manifest (format v3; v1/v2 programs load
-  as the fixed scheme) and ``hardware_report`` prices each layer at its
-  stored candidate after reload.
+  FC reorder tag ride in the manifest and ``hardware_report`` prices
+  each layer at its stored candidate after reload.
 * **tracing** — each layer's search lands as a ``search:<name>``
   compile span carrying evaluations / chosen candidate / area-vs-fixed,
   next to the ``lower:<name>`` spans.
@@ -114,20 +113,15 @@ one ``submit``/``stream``/``run`` verb set over both backends:
 
 ``examples/serve_http.py`` boots the full stack and reports req/s,
 first-result p50/p99, and slot occupancy; ``benchmarks/bench_engine.py
-http_service`` gates the same numbers in CI.  The old entry points
-(``engine.service.ClassifyRequest``, ``runtime.serve.Request``) remain
-as deprecated shims that construct ``repro.serve.Request`` and warn.
+http_service`` gates the same numbers in CI.
 
 Compile options
 ---------------
 :class:`CompileOptions` is the one frozen object carrying everything
 ``compile_network`` accepts beyond the network itself — lowering
-geometry (``block``/``tile``/``precision``/``cell_bits``, mirroring
-:class:`EngineConfig`) plus the compile-pass switches
-(``verify``/``optimize``/``tracer``).  Prefer
-``compile_network(cfg, params, bits, options=CompileOptions(...))``;
-the loose kwargs remain as deprecated aliases that compile bit-identical
-programs while emitting ``DeprecationWarning``.
+geometry (``block``/``tile``/``precision``/``cell_bits``) plus the
+compile-pass switches (``verify``/``optimize``/``tracer``):
+``compile_network(cfg, params, bits, options=CompileOptions(...))``.
 
 Verification
 ------------
@@ -135,7 +129,7 @@ Verification
 over the operands, no kernel execution — and is wired in at every
 trust boundary:
 
-* ``compile_network(..., verify='strict')`` verifies the freshly built
+* ``CompileOptions(verify='strict')`` verifies the freshly built
   program and raises ``analysis.VerificationError`` listing every
   violated invariant; ``verify='warn'`` emits a single warning instead;
   the default ``None`` skips it (compile output is trusted by
@@ -174,15 +168,14 @@ for the quantized path.  Structural rules are ``V1xx``–``V4xx``/
 saturation/denormal, dead scale groups, range divergence, unreachable
 cell slices, stale stored certificates).
 
-When ``compile_network(..., verify=...)`` is on, the pass runs right
+When ``CompileOptions(verify=...)`` is on, the pass runs right
 after verification and attaches a ``RangeCertificate`` to the program:
 per-layer activation bounds plus a certified minimum cells-per-weight
 table on the layer's reference scale grid.  The certificate rides in
-manifest v4 and later (v1–v3 saves still load, without one; v5 adds
-each conv's ``patch_order``, and v1–v4 saves load channel-major),
-``hardware_report()`` prices it as a ``certified_potential`` section
-(certified-vs-stored crossbar area/energy, exactly on the simulator's
-own cost chain), and ``python -m repro.analysis ranges <dir>`` recomputes
+the manifest, ``hardware_report()`` prices it as a
+``certified_potential`` section (certified-vs-stored crossbar
+area/energy, exactly on the simulator's own cost chain), and
+``python -m repro.analysis ranges <dir>`` recomputes
 and cross-checks it for a saved program (rule ``V506`` fires if the
 stored certificate disagrees).  ``python -m repro.analysis all <dir>``
 runs verify + lint + ranges with one merged JSON report.
@@ -215,7 +208,6 @@ from repro.core.mapsearch import (
 from repro.engine.lowering import (
     PRECISIONS,
     CompileOptions,
-    EngineConfig,
     compile_network,
     conv_mapping_search,
     lower_conv,
@@ -224,8 +216,6 @@ from repro.engine.lowering import (
 )
 from repro.engine.program import CompiledConv, CompiledFC, CompiledNetwork
 from repro.engine.serialize import load_program, save_program
-# back-compat re-export for the deprecation window
-from repro.engine.service import ClassifyRequest  # lint: allow(L005)
 from repro.engine.service import InferenceService
 from repro.engine.stats import (
     ActivationStats,
@@ -237,7 +227,6 @@ from repro.engine.stats import (
 __all__ = [
     "PRECISIONS",
     "CompileOptions",
-    "EngineConfig",
     "compile_network",
     "conv_mapping_search",
     "lower_conv",
@@ -256,7 +245,6 @@ __all__ = [
     "extract_patches",
     "save_program",
     "load_program",
-    "ClassifyRequest",
     "InferenceService",
     "SchedulerFull",
     "SchedulerMetrics",

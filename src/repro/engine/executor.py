@@ -17,8 +17,8 @@ it in (the reordered columns of ``bp.new_order``): the next layer's
 weight rows were lowered in that order, and bias, ``channel_norm``, ReLU
 and the pools act per channel.  The Output Indexing Unit's gather runs
 only where ``CompiledNetwork.layout`` finds two orders that differ — at
-the logits, on programs from manifests before format 6, and across a
-residual add whose addends disagree.
+the logits, where a layer's rows were lowered in another order than its
+input carries, and across a residual add whose addends disagree.
 
 With ``collect_stats=True`` the forward additionally counts, per layer
 and per OU row-group (= (input channel, pattern) pair), how many input

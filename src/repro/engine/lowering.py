@@ -61,7 +61,7 @@ from repro.models.cnn import CNNConfig, ConvSpec, out_sizes
 from repro.models.resnet import ResNetConfig
 from repro.obs.trace import NULL_TRACER, Tracer
 
-__all__ = ["EngineConfig", "CompileOptions", "PRECISIONS", "PATCH_ORDERS",
+__all__ = ["CompileOptions", "PRECISIONS", "PATCH_ORDERS",
            "patch_order", "conv_matrix", "fold_bn", "lower_matrix",
            "lower_conv",
            "lower_fc", "conv_mapping_search", "compile_network"]
@@ -74,53 +74,51 @@ MIN_TAP_CHANNELS = 8
 
 
 @dataclasses.dataclass(frozen=True)
-class EngineConfig:
-    """Compile-time geometry of the spmm lowering.
-
-    Defaults match the Pallas kernel's MXU-aligned bricks; smaller values
-    trade alignment for finer-grained zero compression (useful on the XLA
-    CPU path where kernel-granular blocks expose the pruning sparsity).
-
-    ``precision`` selects the stored weight representation: 'fp32' (the
-    historical exact path) or 'int8' — per-row-group symmetric int8
-    bricks + fp32 scales (``core/quantize.py``), the paper's bit-sliced
-    cell storage made executable.  ``cell_bits`` is the RRAM cell width
-    the int payload is sliced over for hardware pricing (4-bit cells by
-    default, matching ``CrossbarConfig``); it does not change the stored
-    numbers, only how ``hardware_report`` derives cells-per-weight.
-    """
-
-    block: int = 128
-    tile: int = 128
-    precision: str = "fp32"
-    cell_bits: int = 4
-
-    def __post_init__(self):
-        if self.precision not in PRECISIONS:
-            raise ValueError(
-                f"precision must be one of {PRECISIONS}, got "
-                f"{self.precision!r}"
-            )
-        if self.cell_bits < 1:
-            raise ValueError(f"cell_bits must be >= 1, got {self.cell_bits}")
-
-
-@dataclasses.dataclass(frozen=True)
 class CompileOptions:
     """Everything :func:`compile_network` accepts beyond the network itself.
 
-    One frozen object in place of the loose kwargs that accreted on the
-    compile entry point (``ecfg``/``precision``/``tracer``/``verify``/
-    ``optimize``) — build it once, thread it through configs and tests,
-    and the compile call stays ``compile_network(cfg, params, bits,
-    options=opts)`` no matter how many knobs exist.
+    The lowering geometry:
 
-    The geometry fields mirror :class:`EngineConfig` (same defaults, same
-    validation); :meth:`engine_config` projects them back out for the
-    ``lower_*`` helpers, which keep taking a plain ``EngineConfig``.
+    * ``block``/``tile`` — the spmm brick's K rows and N columns.  The
+      defaults match the Pallas kernel's MXU-aligned bricks; smaller
+      values trade alignment for finer-grained zero compression (useful
+      on the XLA CPU path, where kernel-granular blocks expose the
+      pruning sparsity).
+    * ``precision`` — the stored weight representation: 'fp32' (exact)
+      or 'int8', per-row-group symmetric int8 bricks + fp32 scales
+      (``core/quantize.py``), the paper's bit-sliced cell storage made
+      executable.
+    * ``cell_bits`` — the RRAM cell width the int payload is sliced over
+      for hardware pricing (4-bit cells by default, matching
+      ``CrossbarConfig``); it does not change the stored numbers, only
+      how ``hardware_report`` derives cells-per-weight.
 
-    ``verify``/``optimize``/``tracer`` carry the compile-pass switches —
-    see :func:`compile_network` for their semantics.
+    The compile-pass switches:
+
+    * ``verify`` — post-condition check of the compiled program via
+      ``repro.analysis.verify``: ``'strict'`` raises
+      :class:`~repro.analysis.diagnostics.VerificationError` on any error
+      diagnostic, ``'warn'`` emits a Python warning instead, ``None``
+      (default) skips the pass on this hot compile path.  When the
+      structural pass is clean, the range certification pass
+      (``repro.analysis.ranges``, its own ``ranges`` compile span) also
+      runs: V5xx diagnostics join the same report and the resulting
+      :class:`~repro.analysis.ranges.RangeCertificate` is attached as
+      ``program.certificate``.
+    * ``optimize`` — per-layer mapping design-space search
+      (``core/mapsearch.py``): ``'auto'`` uses the default
+      :class:`~repro.core.mapsearch.MappingSearchConfig`, or pass a config
+      to pick axes/seed/budget; ``None`` (default) keeps the fixed paper
+      scheme.  The chosen candidates ride on ``CompiledConv.mapping``
+      (priced by ``hardware_report``, saved in the manifest) and each
+      layer's search lands as a ``search:<name>`` compile span.
+    * ``tracer`` — optional span tracer (``obs/trace.py``).  The whole
+      compile becomes a ``compile_network`` span containing one
+      ``lower:<name>`` span per layer, each wrapping its phase spans
+      (prune -> reorder -> pack -> quantize), so a Perfetto load of the
+      trace shows exactly where compile time goes.  The
+      ``compile_network`` span's ``gathers`` arg counts the channel
+      gathers the forward will trace (``CompiledNetwork.gathers``).
     """
 
     block: int = 128
@@ -151,18 +149,6 @@ class CompileOptions:
                 f"optimize must be None, 'auto' or a MappingSearchConfig, "
                 f"got {self.optimize!r}"
             )
-
-    @classmethod
-    def from_engine_config(cls, ecfg: EngineConfig, **kw) -> "CompileOptions":
-        """Lift a lowering geometry into full compile options."""
-        return cls(block=ecfg.block, tile=ecfg.tile,
-                   precision=ecfg.precision, cell_bits=ecfg.cell_bits, **kw)
-
-    def engine_config(self) -> EngineConfig:
-        """The :class:`EngineConfig` these options imply."""
-        return EngineConfig(block=self.block, tile=self.tile,
-                            precision=self.precision,
-                            cell_bits=self.cell_bits)
 
 
 def _pad_axis(a: np.ndarray, axis: int, mult: int) -> np.ndarray:
@@ -269,7 +255,7 @@ def lower_conv(
     b: np.ndarray,
     pattern_bits: np.ndarray | None,
     out_hw: int,
-    ecfg: EngineConfig,
+    options: CompileOptions,
     tracer: Tracer | None = None,
     mapping: MappingCandidate | None = None,
     in_order: np.ndarray | None = None,
@@ -292,7 +278,7 @@ def lower_conv(
     if pattern_bits is None:
         pattern_bits = masks_to_bits(kernel_masks(w))
     reorder = mapping.reorder if mapping is not None else "pattern"
-    order = patch_order(c_in, kh, ecfg.block)
+    order = patch_order(c_in, kh, options.block)
     in_order = _order_or_identity(in_order, c_in)
     return CompiledConv(
         name=name,
@@ -300,8 +286,8 @@ def lower_conv(
         c_out=c_out,
         kernel=kh,
         out_hw=out_hw,
-        bp=lower_matrix(conv_matrix(w, order, in_order), ecfg.block,
-                        ecfg.tile, ecfg.precision, tracer=tracer,
+        bp=lower_matrix(conv_matrix(w, order, in_order), options.block,
+                        options.tile, options.precision, tracer=tracer,
                         reorder=reorder),
         bias=np.asarray(b, np.float32).copy(),
         pattern_bits=np.asarray(pattern_bits, np.int64).copy(),
@@ -324,7 +310,7 @@ def _order_or_identity(order, n: int) -> np.ndarray:
 
 
 def lower_fc(
-    w: np.ndarray, b: np.ndarray, ecfg: EngineConfig,
+    w: np.ndarray, b: np.ndarray, options: CompileOptions,
     tracer: Tracer | None = None, reorder: str = "pattern",
     in_order: np.ndarray | None = None,
 ) -> CompiledFC:
@@ -336,22 +322,22 @@ def lower_fc(
     return CompiledFC(
         d_in=d_in,
         d_out=d_out,
-        bp=lower_matrix(w[in_order], ecfg.block, ecfg.tile,
-                        ecfg.precision, tracer=tracer, reorder=reorder),
+        bp=lower_matrix(w[in_order], options.block, options.tile,
+                        options.precision, tracer=tracer, reorder=reorder),
         bias=np.asarray(b, np.float32).copy(),
         reorder=reorder,
         in_order=in_order,
     )
 
 
-def _fixed_candidate(ecfg: EngineConfig) -> MappingCandidate:
+def _fixed_candidate(options: CompileOptions) -> MappingCandidate:
     """The fixed scheme a search must match-or-beat: the paper's default
     geometry, with cells/weight derived from the program's precision the
     same way ``hardware_report`` derives it."""
     base = CrossbarConfig()
     cells = (
-        n_cell_slices(ecfg.cell_bits)
-        if ecfg.precision == "int8"
+        n_cell_slices(options.cell_bits)
+        if options.precision == "int8"
         else base.cells_per_weight
     )
     return MappingCandidate(
@@ -367,12 +353,12 @@ def conv_mapping_search(
     w: np.ndarray,
     pattern_bits: np.ndarray | None,
     out_hw: int,
-    ecfg: EngineConfig = EngineConfig(),
+    options: CompileOptions = CompileOptions(),
     search: MappingSearchConfig | None = None,
 ) -> MappingSearchResult:
     """Run the mapping design-space search for one conv layer.
 
-    Builds exactly the search inputs ``compile_network(optimize=...)``
+    Builds exactly the search inputs ``CompileOptions(optimize=...)``
     uses — the layer's pattern bits, the block masks of the padded
     matmul view in the row order the lowering stores (:func:`patch_order`;
     input channels in channel order, before the layer's ``in_order``
@@ -386,19 +372,19 @@ def conv_mapping_search(
     if pattern_bits is None:
         pattern_bits = masks_to_bits(kernel_masks(w))
     kernel_size = w.shape[2] * w.shape[3]
-    order = patch_order(w.shape[1], w.shape[2], ecfg.block)
+    order = patch_order(w.shape[1], w.shape[2], options.block)
     wp = _pad_axis(
-        _pad_axis(conv_matrix(w, order), 0, ecfg.block), 1, ecfg.tile
+        _pad_axis(conv_matrix(w, order), 0, options.block), 1, options.tile
     )
-    masks = nonzero_block_masks(wp, ecfg.block)
+    masks = nonzero_block_masks(wp, options.block)
     return search_layer_mapping(
         np.asarray(pattern_bits, np.int64),
         kernel_size=kernel_size,
         windows=out_hw * out_hw,
-        fixed=_fixed_candidate(ecfg),
+        fixed=_fixed_candidate(options),
         search=search,
         masks=masks,
-        tile=ecfg.tile,
+        tile=options.tile,
     )
 
 
@@ -406,11 +392,6 @@ def compile_network(
     cfg: CNNConfig | ResNetConfig,
     params: dict,
     pattern_bits: dict[str, np.ndarray] | None = None,
-    ecfg: EngineConfig | None = None,
-    precision: str | None = None,
-    tracer: Tracer | None = None,
-    verify: str | None = None,
-    optimize: "str | MappingSearchConfig | None" = None,
     *,
     options: CompileOptions | None = None,
 ) -> CompiledNetwork:
@@ -426,78 +407,11 @@ def compile_network(
       pattern_bits: per-conv packed pattern bitmasks
         (``PruneResult.pattern_bits``); recovered from the weights' nonzero
         structure for layers not listed (dense layers: all-ones).
-      options: a :class:`CompileOptions` carrying the lowering geometry
-        and every compile-pass switch.  This is the preferred form; the
-        loose keyword arguments below are deprecated aliases kept for one
-        release and cannot be combined with ``options=``.
-      ecfg: deprecated — spmm lowering geometry (block/tile, stored
-        precision); use the matching :class:`CompileOptions` fields.
-      precision: deprecated — shorthand override of ``ecfg.precision``
-        ('fp32'/'int8'); use ``CompileOptions(precision=...)``.
-      tracer: deprecated alias of ``CompileOptions(tracer=...)``: optional
-        span tracer (``obs/trace.py``).  The whole compile becomes a
-        ``compile_network`` span containing one ``lower:<name>`` span per
-        layer, each wrapping its phase spans
-        (prune -> reorder -> pack -> quantize), so a Perfetto load of the
-        trace shows exactly where compile time goes.  The
-        ``compile_network`` span's ``gathers`` arg counts the channel
-        gathers the forward will trace (``CompiledNetwork.gathers``).
-      verify: deprecated alias of ``CompileOptions(verify=...)``:
-        post-condition check of the compiled program via
-        ``repro.analysis.verify`` — ``'strict'`` raises
-        :class:`~repro.analysis.diagnostics.VerificationError` on any
-        error diagnostic, ``'warn'`` emits a Python warning instead,
-        ``None`` (default) skips the pass on this hot compile path.
-        When the structural pass is clean, the range certification pass
-        (``repro.analysis.ranges``, its own ``ranges`` compile span)
-        also runs: V5xx diagnostics join the same report and the
-        resulting :class:`~repro.analysis.ranges.RangeCertificate` is
-        attached as ``program.certificate``.
-      optimize: deprecated alias of ``CompileOptions(optimize=...)``:
-        per-layer mapping design-space search
-        (``core/mapsearch.py``) — ``'auto'`` uses the default
-        :class:`~repro.core.mapsearch.MappingSearchConfig`, or pass a
-        config to pick axes/seed/budget; ``None`` (default) keeps the
-        fixed paper scheme.  The chosen candidates ride on
-        ``CompiledConv.mapping`` (priced by ``hardware_report``, saved in
-        manifest v3) and each layer's search lands as a
-        ``search:<name>`` compile span.
-
-    The deprecated-kwargs form compiles a bit-identical program to the
-    equivalent ``options=`` form (``tests/test_compile_options.py`` pins
-    this), it just warns on the way.
+      options: the lowering geometry and every compile-pass switch
+        (:class:`CompileOptions`); None: ``CompileOptions()``.
     """
-    legacy = [
-        name for name, value in (
-            ("ecfg", ecfg), ("precision", precision), ("tracer", tracer),
-            ("verify", verify), ("optimize", optimize),
-        ) if value is not None
-    ]
-    if options is not None:
-        if legacy:
-            raise TypeError(
-                "compile_network: pass options=CompileOptions(...) alone; "
-                f"also got deprecated kwarg(s) {legacy}"
-            )
-    else:
-        if legacy:
-            warnings.warn(
-                "compile_network's loose kwargs "
-                "(ecfg/precision/tracer/verify/optimize) are deprecated; "
-                "pass options=CompileOptions(...) instead",
-                DeprecationWarning, stacklevel=2,
-            )
-        base = ecfg if ecfg is not None else EngineConfig()
-        options = CompileOptions(
-            block=base.block,
-            tile=base.tile,
-            precision=precision if precision is not None else base.precision,
-            cell_bits=base.cell_bits,
-            verify=verify,
-            optimize=optimize,
-            tracer=tracer,
-        )
-    ecfg = options.engine_config()
+    if options is None:
+        options = CompileOptions()
     verify = options.verify
     if isinstance(options.optimize, MappingSearchConfig):
         search_cfg = options.optimize
@@ -516,7 +430,7 @@ def compile_network(
     prev = "input"
     with tracer.span(
         "compile_network", cat="compile",
-        layers=len(layers) + 1, precision=ecfg.precision,
+        layers=len(layers) + 1, precision=options.precision,
         optimize=search_cfg is not None,
     ) as compile_span:
         for spec in layers:
@@ -529,7 +443,7 @@ def compile_network(
             if search_cfg is not None:
                 with tracer.span(f"search:{name}", cat="compile") as sp:
                     res = conv_mapping_search(
-                        w, pattern_bits.get(name), hw, ecfg, search_cfg,
+                        w, pattern_bits.get(name), hw, options, search_cfg,
                     )
                     mapping = res.chosen
                     sp.args.update(
@@ -546,7 +460,7 @@ def compile_network(
                     b,
                     pattern_bits.get(name),
                     out_hw=hw,
-                    ecfg=ecfg,
+                    options=options,
                     tracer=tracer,
                     mapping=mapping,
                     in_order=orders[prev if spec.src is None else spec.src],
@@ -561,24 +475,24 @@ def compile_network(
                 wfc = _pad_axis(
                     _pad_axis(
                         np.asarray(params["fc"]["w"], np.float32),
-                        0, ecfg.block,
+                        0, options.block,
                     ),
-                    1, ecfg.tile,
+                    1, options.tile,
                 )
                 fc_reorder, counts = choose_fc_reorder(
-                    nonzero_block_masks(wfc, ecfg.block),
-                    ecfg.tile, search_cfg.reorders,
+                    nonzero_block_masks(wfc, options.block),
+                    options.tile, search_cfg.reorders,
                 )
                 sp.args.update(chosen=fc_reorder, bricks=counts)
         with tracer.span("lower:fc", cat="compile"):
             # the global average pool keeps the last conv's order
-            fc = lower_fc(params["fc"]["w"], params["fc"]["b"], ecfg,
+            fc = lower_fc(params["fc"]["w"], params["fc"]["b"], options,
                           tracer=tracer, reorder=fc_reorder,
                           in_order=orders[prev])
         program = CompiledNetwork(
-            config=cfg, convs=convs, fc=fc, block=ecfg.block,
-            tile=ecfg.tile, precision=ecfg.precision,
-            cell_bits=ecfg.cell_bits,
+            config=cfg, convs=convs, fc=fc, block=options.block,
+            tile=options.tile, precision=options.precision,
+            cell_bits=options.cell_bits,
         )
         # the channel gathers the forward will trace
         compile_span.args.update(gathers=program.gathers())
@@ -591,7 +505,7 @@ def compile_network(
         # the range certification pass only runs over structurally sound
         # programs (its interval math assumes the verifier's contracts);
         # V5xx diagnostics land in the same report, the certificate rides
-        # on the program (priced by hardware_report, saved in manifest v4)
+        # on the program (priced by hardware_report, saved in the manifest)
         if report.ok:
             with tracer.span("ranges", cat="compile") as sp:
                 report, cert = analyze_network(program, report=report)

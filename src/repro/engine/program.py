@@ -24,9 +24,8 @@ builds each consumer's weight rows in the order of the tensor it reads
 (``CompiledConv.in_order``, ``CompiledFC.in_order``), so nothing has to be
 put back into channel order between layers.  :meth:`CompiledNetwork.layout`
 derives, from those orders alone, where the forward still gathers: where
-a tensor's order differs from the one its consumer's rows assume (a
-program loaded from a manifest before format 6, whose rows are in channel
-order), where two addends of a residual add differ, where a layer's real
+a tensor's order differs from the one its consumer's rows assume (rows
+lowered in channel order, ``in_order`` None), where two addends of a residual add differ, where a layer's real
 outputs are not its leading stored columns, and at the logits, which are
 in class order.
 """
@@ -55,8 +54,8 @@ __all__ = ["CompiledConv", "CompiledFC", "CompiledNetwork", "OpLayout",
 def leading_order(bp: BlockPatternWeight, n: int) -> np.ndarray | None:
     """The channels held by the first ``n`` stored columns of ``bp``, or
     None where those are not exactly its ``n`` real outputs (a lowering
-    that sorted padding columns first, as manifests before format 6 hold:
-    the forward then gathers the output back into channel order)."""
+    that sorted padding columns first: the forward then gathers the
+    output back into channel order)."""
     head = np.asarray(bp.new_order)[:n]
     if np.array_equal(np.sort(head), np.arange(n)):
         return head
@@ -91,19 +90,17 @@ class CompiledConv:
     ``out_hw`` is the conv's output side, before its pool.
 
     ``mapping`` (optional) is the searched per-layer crossbar mapping
-    (``compile_network(optimize=...)``, ``core/mapsearch.py``):
+    (``CompileOptions(optimize=...)``, ``core/mapsearch.py``):
     ``hardware_report`` prices the layer at that candidate's geometry and
-    packing order instead of the report-wide defaults.  ``None`` (the
-    fixed scheme, and every v1/v2-loaded program) keeps the historical
-    pricing.
+    packing order instead of the report-wide defaults.  ``None`` is the
+    fixed scheme, priced at those defaults.
 
     ``in_order`` is the channel order the weight rows assume for the
     input tensor: row ``tap * c_in + i`` (channel-major: ``i * kernel**2
     + tap``) holds input channel ``in_order[i]``.  The lowering records
     the order its ``src`` tensor carries, so the forward reads that tensor
-    as it is; ``None`` (every program loaded from a manifest before format
-    6) means channel order.  ``bias`` and ``pattern_bits`` stay in channel
-    order either way.
+    as it is; ``None`` means channel order.  ``bias`` and
+    ``pattern_bits`` stay in channel order either way.
     """
 
     name: str
@@ -221,11 +218,10 @@ class CompiledNetwork:
 
     ``certificate`` (optional) is the
     :class:`~repro.analysis.ranges.RangeCertificate` the certification
-    pass attaches (``compile_network(verify=...)``): certified activation
+    pass attaches (``CompileOptions(verify=...)``): certified activation
     bounds, accumulator extrema, and the per-OU-row-group minimum
     cells-per-weight table.  ``hardware_report`` prices it as the
-    ``certified_potential`` section; ``serialize.py`` persists it
-    (manifest v4).
+    ``certified_potential`` section; ``serialize.py`` persists it.
     """
 
     config: CNNConfig | ResNetConfig
@@ -549,7 +545,7 @@ class CompiledNetwork:
         ``n_chips=None`` the view is derived from ``self.partition`` when
         the program carries one (model shards x data replicas).
 
-        Mapping: a searched program (``compile_network(optimize=...)``)
+        Mapping: a searched program (``CompileOptions(optimize=...)``)
         carries a per-layer :class:`~repro.core.mapping.MappingCandidate`
         — those layers are priced at their candidate's crossbar geometry
         and packing order (exactly the ``core/simulator.mapping_cost``

@@ -6,28 +6,23 @@ a fsynced ``program.json`` manifest, written into a ``.tmp`` directory and
 half-written program that a loader would pick up.  The round trip is
 bit-exact: every array is stored verbatim (float payloads as fp32,
 quantized payloads as int8 with their fp32 row-group scales, index
-streams as int32/int64).  A ``CompiledNetwork.partition``
-(``engine/partition.py``) rides along in the manifest, so a program
-partitioned for an N-chip mesh reloads ready to serve from one; the
-stored ``precision`` / ``cell_bits`` reload the same way (format v2 —
-v1 programs load as fp32).  Format v3 adds the searched mapping
-metadata: an optional per-conv ``mapping``
-(:meth:`~repro.core.mapping.MappingCandidate.to_manifest`) and the FC
-``reorder`` tag — v1/v2 programs load with no mapping and the
-'pattern' reorder (the fixed scheme), so old artifacts keep their
-historical pricing.  Format v4 adds the optional range
-``certificate`` (:class:`~repro.analysis.ranges.RangeCertificate`):
-v1-v3 programs load with ``certificate=None``, and only its structure
-is checked here (M003) — whether the certificate still matches the
-payloads is the certification pass's job (V506).  Format v5 adds each
-conv's ``patch_order`` (``lowering.patch_order``), the row order its
-weights were lowered in and its patches must be built in: v1-v4
-programs were lowered channel-major and always load as ``'channel'``.
-Format v6 adds each conv's and the FC's ``in_order`` (an int32 payload):
-the channel order of the tensor its weight rows read, which the lowering
-takes from the producing layer's stored column order.  v1-v5 programs
-were lowered with rows in channel order and load with ``in_order=None``,
-so their forward gathers as it always did.
+streams as int32/int64).
+
+The manifest (format 6) holds the lowering geometry (``block``,
+``tile``, ``precision``, ``cell_bits``), the chain config, and per conv
+its payload files, its searched ``mapping``
+(:meth:`~repro.core.mapping.MappingCandidate.to_manifest`, null for the
+fixed scheme), its ``patch_order`` (``lowering.patch_order``, the row
+order its weights were lowered in and its patches must be built in) and
+its ``in_order`` payload (the channel order of the tensor its weight
+rows read); the FC carries its ``reorder`` tag and ``in_order`` too.  A
+``CompiledNetwork.partition`` (``engine/partition.py``) rides along when
+set, so a program partitioned for an N-chip mesh reloads ready to serve
+from one, and so does the range ``certificate``
+(:class:`~repro.analysis.ranges.RangeCertificate`), of which only the
+structure is checked here (M003): whether it still matches the payloads
+is the certification pass's job (V506).  Any other ``format_version`` is
+refused (M002): recompile such a program and save it again.
 
 Only chain programs (``CNNConfig``: stride-1 convs with ``channel_norm``,
 ReLU and optional 2x2 max pools) have a manifest; saving a graph program
@@ -60,15 +55,8 @@ __all__ = [
 ]
 
 _MANIFEST = "program.json"
-# v2 adds precision/cell_bits + per-bp w_scales; v3 adds per-conv
-# mapping candidates + the fc reorder tag; v4 adds the optional range
-# certificate; v5 adds the per-conv patch order; v6 the input orders
 _FORMAT_VERSION = 6
-_SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6)
-# the first version whose convs may be tap-major (and say so)
-_PATCH_ORDER_VERSION = 5
-# the first version whose weight rows may read a stored channel order
-_IN_ORDER_VERSION = 6
+_SUPPORTED_VERSIONS = (_FORMAT_VERSION,)
 
 
 def _save_array(directory: str, name: str, arr) -> str:
@@ -124,8 +112,8 @@ def _save_order(directory: str, name: str, order) -> str | None:
                        np.asarray(order, np.int32))
 
 
-def _load_order(entry: dict, directory: str, has_order: bool):
-    fname = entry.get("in_order") if has_order else None
+def _load_order(entry: dict, directory: str):
+    fname = entry["in_order"]
     if fname is None:
         return None
     return np.load(os.path.join(directory, fname))
@@ -253,8 +241,12 @@ _BP_ARRAY_FIELDS = ("w_comp", "block_ids", "nnz", "new_order", "inv_order",
                     "dict_masks")
 _CONFIG_KEYS = ("conv_channels", "pool_after", "num_classes", "input_hw",
                 "kernel")
+_ROOT_KEYS = ("block", "tile", "precision", "cell_bits", "config", "convs",
+              "fc")
 _CONV_KEYS = ("name", "c_in", "c_out", "kernel", "out_hw", "pool_after",
-              "bias", "pattern_bits", "bp")
+              "bias", "pattern_bits", "bp", "mapping", "patch_order",
+              "in_order")
+_FC_KEYS = ("d_in", "d_out", "bias", "bp", "reorder", "in_order")
 _MAPPING_KEYS = ("rows", "cols", "cells_per_weight", "ou_rows", "ou_cols",
                  "block_order", "reorder")
 _CERT_KEYS = ("input_lo", "input_hi", "precision", "cell_bits",
@@ -272,7 +264,7 @@ def _require(entry: dict, keys, where: str) -> None:
 
 
 def _check_mapping_entry(entry, where: str) -> None:
-    """Structural (M003) check of a v3 ``mapping`` entry.
+    """Structural (M003) check of a conv's ``mapping`` entry.
 
     Only types and keys are checked here — *validity* of the tags and
     dims against the packed operands is the static verifier's job
@@ -301,7 +293,7 @@ def _check_mapping_entry(entry, where: str) -> None:
 
 
 def _check_certificate_entry(entry, where: str) -> None:
-    """Structural (M003) check of a v4 range ``certificate`` entry.
+    """Structural (M003) check of the range ``certificate`` entry.
 
     Like :func:`_check_mapping_entry`, only keys and types are enforced
     here — whether the certified bounds and cell table still match the
@@ -356,9 +348,8 @@ def _check_certificate_entry(entry, where: str) -> None:
 
 
 def _check_order_entry(entry: dict, directory: str, where: str) -> None:
-    """A v6 ``in_order``: null (channel order) or an existing payload;
+    """An ``in_order``: null (channel order) or an existing payload;
     whether it is a permutation is the verifier's V208."""
-    _require(entry, ("in_order",), where)
     fname = entry["in_order"]
     if fname is None:
         return
@@ -402,9 +393,10 @@ def validate_manifest(manifest: dict, directory: str) -> None:
     if version not in _SUPPORTED_VERSIONS:
         raise ProgramFormatError(
             f"unsupported program format version {version!r} "
-            f"(supported: {_SUPPORTED_VERSIONS})", rule="M002"
+            f"(supported: {_SUPPORTED_VERSIONS}); recompile the program "
+            "with compile_network and save it again", rule="M002"
         )
-    _require(manifest, ("block", "tile", "config", "convs", "fc"), "root")
+    _require(manifest, _ROOT_KEYS, "root")
     cfg = manifest["config"]
     if not isinstance(cfg, dict):
         raise ProgramFormatError(
@@ -416,9 +408,9 @@ def validate_manifest(manifest: dict, directory: str) -> None:
         raise ProgramFormatError(
             "program manifest convs must be a list", rule="M003"
         )
-    if manifest.get("precision", "fp32") not in ("fp32", "int8"):
+    if manifest["precision"] not in ("fp32", "int8"):
         raise ProgramFormatError(
-            f"unknown precision {manifest.get('precision')!r}", rule="M003"
+            f"unknown precision {manifest['precision']!r}", rule="M003"
         )
     for i, e in enumerate(convs):
         where = f"convs[{i}]"
@@ -437,24 +429,21 @@ def validate_manifest(manifest: dict, directory: str) -> None:
                     f"{fname!r}", rule="M004"
                 )
         _check_bp_entry(e["bp"], directory, f"{where}.bp")
-        _check_mapping_entry(e.get("mapping"), f"{where}.mapping")
-        if version >= _PATCH_ORDER_VERSION:
-            # which orders are valid, and where, is the verifier's V207
-            _require(e, ("patch_order",), where)
-            if not isinstance(e["patch_order"], str):
-                raise ProgramFormatError(
-                    f"program manifest {where}.patch_order must be a "
-                    "string", rule="M003",
-                )
-        if version >= _IN_ORDER_VERSION:
-            _check_order_entry(e, directory, where)
+        _check_mapping_entry(e["mapping"], f"{where}.mapping")
+        # which orders are valid, and where, is the verifier's V207
+        if not isinstance(e["patch_order"], str):
+            raise ProgramFormatError(
+                f"program manifest {where}.patch_order must be a string",
+                rule="M003",
+            )
+        _check_order_entry(e, directory, where)
     fce = manifest["fc"]
     if not isinstance(fce, dict):
         raise ProgramFormatError(
             "program manifest fc must be an object", rule="M003"
         )
-    _require(fce, ("d_in", "d_out", "bias", "bp"), "fc")
-    if not isinstance(fce.get("reorder", "pattern"), str):
+    _require(fce, _FC_KEYS, "fc")
+    if not isinstance(fce["reorder"], str):
         raise ProgramFormatError(
             "program manifest fc.reorder must be a string", rule="M003"
         )
@@ -466,8 +455,7 @@ def validate_manifest(manifest: dict, directory: str) -> None:
             f"payload file for fc.bias missing: {fname!r}", rule="M004"
         )
     _check_bp_entry(fce["bp"], directory, "fc.bp")
-    if version >= _IN_ORDER_VERSION:
-        _check_order_entry(fce, directory, "fc")
+    _check_order_entry(fce, directory, "fc")
     part = manifest.get("partition")
     if part is not None:
         _require(part, ("data", "model", "data_axis", "model_axis"),
@@ -491,10 +479,6 @@ def load_program(directory: str, verify: bool = True) -> CompiledNetwork:
     directory = _resolve_directory(directory)
     manifest = read_manifest(directory)
     validate_manifest(manifest, directory)
-    # before v5 every conv was lowered channel-major, whatever it says;
-    # before v6 every weight row read its input in channel order
-    has_order = manifest["format_version"] >= _PATCH_ORDER_VERSION
-    has_in_order = manifest["format_version"] >= _IN_ORDER_VERSION
     c = manifest["config"]
     cfg = CNNConfig(
         conv_channels=tuple(tuple(x) for x in c["conv_channels"]),
@@ -519,11 +503,11 @@ def load_program(directory: str, verify: bool = True) -> CompiledNetwork:
                 ),
                 mapping=(
                     MappingCandidate.from_manifest(e["mapping"])
-                    if e.get("mapping") is not None
+                    if e["mapping"] is not None
                     else None
                 ),
-                patch_order=e["patch_order"] if has_order else "channel",
-                in_order=_load_order(e, directory, has_in_order),
+                patch_order=e["patch_order"],
+                in_order=_load_order(e, directory),
             )
             for e in manifest["convs"]
         ]
@@ -533,8 +517,8 @@ def load_program(directory: str, verify: bool = True) -> CompiledNetwork:
             d_out=fce["d_out"],
             bp=_load_bp(fce["bp"], directory),
             bias=np.load(os.path.join(directory, fce["bias"])),
-            reorder=str(fce.get("reorder", "pattern")),
-            in_order=_load_order(fce, directory, has_in_order),
+            reorder=fce["reorder"],
+            in_order=_load_order(fce, directory),
         )
     except (OSError, ValueError) as e:
         raise ProgramFormatError(
@@ -563,8 +547,8 @@ def load_program(directory: str, verify: bool = True) -> CompiledNetwork:
         block=manifest["block"],
         tile=manifest["tile"],
         partition=NetworkPartition.from_manifest(part) if part else None,
-        precision=manifest.get("precision", "fp32"),
-        cell_bits=int(manifest.get("cell_bits", 4)),
+        precision=manifest["precision"],
+        cell_bits=int(manifest["cell_bits"]),
         certificate=certificate,
     )
     if verify:

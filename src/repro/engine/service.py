@@ -25,7 +25,6 @@ served* rather than an assumption.
 from __future__ import annotations
 
 import time
-import warnings
 from typing import Callable
 
 import jax
@@ -37,21 +36,9 @@ from repro.engine.program import CompiledNetwork
 from repro.engine.scheduler import SlotScheduler
 from repro.engine.stats import ActivationStats
 from repro.obs.trace import NULL_TRACER, Tracer
-from repro.serve.api import Request as ServeRequest
+from repro.serve.api import Request
 
-__all__ = ["ClassifyRequest", "InferenceService"]
-
-
-class ClassifyRequest(ServeRequest):
-    """Deprecated: use :class:`repro.serve.Request` (``image=`` form)."""
-
-    def __init__(self, image, logits=None, label=None, done: bool = False):
-        warnings.warn(
-            "repro.engine.service.ClassifyRequest is deprecated; use "
-            "repro.serve.Request(image=...)",
-            DeprecationWarning, stacklevel=2,
-        )
-        super().__init__(image=image, logits=logits, label=label, done=done)
+__all__ = ["InferenceService"]
 
 
 class InferenceService:
@@ -157,14 +144,14 @@ class InferenceService:
             raise ValueError(f"request image {img.shape} != expected {shape}")
         return img
 
-    def submit(self, request: ServeRequest) -> ServeRequest:
+    def submit(self, request: Request) -> Request:
         """Validate and enqueue one request (raises ``SchedulerFull`` when
         the bounded queue is full, ``ValueError`` on a bad image shape)."""
         request.image = self._validate(request.image)
         self.scheduler.submit(request)
         return request
 
-    def try_submit(self, request: ServeRequest) -> bool:
+    def try_submit(self, request: Request) -> bool:
         """Validate and enqueue; ``False`` when the bounded queue is full
         (the shed path the ``repro.serve`` session turns into
         ``Overloaded`` — ``SchedulerFull`` never escapes that route)."""
@@ -174,7 +161,7 @@ class InferenceService:
     def has_work(self) -> bool:
         return self.scheduler.has_work()
 
-    def step(self) -> list[ServeRequest]:
+    def step(self) -> list[Request]:
         """Refill free slots from the queue and run one fixed-shape batch.
 
         Returns the requests completed by this batch (empty when there
@@ -222,14 +209,14 @@ class InferenceService:
         sched.record_step_times((t1 - t0) + (t3 - t2), t2 - t1)
         return finished
 
-    def run(self) -> list[ServeRequest]:
+    def run(self) -> list[Request]:
         """Serve until the queue and every slot are drained."""
         finished = []
         while self.scheduler.has_work():
             finished.extend(self.step())
         return finished
 
-    def serve(self, requests: list[ServeRequest]) -> list[ServeRequest]:
+    def serve(self, requests: list[Request]) -> list[Request]:
         """Drain ``requests`` through the scheduler.
 
         All request shapes are validated *before* any batch runs, so a
@@ -252,7 +239,7 @@ class InferenceService:
 
     def classify(self, images: np.ndarray) -> np.ndarray:
         """Convenience: [N, C, H, W] -> labels [N]."""
-        reqs = [ServeRequest(image=img) for img in np.asarray(images)]
+        reqs = [Request(image=img) for img in np.asarray(images)]
         self.serve(reqs)
         return np.array([r.label for r in reqs], np.int64)
 

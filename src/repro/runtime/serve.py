@@ -28,14 +28,13 @@ crossing into the traced function:
     across batch rows.
 
 :class:`ServeLoop` keeps the old drain-a-list-of-requests API on top of
-it.  ``Request`` is a deprecated alias of :class:`repro.serve.Request`.
+it.  Requests are :class:`repro.serve.Request` (``prompt=`` form).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import time
-import warnings
 from typing import Callable
 
 import jax
@@ -45,7 +44,7 @@ import numpy as np
 from repro.engine.scheduler import SlotScheduler
 from repro.models.transformer import ModelConfig, apply_model, init_cache
 from repro.obs.trace import NULL_TRACER, Tracer
-from repro.serve.api import Request as ServeRequest
+from repro.serve.api import Request
 
 __all__ = [
     "ServeConfig",
@@ -54,7 +53,6 @@ __all__ = [
     "make_slot_prefill",
     "DecodeService",
     "ServeLoop",
-    "Request",
 ]
 
 
@@ -259,7 +257,7 @@ class DecodeService:
     def reset_metrics(self) -> None:
         self.scheduler.reset_metrics()
 
-    def _validate(self, request: ServeRequest) -> ServeRequest:
+    def _validate(self, request: Request) -> Request:
         if request.prompt is None:
             raise ValueError("generation request needs a prompt")
         prompt = np.asarray(request.prompt, np.int32).reshape(-1)
@@ -271,13 +269,13 @@ class DecodeService:
         request.prompt = prompt
         return request
 
-    def submit(self, request: ServeRequest) -> ServeRequest:
+    def submit(self, request: Request) -> Request:
         """Validate + enqueue (raises ``SchedulerFull`` when bounded
         queue is full — front ends should use ``try_submit``)."""
         self.scheduler.submit(self._validate(request))
         return request
 
-    def try_submit(self, request: ServeRequest) -> bool:
+    def try_submit(self, request: Request) -> bool:
         return self.scheduler.try_submit(self._validate(request))
 
     def has_work(self) -> bool:
@@ -285,20 +283,20 @@ class DecodeService:
 
     # ------------------------------------------------------------- stepping
 
-    def _finish(self, slot: int, req: ServeRequest, finished: list) -> None:
+    def _finish(self, slot: int, req: Request, finished: list) -> None:
         req.done = True
         self.scheduler.complete(slot)
         self._tokens[slot] = 0
         self._pos[slot] = 0
         finished.append(req)
 
-    def step(self) -> list[ServeRequest]:
+    def step(self) -> list[Request]:
         """Admit queued prompts into free slots (prefill), then advance
         every live slot one decode step at its own position.  Returns the
         requests completed by this step."""
         sched = self.scheduler
         scfg = self.scfg
-        finished: list[ServeRequest] = []
+        finished: list[Request] = []
         was_decoding = bool(sched.live())
         for slot, req in sched.refill():
             prompt = np.asarray(req.prompt, np.int32)[None]
@@ -357,28 +355,12 @@ class DecodeService:
                 self._finish(slot, req, finished)
         return finished
 
-    def run(self) -> list[ServeRequest]:
+    def run(self) -> list[Request]:
         """Serve until the queue and every slot are drained."""
-        finished: list[ServeRequest] = []
+        finished: list[Request] = []
         while self.has_work():
             finished.extend(self.step())
         return finished
-
-
-class Request(ServeRequest):
-    """Deprecated: use :class:`repro.serve.Request` (``prompt=`` form)."""
-
-    def __init__(self, prompt, max_new_tokens: int = 32, output=None,
-                 done: bool = False):
-        warnings.warn(
-            "repro.runtime.serve.Request is deprecated; use "
-            "repro.serve.Request(prompt=...)",
-            DeprecationWarning, stacklevel=2,
-        )
-        super().__init__(
-            prompt=np.asarray(prompt), max_new_tokens=max_new_tokens,
-            output=list(output) if output else [], done=done,
-        )
 
 
 class ServeLoop:
@@ -401,7 +383,7 @@ class ServeLoop:
         )
         self.metrics: dict | None = None
 
-    def generate(self, requests: list[ServeRequest]) -> list[ServeRequest]:
+    def generate(self, requests: list[Request]) -> list[Request]:
         for r in requests:
             self.service.submit(r)
         self.service.run()

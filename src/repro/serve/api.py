@@ -1,12 +1,9 @@
 """Unified serving contract: one ``Request``/``Response`` pair for every
 front end.
 
-Historically the repo grew two request models — classification's
-``engine/service.ClassifyRequest`` (image in, logits/label out) and
-generation's ``runtime/serve.Request`` (prompt in, tokens out).  Both are
-now thin deprecation shims over the single :class:`Request` here, and the
-HTTP server, the :class:`~repro.serve.session.ServeSession` facade, and
-both backends speak only this contract.
+The HTTP server, the :class:`~repro.serve.session.ServeSession` facade
+and both backends (classification: image in, logits/label out;
+generation: prompt in, tokens out) speak only this :class:`Request`.
 
 This module is deliberately leaf-level: stdlib + numpy only, no imports
 from anywhere else in ``repro``, so the engine and runtime packages can

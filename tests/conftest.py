@@ -48,13 +48,13 @@ def run_virtual_devices(n_devices: int, body: str) -> dict:
     return json.loads(line)
 
 
-def unfolded(program, channel_major: bool = False):
+def unfolded(program):
     """``program`` as compilers before format 6 lowered it: every weight
     row (the FC's too) reads its input in channel order (``in_order``
     None), re-lowered from the stored weights with the layer's own reorder
-    strategy; with ``channel_major`` every conv's rows also channel-major,
-    as format v1-v4 compilers lowered them.  The result carries no range
-    certificate (the one ``program`` had describes other bricks)."""
+    strategy, so the forward gathers each layer's output back into
+    channel order.  The result carries no range certificate (the one
+    ``program`` had describes other bricks)."""
     import dataclasses
 
     from repro.engine.lowering import conv_matrix, lower_matrix
@@ -65,21 +65,19 @@ def unfolded(program, channel_major: bool = False):
 
     convs = []
     for c in program.convs:
-        order = "channel" if channel_major else c.patch_order
-        if c.in_order is not None or order != c.patch_order:
+        if c.in_order is not None:
             kk = c.kernel * c.kernel
             wm = np.asarray(c.bp.dense())[: c.k_unpadded, : c.c_out]
             if c.patch_order == "tap":
                 w = wm.reshape(kk, c.c_in, c.c_out).transpose(2, 1, 0)
             else:
                 w = wm.reshape(c.c_in, kk, c.c_out).transpose(2, 0, 1)
-            if c.in_order is not None:  # rows by position -> by channel
-                w = w[:, np.argsort(c.in_order)]
+            w = w[:, np.argsort(c.in_order)]  # rows by position -> by channel
             w = w.reshape(c.c_out, c.c_in, c.kernel, c.kernel)
             reorder = "pattern" if c.mapping is None else c.mapping.reorder
             c = dataclasses.replace(
-                c, bp=relower(conv_matrix(w, order), reorder),
-                patch_order=order, in_order=None,
+                c, bp=relower(conv_matrix(w, c.patch_order), reorder),
+                in_order=None,
             )
         convs.append(c)
     fc = program.fc
@@ -91,30 +89,6 @@ def unfolded(program, channel_major: bool = False):
         )
     return dataclasses.replace(program, convs=convs, fc=fc,
                                certificate=None)
-
-
-def channel_major(program):
-    """``program`` as format v1-v4 compilers lowered it (:func:`unfolded`
-    with every conv channel-major)."""
-    return unfolded(program, channel_major=True)
-
-
-def downgrade_manifest(directory: str, version: int) -> dict:
-    """Rewrite a saved program's manifest as format ``version`` (< 6): no
-    ``in_order`` (and, below 5, no per-conv ``patch_order``).  Returns the
-    rewritten manifest."""
-    path = os.path.join(directory, "program.json")
-    with open(path) as f:
-        manifest = json.load(f)
-    manifest["format_version"] = version
-    for e in [*manifest["convs"], manifest["fc"]]:
-        del e["in_order"]
-    if version < 5:
-        for e in manifest["convs"]:
-            del e["patch_order"]
-    with open(path, "w") as f:
-        json.dump(manifest, f)
-    return manifest
 
 
 @contextlib.contextmanager

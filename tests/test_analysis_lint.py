@@ -5,8 +5,6 @@ real ``src/repro`` tree is clean (the CI contract)."""
 import os
 import textwrap
 
-import pytest
-
 from repro.analysis.lint import lint_paths
 
 REPO_SRC = os.path.join(os.path.dirname(__file__), "..", "src", "repro")
@@ -214,31 +212,6 @@ def test_l004_jax_work_outside_timed_region_passes(tmp_path):
             t0 = time.time()
             host_work(key)
             return time.time() - t0
-    """)
-    assert report.clean, report.format()
-
-
-def test_l005_deprecated_serving_imports(tmp_path):
-    report = _lint(tmp_path, """
-        from repro.engine.service import ClassifyRequest
-        from repro.runtime.serve import Request
-    """)
-    assert report.rules() == {"L005"}
-    assert len(report.diagnostics) == 2
-
-
-def test_l005_unified_and_unrelated_imports_are_fine(tmp_path):
-    report = _lint(tmp_path, """
-        from repro.serve import Request
-        from repro.engine.service import InferenceService
-        from repro.runtime.serve import ServeConfig, ServeLoop
-    """)
-    assert report.clean, report.format()
-
-
-def test_l005_allow_comment_for_backcompat_reexport(tmp_path):
-    report = _lint(tmp_path, """
-        from repro.engine.service import ClassifyRequest  # lint: allow(L005)
     """)
     assert report.clean, report.format()
 

@@ -348,7 +348,7 @@ def test_certified_potential_unavailable_on_fp32(prog_fp32):
     assert "fp32" in cp["reason"]
 
 
-# ------------------------------------------------- manifest v4 / compat
+# ------------------------------------------------- manifest
 
 
 def test_manifest_v4_carries_certificate(prog_int8, tmp_path):
@@ -360,13 +360,19 @@ def test_manifest_v4_carries_certificate(prog_int8, tmp_path):
     assert manifest["certificate"]["precision"] == "int8"
 
 
-def test_v3_manifest_loads_without_certificate(prog_int8, tmp_path):
-    from conftest import channel_major, downgrade_manifest
-
+def test_v3_manifest_loads_without_certificate(pruned, tmp_path):
+    """A program compiled without ``verify`` carries no certificate: its
+    save has none, loads with none, and still certifies clean."""
+    cfg, params, bits = pruned
+    prog = compile_network(
+        cfg, params, bits,
+        options=CompileOptions(block=16, tile=16, precision="int8"),
+    )
+    assert prog.certificate is None
     d = str(tmp_path / "prog")
-    # a v3 compiler lowered every conv channel-major and certified nothing
-    serialize.save_program(d, channel_major(prog_int8))
-    assert "certificate" not in downgrade_manifest(d, 3)
+    serialize.save_program(d, prog)
+    with open(os.path.join(d, "program.json")) as f:
+        assert "certificate" not in json.load(f)
     loaded = serialize.load_program(d)
     assert loaded.certificate is None
     # a certificate-less save still certifies — it just can't cross-check

@@ -30,8 +30,7 @@ from repro.core.pruning import (
     project_params,
 )
 from repro.core.patterns import ALL_ZERO, pattern_sizes
-from repro.engine import compile_network, partition_network
-from repro.engine.lowering import EngineConfig
+from repro.engine import CompileOptions, compile_network, partition_network
 from repro.engine.partition import NetworkPartition, pad_bp_tiles
 from repro.engine import serialize
 from repro.models.cnn import conv_weight_names, init_cnn, mini_cnn_config
@@ -52,15 +51,16 @@ def pruned():
 def prog_fp32(pruned):
     cfg, params, bits = pruned
     return compile_network(cfg, params, bits,
-                           ecfg=EngineConfig(block=16, tile=16))
+                           options=CompileOptions(block=16, tile=16))
 
 
 @pytest.fixture(scope="module")
 def prog_int8(pruned):
     cfg, params, bits = pruned
-    return compile_network(cfg, params, bits,
-                           ecfg=EngineConfig(block=16, tile=16),
-                           precision="int8")
+    return compile_network(
+        cfg, params, bits,
+        options=CompileOptions(block=16, tile=16, precision="int8"),
+    )
 
 
 def _with_bp(prog, bp):
@@ -368,12 +368,14 @@ def test_partition_valid_passes(prog_fp32):
 
 def test_compile_network_verify_modes(pruned):
     cfg, params, bits = pruned
-    ecfg = EngineConfig(block=16, tile=16)
-    prog = compile_network(cfg, params, bits, ecfg=ecfg, verify="strict")
+    def opts(verify):
+        return CompileOptions(block=16, tile=16, verify=verify)
+
+    prog = compile_network(cfg, params, bits, options=opts("strict"))
     assert verify_network(prog).ok
-    compile_network(cfg, params, bits, ecfg=ecfg, verify="warn")
+    compile_network(cfg, params, bits, options=opts("warn"))
     with pytest.raises(ValueError, match="verify must be"):
-        compile_network(cfg, params, bits, ecfg=ecfg, verify="bogus")
+        compile_network(cfg, params, bits, options=opts("bogus"))
 
 
 # ---------------------------------------------------------------------------
@@ -384,9 +386,10 @@ def test_compile_network_verify_modes(pruned):
 @pytest.fixture(scope="module")
 def prog_auto(pruned):
     cfg, params, bits = pruned
-    return compile_network(cfg, params, bits,
-                           ecfg=EngineConfig(block=16, tile=16),
-                           optimize="auto")
+    return compile_network(
+        cfg, params, bits,
+        options=CompileOptions(block=16, tile=16, optimize="auto"),
+    )
 
 
 def _with_mapping(prog, **kw):
@@ -440,9 +443,11 @@ def test_mapping_ou_cannot_hold_tallest_pattern(prog_auto):
 
 def test_mapping_int8_cell_slice_mismatch(pruned):
     cfg, params, bits = pruned
-    prog = compile_network(cfg, params, bits,
-                           ecfg=EngineConfig(block=16, tile=16),
-                           precision="int8", optimize="auto")
+    prog = compile_network(
+        cfg, params, bits,
+        options=CompileOptions(block=16, tile=16, precision="int8",
+                               optimize="auto"),
+    )
     assert verify_network(prog).ok
     bad = _with_mapping(prog, cells_per_weight=1)
     report = verify_network(bad)
@@ -518,9 +523,16 @@ def test_saved_pristine_roundtrip(saved):
             {k: v for k, v in e.items() if k != "patch_order"}
             for e in _manifest(p)["convs"]
         ]}), "M003"),
+        (lambda p: _rewrite(p, {**_manifest(p), "fc": {
+            k: v for k, v in _manifest(p)["fc"].items() if k != "in_order"
+        }}), "M003"),
+        (lambda p: _rewrite(p, {
+            k: v for k, v in _manifest(p).items() if k != "cell_bits"
+        }), "M003"),
     ],
     ids=["bad-version", "missing-key", "missing-payload", "truncated-json",
-         "corrupt-payload", "missing-patch-order"],
+         "corrupt-payload", "missing-patch-order", "missing-fc-in-order",
+         "missing-cell-bits"],
 )
 def test_corrupt_saved_program(saved, corrupt, rule):
     corrupt(saved)
@@ -529,6 +541,19 @@ def test_corrupt_saved_program(saved, corrupt, rule):
     assert ei.value.rule == rule
     report = verify_saved(saved)
     assert report.rules("error") == {rule}, report.format()
+
+
+@pytest.mark.parametrize("version", [1, 2, 3, 4, 5])
+def test_old_manifest_versions_are_refused(saved, version):
+    """A manifest of an older format is refused by its version (M002)
+    before any payload is read: even a corrupt payload does not surface."""
+    _rewrite(saved, {**_manifest(saved), "format_version": version})
+    with open(os.path.join(saved, "fc.w_comp.npy"), "wb") as f:
+        f.write(b"not-an-npy")
+    with pytest.raises(ProgramFormatError, match="recompile") as ei:
+        serialize.load_program(saved)
+    assert ei.value.rule == "M002"
+    assert verify_saved(saved).rules("error") == {"M002"}
 
 
 def test_load_rejects_unknown_patch_order(saved):

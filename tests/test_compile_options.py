@@ -1,12 +1,11 @@
 """CompileOptions: the one-object compile surface.
 
-Pins the API-redesign contract: ``compile_network(options=...)`` is the
-preferred form, the historical loose kwargs keep working as deprecated
-aliases (warning, but compiling a *bit-identical* program), and the two
-forms cannot be mixed.
+``compile_network(cfg, params, bits, options=CompileOptions(...))`` is the
+only compile call: each option field is validated when the options are
+built and reaches the program it compiles.
 """
 
-import dataclasses
+import inspect
 import warnings
 
 import jax
@@ -18,7 +17,7 @@ from repro.core.pruning import (
     magnitude_prune,
     project_params,
 )
-from repro.engine import CompileOptions, EngineConfig, compile_network
+from repro.engine import CompileOptions, compile_network
 from repro.models.cnn import conv_weight_names, init_cnn, mini_cnn_config
 from repro.obs.trace import Tracer
 
@@ -34,117 +33,99 @@ def mini():
     return cfg, params, bits
 
 
-def _bp_arrays(bp):
-    arrs = [bp.w_comp, bp.block_ids, bp.nnz, bp.new_order, bp.inv_order]
-    if bp.w_scales is not None:
-        arrs.append(bp.w_scales)
-    return [np.asarray(a) for a in arrs]
-
-
-def assert_programs_identical(a, b):
-    """Every stored operand of two compiled programs is bit-equal."""
-    assert (a.block, a.tile, a.precision, a.cell_bits) == (
-        b.block, b.tile, b.precision, b.cell_bits
-    )
-    assert len(a.convs) == len(b.convs)
-    for ca, cb in zip(a.convs, b.convs):
-        assert ca.name == cb.name
-        np.testing.assert_array_equal(ca.bias, cb.bias)
-        np.testing.assert_array_equal(ca.pattern_bits, cb.pattern_bits)
-        for xa, xb in zip(_bp_arrays(ca.bp), _bp_arrays(cb.bp)):
-            np.testing.assert_array_equal(xa, xb)
-    np.testing.assert_array_equal(a.fc.bias, b.fc.bias)
-    for xa, xb in zip(_bp_arrays(a.fc.bp), _bp_arrays(b.fc.bp)):
-        np.testing.assert_array_equal(xa, xb)
-
-
 def test_options_form_does_not_warn(mini):
     cfg, params, bits = mini
     with warnings.catch_warnings():
-        warnings.simplefilter("error", DeprecationWarning)
+        warnings.simplefilter("error")
         compile_network(cfg, params, bits, options=CompileOptions())
-        compile_network(cfg, params, bits)  # bare call is not deprecated
+        compile_network(cfg, params, bits)  # None: the default options
+
+
+def test_compile_network_signature():
+    """Options go by keyword only; there is no other compile surface."""
+    params = inspect.signature(compile_network).parameters
+    assert list(params) == ["cfg", "params", "pattern_bits", "options"]
+    assert params["pattern_bits"].default is None
+    assert params["options"].kind is inspect.Parameter.KEYWORD_ONLY
+    assert params["options"].default is None
+    defaults = {f: getattr(CompileOptions(), f)
+                for f in CompileOptions.__dataclass_fields__}
+    assert defaults == {"block": 128, "tile": 128, "precision": "fp32",
+                        "cell_bits": 4, "verify": None, "optimize": None,
+                        "tracer": None}
 
 
 @pytest.mark.parametrize(
-    "kwargs, options",
-    [
-        (
-            dict(ecfg=EngineConfig(block=16, tile=16)),
-            CompileOptions(block=16, tile=16),
-        ),
-        (
-            dict(precision="int8"),
-            CompileOptions(precision="int8"),
-        ),
-        (
-            dict(ecfg=EngineConfig(block=16, tile=16, cell_bits=2),
-                 precision="int8", verify="strict"),
-            CompileOptions(block=16, tile=16, cell_bits=2,
-                           precision="int8", verify="strict"),
-        ),
-    ],
+    "field, value",
+    [("precision", "fp16"), ("cell_bits", 0), ("verify", "bogus"),
+     ("optimize", 42)],
 )
-def test_kwargs_alias_round_trip_bit_identical(mini, kwargs, options):
-    """Deprecated kwargs warn but compile the same bits as options=."""
+def test_options_validation(field, value):
+    with pytest.raises(ValueError, match=field):
+        CompileOptions(**{field: value})
+
+
+def _check_geometry(prog, _):
+    assert (prog.block, prog.tile) == (16, 16)
+    for bp in [c.bp for c in prog.convs] + [prog.fc.bp]:
+        assert (bp.block, bp.tile) == (16, 16)
+
+
+def _check_precision(prog, base):
+    assert prog.precision == "int8"
+    for bp in [c.bp for c in prog.convs] + [prog.fc.bp]:
+        assert bp.w_scales is not None
+    assert all(c.bp.w_scales is None for c in base.convs)
+
+
+def _check_cell_bits(prog, _):
+    assert prog.cell_bits == 2
+    rep = prog.hardware_report()["precision"]
+    assert rep["cell_bits"] == 2
+    assert rep["cells_per_weight"] == 4  # ceil(8 / 2) slices per weight
+
+
+def _check_verify(prog, base):
+    assert base.certificate is None
+    assert prog.certificate is not None
+    assert prog.certificate.fp32_safe
+
+
+def _check_optimize(prog, base):
+    assert all(c.mapping is None for c in base.convs)
+    assert all(c.mapping is not None for c in prog.convs)
+
+
+def _check_tracer(_prog, _base, tracer):
+    names = {e["name"] for e in tracer.events()}
+    assert {"compile_network", "lower:conv1", "lower:fc"} <= names
+
+
+@pytest.mark.parametrize(
+    "field", ["block_tile", "precision", "cell_bits", "verify", "optimize",
+              "tracer"],
+)
+def test_each_option_reaches_the_program(mini, field):
+    """Each field of the options shows on the compiled program: against a
+    compile with the default options, it changes what it names."""
     cfg, params, bits = mini
-    with pytest.warns(DeprecationWarning, match="CompileOptions"):
-        legacy = compile_network(cfg, params, bits, **kwargs)
-    new = compile_network(cfg, params, bits, options=options)
-    assert_programs_identical(legacy, new)
-
-
-def test_positional_ecfg_slot_still_works(mini):
-    """CI's analysis job passes EngineConfig in the 4th positional slot;
-    that call shape must keep compiling (with a deprecation warning)."""
-    cfg, params, bits = mini
-    e = EngineConfig(block=16, tile=16)
-    with pytest.warns(DeprecationWarning):
-        prog = compile_network(cfg, params, bits, e, verify="strict")
-    assert_programs_identical(
-        prog,
-        compile_network(
-            cfg, params, bits,
-            options=CompileOptions.from_engine_config(e, verify="strict"),
-        ),
-    )
-
-
-def test_options_cannot_mix_with_legacy_kwargs(mini):
-    cfg, params, bits = mini
-    with pytest.raises(TypeError, match="deprecated kwarg"):
-        compile_network(
-            cfg, params, bits, precision="int8", options=CompileOptions()
-        )
-
-
-def test_options_validation():
-    with pytest.raises(ValueError, match="precision"):
-        CompileOptions(precision="fp16")
-    with pytest.raises(ValueError, match="cell_bits"):
-        CompileOptions(cell_bits=0)
-    with pytest.raises(ValueError, match="verify"):
-        CompileOptions(verify="bogus")
-    with pytest.raises(ValueError, match="optimize"):
-        CompileOptions(optimize=42)
-
-
-def test_engine_config_projection_round_trips():
-    e = EngineConfig(block=16, tile=32, precision="int8", cell_bits=2)
-    opts = CompileOptions.from_engine_config(e, verify="warn")
-    assert opts.engine_config() == e
-    assert opts.verify == "warn"
-    assert dataclasses.replace(opts, verify=None).engine_config() == e
-
-
-def test_options_carry_tracer(mini):
-    """The tracer rides inside options: compile spans land on it."""
-    cfg, params, bits = mini
-    tr = Tracer()
-    compile_network(
-        cfg, params, bits,
-        options=CompileOptions(block=16, tile=16, tracer=tr),
-    )
-    names = {e["name"] for e in tr.events()}
-    assert "compile_network" in names
-    assert "lower:fc" in names
+    tracer = Tracer()
+    options, check = {
+        "block_tile": (CompileOptions(block=16, tile=16), _check_geometry),
+        "precision": (CompileOptions(precision="int8"), _check_precision),
+        "cell_bits": (CompileOptions(precision="int8", cell_bits=2),
+                      _check_cell_bits),
+        "verify": (CompileOptions(verify="strict"), _check_verify),
+        "optimize": (CompileOptions(optimize="auto"), _check_optimize),
+        "tracer": (CompileOptions(tracer=tracer),
+                   lambda p, b: _check_tracer(p, b, tracer)),
+    }[field]
+    base = compile_network(cfg, params, bits)
+    prog = compile_network(cfg, params, bits, options=options)
+    check(prog, base)
+    # the compile-pass switches that only observe leave the bricks as they
+    # were
+    if field in ("verify", "tracer"):
+        for a, b in zip([*base.convs, base.fc], [*prog.convs, prog.fc]):
+            np.testing.assert_array_equal(np.asarray(a.bp.w_comp),
+                                          np.asarray(b.bp.w_comp))

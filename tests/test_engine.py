@@ -16,8 +16,7 @@ from repro.core.pruning import (
 )
 from repro.core.sparse import block_density
 from repro.engine import (
-    ClassifyRequest,
-    EngineConfig,
+    CompileOptions,
     InferenceService,
     compile_network,
     execute,
@@ -26,6 +25,7 @@ from repro.engine import (
     make_forward,
     save_program,
 )
+from repro.serve import Request
 from repro.models.cnn import (
     cnn_apply,
     conv_weight_names,
@@ -219,7 +219,7 @@ def test_engine_config_small_blocks(mini):
     """Non-default (block, tile) geometry stays exact."""
     cfg, params, bits, _ = mini
     prog = compile_network(cfg, params, bits,
-                           ecfg=EngineConfig(block=9, tile=8))
+                           options=CompileOptions(block=9, tile=8))
     x = jax.random.normal(jax.random.PRNGKey(5), (4, 1, 12, 12))
     ref = cnn_apply(cfg, params, x)
     out = make_forward(prog, backend="xla")(x)
@@ -270,41 +270,6 @@ def test_manifest_v5_carries_patch_order(mini, tmp_path):
         == [c.patch_order for c in prog.convs] == ["channel", "channel", "tap"]
 
 
-@pytest.mark.parametrize("claims_tap", [False, True])
-def test_v4_program_loads_channel_major(mini, tmp_path, claims_tap):
-    """A format-v4 program (every conv lowered channel-major) loads
-    channel-major and serves the logits it served before v5, even where
-    its manifest claims a tap-major conv."""
-    from conftest import channel_major, downgrade_manifest
-    from repro.engine.lowering import conv_matrix, lower_matrix
-
-    cfg, params, bits, prog = mini
-    old = channel_major(prog)
-    # the rebuilt operands are the ones the v4 compiler stored
-    w3 = np.asarray(params["conv3"]["w"])
-    np.testing.assert_array_equal(
-        np.asarray(old.convs[2].bp.w_comp),
-        np.asarray(lower_matrix(conv_matrix(w3), 128, 128).w_comp),
-    )
-    path = save_program(str(tmp_path / "v4"), old)
-    manifest = downgrade_manifest(path, 4)
-    if claims_tap:
-        manifest["convs"][2]["patch_order"] = "tap"
-        with open(os.path.join(path, "program.json"), "w") as f:
-            json.dump(manifest, f)
-    loaded = load_program(path)
-    assert [c.patch_order for c in loaded.convs] == ["channel"] * 3
-    x = jax.random.normal(jax.random.PRNGKey(9), (3, 1, 12, 12))
-    served = np.asarray(make_forward(loaded, backend="xla")(x))
-    np.testing.assert_array_equal(
-        served, np.asarray(make_forward(old, backend="xla")(x))
-    )
-    np.testing.assert_allclose(
-        served, np.asarray(make_forward(prog, backend="xla")(x)),
-        rtol=1e-5, atol=1e-5,
-    )
-
-
 def test_serialize_roundtrip_partition_metadata(mini, tmp_path):
     """A partitioned program reloads with its partition intact and still
     produces the identical forward output (golden, bit-exact)."""
@@ -339,7 +304,9 @@ def test_serialize_roundtrip_quantized(mini, tmp_path):
 
     cfg, params, bits, _ = mini
     progq = partition_network(
-        compile_network(cfg, params, bits, precision="int8"), data=2, model=2
+        compile_network(cfg, params, bits,
+                        options=CompileOptions(precision="int8")),
+        data=2, model=2,
     )
     path = save_program(str(tmp_path / "progq"), progq)
     prog2 = load_program(path)
@@ -399,7 +366,7 @@ def test_service_matches_forward(mini):
     assert svc.batches_run == 1
 
     # two generations: 16 requests through 8 slots
-    reqs = [ClassifyRequest(image=img) for img in np.concatenate([x, x])]
+    reqs = [Request(image=img) for img in np.concatenate([x, x])]
     svc.serve(reqs)
     assert all(r.done and r.logits is not None for r in reqs)
     np.testing.assert_array_equal(
@@ -419,7 +386,7 @@ def test_service_partial_batch_padded_with_dead_slots(mini):
         np.float32,
     )
     svc = InferenceService(prog, batch_slots=8, backend="xla")
-    reqs = [ClassifyRequest(image=img) for img in x]
+    reqs = [Request(image=img) for img in x]
     svc.serve(reqs)
     assert svc.batches_run == 1 and svc.trace_count() == 1
     got = np.stack([r.logits for r in reqs])

@@ -28,7 +28,7 @@ from repro.core.pruning import (
     project_params,
 )
 from repro.engine import (
-    EngineConfig,
+    CompileOptions,
     NetworkPartition,
     compile_network,
     make_forward,
@@ -43,17 +43,17 @@ from repro.models.cnn import conv_weight_names, init_cnn, mini_cnn_config
 # widths (8, 16, 24) with tile=8 give per-layer spmm tile counts (1, 2, 3)
 # — deliberately not divisible by 2/4/8-way meshes, so every sharded run
 # exercises the zero-padded grey-area tiles.
-UNEVEN_ECFG = EngineConfig(block=9, tile=8)
+UNEVEN_OPTIONS = CompileOptions(block=9, tile=8)
 
 
-def _pruned_program(ecfg=UNEVEN_ECFG, widths=(8, 16, 24), num_classes=5):
+def _pruned_program(options=UNEVEN_OPTIONS, widths=(8, 16, 24), num_classes=5):
     cfg = mini_cnn_config(num_classes=num_classes, input_hw=12, widths=widths)
     params = init_cnn(cfg, jax.random.PRNGKey(0))
     names = conv_weight_names(cfg)
     params = magnitude_prune(params, names, 0.7)
     dicts = build_dictionaries(params, names, 4)
     params, bits = project_params(params, dicts)
-    return cfg, compile_network(cfg, params, bits, ecfg=ecfg)
+    return cfg, compile_network(cfg, params, bits, options=options)
 
 
 @pytest.fixture(scope="module")
@@ -146,8 +146,8 @@ def _pruned_program_quantized():
     params = magnitude_prune(params, names, 0.7)
     dicts = build_dictionaries(params, names, 4)
     params, bits = project_params(params, dicts)
-    ecfg = EngineConfig(block=9, tile=8, precision="int8")
-    return cfg, compile_network(cfg, params, bits, ecfg=ecfg)
+    options = CompileOptions(block=9, tile=8, precision="int8")
+    return cfg, compile_network(cfg, params, bits, options=options)
 
 
 # ---------------------------------------------------------------- subprocess
@@ -162,7 +162,7 @@ def test_sharded_matrix_subprocess():
     res = _run_sub(8, """
     from repro.core.pruning import (build_dictionaries, magnitude_prune,
                                     project_params)
-    from repro.engine import (EngineConfig, InferenceService,
+    from repro.engine import (CompileOptions, InferenceService,
                               compile_network, make_forward)
     from repro.launch.mesh import make_mesh
     from repro.models.cnn import (conv_weight_names, init_cnn,
@@ -177,9 +177,9 @@ def test_sharded_matrix_subprocess():
     x = jax.random.normal(jax.random.PRNGKey(5), (8, 1, 12, 12))
 
     out = {"diffs": {}, "n_tiles": {}, "folds": {}}
-    for gname, ecfg in [("mxu", EngineConfig()),
-                        ("fine", EngineConfig(block=9, tile=8))]:
-        prog = compile_network(cfg, params, bits, ecfg=ecfg)
+    for gname, options in [("mxu", CompileOptions()),
+                           ("fine", CompileOptions(block=9, tile=8))]:
+        prog = compile_network(cfg, params, bits, options=options)
         out["n_tiles"][gname] = [c.bp.n_tiles for c in prog.convs]
         # some layer's rows read a stored order that is not channel order
         out["folds"][gname] = any(
@@ -207,7 +207,7 @@ def test_sharded_matrix_subprocess():
 
     # sharded service: 10 requests through 8 slots (partial generation)
     prog = compile_network(cfg, params, bits,
-                           ecfg=EngineConfig(block=9, tile=8))
+                           options=CompileOptions(block=9, tile=8))
     imgs = np.asarray(
         jax.random.normal(jax.random.PRNGKey(1), (10, 1, 12, 12)),
         np.float32)

@@ -31,7 +31,7 @@ import unittest.mock
 import jax
 import numpy as np
 import pytest
-from conftest import downgrade_manifest, pre_v6_columns, unfolded
+from conftest import pre_v6_columns, unfolded
 
 from repro.analysis.diagnostics import VerificationError
 from repro.analysis.verify import verify_network
@@ -266,23 +266,6 @@ def test_compile_span_counts_the_gathers():
 
 
 # ------------------------------------------------------- the saved program
-
-
-def test_v5_manifest_loads_and_gathers(tmp_path):
-    """A format-5 save (rows in channel order, padding first) loads with
-    no input orders, gathers, and serves the logits it served before."""
-    prog, x, _ = _compile("vgg")
-    old = _gathering(prog)
-    path = save_program(str(tmp_path / "v5"), old)
-    manifest = downgrade_manifest(path, 5)
-    assert "in_order" not in manifest["fc"]
-    loaded = load_program(path)
-    assert all(c.in_order is None for c in loaded.convs)
-    assert loaded.fc.in_order is None
-    assert loaded.gathers() == old.gathers() > prog.gathers()
-    np.testing.assert_array_equal(_logits(loaded, x), _logits(old, x))
-    np.testing.assert_allclose(_logits(loaded, x), _logits(prog, x),
-                               rtol=1e-5, atol=1e-5)
 
 
 def test_v6_roundtrips_input_orders(tmp_path):

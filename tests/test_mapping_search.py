@@ -8,7 +8,7 @@ gates on the bench side:
     area/energy/cycles equal the ``hardware_report`` numbers *exactly*
     (``==`` on floats, no tolerance) for every layer of an optimized
     program, fp32 and int8;
-  * **semantics preserved** — ``compile_network(optimize='auto')`` only
+  * **semantics preserved** — ``CompileOptions(optimize='auto')`` only
     changes layout, never math: fp32 logits are bit-identical to the
     fixed scheme on XLA (any forced reorder strategy included), Pallas
     agrees to fp32 noise, the 8-virtual-device sharded path agrees at
@@ -18,7 +18,7 @@ gates on the bench side:
     (chosen <= fixed on both area-cells and energy, fixed on ties),
     deterministic within a process and byte-identical across processes
     for the same seed, and the chosen mapping round-trips through the
-    v3 manifest (v2 manifests still load, as the fixed scheme).
+    manifest.
 
 Hypothesis-randomized variants of the bijectivity and zero-drift
 properties live in ``tests/test_mapping_search_props.py``; the
@@ -54,7 +54,7 @@ from repro.core.sparse import (
     reorder_columns,
 )
 from repro.engine import (
-    EngineConfig,
+    CompileOptions,
     compile_network,
     conv_mapping_search,
     load_program,
@@ -93,7 +93,8 @@ def progs(mini):
     cfg, params, bits = mini
     return (
         compile_network(cfg, params, bits),
-        compile_network(cfg, params, bits, optimize="auto"),
+        compile_network(cfg, params, bits,
+                        options=CompileOptions(optimize="auto")),
     )
 
 
@@ -125,8 +126,10 @@ def test_cost_model_zero_drift_int8(mini):
     """Same zero-drift contract when the search prices the quantized
     cell-slice count."""
     cfg, params, bits = mini
-    prog = compile_network(cfg, params, bits, precision="int8",
-                           optimize="auto")
+    prog = compile_network(
+        cfg, params, bits,
+        options=CompileOptions(precision="int8", optimize="auto"),
+    )
     rep = prog.hardware_report()
     for c, row in zip(prog.convs, rep["layers"]):
         assert c.mapping.cells_per_weight == prog.cells_per_weight
@@ -161,13 +164,13 @@ def test_visited_candidates_all_bijective(mini):
     permutation on the layer's engine operands — no reorder strategy can
     drop or duplicate an output column."""
     cfg, params, bits = mini
-    ecfg = EngineConfig()
+    opts = CompileOptions()
     for i in (1, 2, 3):
         w = np.asarray(params[f"conv{i}"]["w"], np.float32)
-        order = patch_order(w.shape[1], w.shape[2], ecfg.block)
-        wp = _pad_axis(_pad_axis(conv_matrix(w, order), 0, ecfg.block), 1,
-                       ecfg.tile)
-        masks = nonzero_block_masks(wp, ecfg.block)
+        order = patch_order(w.shape[1], w.shape[2], opts.block)
+        wp = _pad_axis(_pad_axis(conv_matrix(w, order), 0, opts.block), 1,
+                       opts.tile)
+        masks = nonzero_block_masks(wp, opts.block)
         res = conv_mapping_search(w, bits[f"conv{i}"], out_hw=10)
         assert res.evaluations == len(res.visited) > 1
         for cand in res.visited:
@@ -181,16 +184,16 @@ def test_predicted_bricks_match_built(mini):
     """predicted_tile_nnz (the search's engine-memory objective) equals
     the brick count the lowering actually stores, per strategy."""
     cfg, params, bits = mini
-    ecfg = EngineConfig()
+    opts = CompileOptions()
     w = np.asarray(params["conv2"]["w"], np.float32)
-    order = patch_order(w.shape[1], w.shape[2], ecfg.block)
-    wp = _pad_axis(_pad_axis(conv_matrix(w, order), 0, ecfg.block), 1,
-                   ecfg.tile)
-    masks = nonzero_block_masks(wp, ecfg.block)
+    order = patch_order(w.shape[1], w.shape[2], opts.block)
+    wp = _pad_axis(_pad_axis(conv_matrix(w, order), 0, opts.block), 1,
+                   opts.tile)
+    masks = nonzero_block_masks(wp, opts.block)
     for strategy in REORDERS:
         order = reorder_columns(masks, strategy)
-        predicted = int(predicted_tile_nnz(masks, order, ecfg.tile).sum())
-        bp = lower_matrix(wp, ecfg.block, ecfg.tile, reorder=strategy)
+        predicted = int(predicted_tile_nnz(masks, order, opts.tile).sum())
+        bp = lower_matrix(wp, opts.block, opts.tile, reorder=strategy)
         assert predicted == int(bp.nnz.sum())
 
 
@@ -256,9 +259,11 @@ def test_search_config_validation():
 def test_optimize_arg_validation(mini):
     cfg, params, bits = mini
     with pytest.raises(ValueError, match="optimize"):
-        compile_network(cfg, params, bits, optimize="bogus")
+        compile_network(cfg, params, bits,
+                        options=CompileOptions(optimize="bogus"))
     with pytest.raises(ValueError, match="optimize"):
-        compile_network(cfg, params, bits, optimize=42)
+        compile_network(cfg, params, bits,
+                        options=CompileOptions(optimize=42))
 
 
 def test_choose_fc_reorder_counts_complete():
@@ -304,7 +309,9 @@ def test_forced_reorder_bit_identical_xla(mini, x8, strategy):
     fixed = compile_network(cfg, params, bits)
     auto = compile_network(
         cfg, params, bits,
-        optimize=MappingSearchConfig(reorders=(strategy,)),
+        options=CompileOptions(
+            optimize=MappingSearchConfig(reorders=(strategy,))
+        ),
     )
     _assert_layout_only(fixed, auto, x8)
 
@@ -323,9 +330,12 @@ def test_auto_int8_tolerance_equal(mini):
     quantization scales depend on column grouping, so a reorder can
     shift individual logits by O(quantization error)."""
     cfg, params, bits = mini
-    fixed = compile_network(cfg, params, bits, precision="int8")
-    auto = compile_network(cfg, params, bits, precision="int8",
-                           optimize="auto")
+    fixed = compile_network(cfg, params, bits,
+                            options=CompileOptions(precision="int8"))
+    auto = compile_network(
+        cfg, params, bits,
+        options=CompileOptions(precision="int8", optimize="auto"),
+    )
     x = jax.random.normal(jax.random.PRNGKey(5), (64, 1, 12, 12))
     ref = np.asarray(make_forward(fixed, backend="xla")(x))
     out = np.asarray(make_forward(auto, backend="xla")(x))
@@ -345,7 +355,7 @@ def test_sharded_auto_matches_subprocess():
     from conftest import unfolded
     from repro.core.pruning import (build_dictionaries, magnitude_prune,
                                     project_params)
-    from repro.engine import compile_network, make_forward
+    from repro.engine import CompileOptions, compile_network, make_forward
     from repro.launch.mesh import make_mesh
     from repro.models.cnn import (conv_weight_names, init_cnn,
                                   mini_cnn_config)
@@ -361,7 +371,8 @@ def test_sharded_auto_matches_subprocess():
 
     out = {}
     fixed = compile_network(cfg, params, bits)
-    auto = compile_network(cfg, params, bits, optimize="auto")
+    auto = compile_network(cfg, params, bits,
+                           options=CompileOptions(optimize="auto"))
     ref = np.asarray(make_forward(fixed, backend="xla")(x))
     single = np.asarray(make_forward(auto, backend="xla")(x))
     sharded = np.asarray(make_forward(auto, backend="xla", mesh=mesh)(x))
@@ -372,8 +383,9 @@ def test_sharded_auto_matches_subprocess():
                                                       - ref_u).max())
     out["fp32_sharded_vs_single"] = float(np.abs(sharded - single).max())
 
-    autoq = compile_network(cfg, params, bits, precision="int8",
-                            optimize="auto")
+    autoq = compile_network(
+        cfg, params, bits,
+        options=CompileOptions(precision="int8", optimize="auto"))
     sq = np.asarray(make_forward(autoq, backend="xla")(x))
     shq = np.asarray(make_forward(autoq, backend="xla", mesh=mesh)(x))
     out["int8_sharded_vs_single"] = float(np.abs(shq - sq).max())
@@ -394,7 +406,7 @@ def test_search_cross_process_determinism():
     body = """
     from repro.core.pruning import (build_dictionaries, magnitude_prune,
                                     project_params)
-    from repro.engine import compile_network
+    from repro.engine import CompileOptions, compile_network
     from repro.models.cnn import (conv_weight_names, init_cnn,
                                   mini_cnn_config)
 
@@ -404,7 +416,8 @@ def test_search_cross_process_determinism():
     params = magnitude_prune(params, names, 0.7)
     dicts = build_dictionaries(params, names, 4)
     params, bits = project_params(params, dicts)
-    prog = compile_network(cfg, params, bits, optimize="auto")
+    prog = compile_network(cfg, params, bits,
+                           options=CompileOptions(optimize="auto"))
     print(json.dumps({
         "mappings": [c.mapping.to_manifest() for c in prog.convs],
         "fc": prog.fc.reorder,
@@ -422,7 +435,7 @@ def test_in_process_matches_subprocess(progs):
     res = _run_sub(1, """
     from repro.core.pruning import (build_dictionaries, magnitude_prune,
                                     project_params)
-    from repro.engine import compile_network
+    from repro.engine import CompileOptions, compile_network
     from repro.models.cnn import (conv_weight_names, init_cnn,
                                   mini_cnn_config)
 
@@ -432,7 +445,8 @@ def test_in_process_matches_subprocess(progs):
     params = magnitude_prune(params, names, 0.7)
     dicts = build_dictionaries(params, names, 4)
     params, bits = project_params(params, dicts)
-    prog = compile_network(cfg, params, bits, optimize="auto")
+    prog = compile_network(cfg, params, bits,
+                           options=CompileOptions(optimize="auto"))
     print(json.dumps([c.mapping.to_manifest() for c in prog.convs]))
     """)
     assert res == [c.mapping.to_manifest() for c in auto.convs]
@@ -453,31 +467,6 @@ def test_v3_roundtrip_preserves_mapping(tmp_path, progs, x8):
     out = np.asarray(make_forward(loaded, backend="xla")(x8))
     np.testing.assert_array_equal(out, ref)
     assert loaded.hardware_report() == auto.hardware_report()
-
-
-def test_v2_manifest_loads_as_fixed_scheme(tmp_path, progs, x8):
-    """A hand-downgraded v2 manifest (no mapping keys) still loads: convs
-    get ``mapping=None``, the FC reorder defaults to 'pattern', and the
-    program verifies clean."""
-    from conftest import channel_major, downgrade_manifest
-
-    # a v2 compiler lowered every conv channel-major
-    fixed = channel_major(progs[0])
-    d = str(tmp_path / "prog")
-    save_program(d, fixed)
-    manifest = downgrade_manifest(d, 2)
-    for e in manifest["convs"]:
-        del e["mapping"]
-    del manifest["fc"]["reorder"]
-    with open(os.path.join(d, "program.json"), "w") as f:
-        json.dump(manifest, f)
-    loaded = load_program(d)
-    assert all(c.mapping is None for c in loaded.convs)
-    assert loaded.fc.reorder == "pattern"
-    ref = np.asarray(make_forward(fixed, backend="xla")(x8))
-    np.testing.assert_array_equal(
-        np.asarray(make_forward(loaded, backend="xla")(x8)), ref
-    )
 
 
 def test_report_mapping_section(progs):

@@ -30,7 +30,7 @@ from repro.core.quantize import (
     quantize_rows,
 )
 from repro.core.sparse import build_block_pattern, nonzero_block_masks
-from repro.engine import EngineConfig, compile_network, make_forward
+from repro.engine import CompileOptions, compile_network, make_forward
 from repro.kernels.ops import pattern_spmm
 from repro.models.cnn import (
     conv_weight_names,
@@ -56,7 +56,8 @@ def mini_pair():
     cfg = mini_cnn_config(num_classes=4, input_hw=12, widths=(8, 16, 16))
     params, bits = _pruned_net(cfg)
     prog = compile_network(cfg, params, bits)
-    progq = compile_network(cfg, params, bits, precision="int8")
+    progq = compile_network(cfg, params, bits,
+                            options=CompileOptions(precision="int8"))
     return cfg, prog, progq
 
 
@@ -228,7 +229,8 @@ def test_vgg16_quantized_area_win():
     cfg = vgg16_config(num_classes=10, input_hw=32)
     params, bits = _pruned_net(cfg, seed=1, sparsity=0.86, num_patterns=8)
     prog = compile_network(cfg, params, bits)
-    progq = compile_network(cfg, params, bits, precision="int8")
+    progq = compile_network(cfg, params, bits,
+                            options=CompileOptions(precision="int8"))
     rep, repq = prog.hardware_report(), progq.hardware_report()
     assert repq["crossbars"] < rep["crossbars"]
     assert repq["naive_crossbars"] < rep["naive_crossbars"]
@@ -236,13 +238,14 @@ def test_vgg16_quantized_area_win():
 
 def test_engine_config_validates_precision():
     with pytest.raises(ValueError):
-        EngineConfig(precision="int4")
+        CompileOptions(precision="int4")
     with pytest.raises(ValueError):
-        EngineConfig(cell_bits=0)
+        CompileOptions(cell_bits=0)
     cfg = mini_cnn_config(num_classes=4, input_hw=12, widths=(8, 16, 16))
     params, bits = _pruned_net(cfg)
     with pytest.raises(ValueError):
-        compile_network(cfg, params, bits, precision="fp16")
+        compile_network(cfg, params, bits,
+                        options=CompileOptions(precision="fp16"))
 
 
 def test_quantized_nondefault_geometry(mini_pair):
@@ -250,7 +253,8 @@ def test_quantized_nondefault_geometry(mini_pair):
     cfg, prog, _ = mini_pair
     params, bits = _pruned_net(cfg)
     progq = compile_network(
-        cfg, params, bits, ecfg=EngineConfig(block=9, tile=8, precision="int8")
+        cfg, params, bits,
+        options=CompileOptions(block=9, tile=8, precision="int8"),
     )
     x = jax.random.normal(jax.random.PRNGKey(5), (64, 1, 12, 12))
     ref = np.asarray(make_forward(prog, backend="xla")(x))

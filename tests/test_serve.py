@@ -12,8 +12,7 @@ The acceptance properties pinned here:
     work the scheduler admitted is never dropped; the internal
     ``SchedulerFull`` never escapes the public serve path;
   * **HTTP e2e** — real sockets, concurrent clients, chunked NDJSON
-    streaming, Prometheus ``/metrics``;
-  * the deprecated request shims keep working while warning.
+    streaming, Prometheus ``/metrics``.
 """
 
 import http.client
@@ -31,7 +30,7 @@ from repro.core.pruning import (
     magnitude_prune,
     project_params,
 )
-from repro.engine import InferenceService, execute
+from repro.engine import execute
 from repro.engine import compile_network
 from repro.engine.scheduler import SchedulerFull, SlotScheduler
 from repro.models.cnn import conv_weight_names, init_cnn, mini_cnn_config
@@ -140,33 +139,6 @@ def test_shed_retry_after_matches_live_backpressure(mini_prog):
         sess.scheduler.retry_after_hint()
     )
     sess.run([])  # drain
-
-
-# ---------------------------------------------------------------------------
-# deprecated shims
-# ---------------------------------------------------------------------------
-
-
-def test_classify_request_shim_warns_and_serves(mini_prog):
-    from repro.engine.service import ClassifyRequest
-
-    cfg, prog = mini_prog
-    with pytest.warns(DeprecationWarning, match="repro.serve.Request"):
-        req = ClassifyRequest(_images(1)[0])
-    assert isinstance(req, Request)
-    svc = InferenceService(prog, batch_slots=2)
-    svc.submit(req)
-    svc.run()
-    assert req.done and req.label is not None
-
-
-def test_runtime_request_shim_warns():
-    from repro.runtime.serve import Request as OldRequest
-
-    with pytest.warns(DeprecationWarning, match="repro.serve.Request"):
-        req = OldRequest(prompt=np.ones(4, np.int32), max_new_tokens=2)
-    assert isinstance(req, Request)
-    assert req.kind == "generate"
 
 
 # ---------------------------------------------------------------------------

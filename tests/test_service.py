@@ -25,13 +25,14 @@ from repro.core.pruning import (
     project_params,
 )
 from repro.engine import (
-    ClassifyRequest,
+    CompileOptions,
     InferenceService,
     SchedulerFull,
     compile_network,
     execute,
     make_forward,
 )
+from repro.serve import Request
 from repro.models.cnn import (
     cnn_apply,
     conv_weight_names,
@@ -103,9 +104,9 @@ def test_classify_alone_equals_classify_in_crowd(mini):
     cfg, params, bits, prog = mini
     x = _images(8)
     svc = InferenceService(prog, batch_slots=8, backend="xla")
-    alone = [ClassifyRequest(image=x[0])]
+    alone = [Request(image=x[0])]
     svc.serve(alone)
-    crowd = [ClassifyRequest(image=img) for img in x]
+    crowd = [Request(image=img) for img in x]
     svc.serve(crowd)
     np.testing.assert_array_equal(alone[0].logits, crowd[0].logits)
     assert alone[0].label == crowd[0].label
@@ -152,7 +153,7 @@ def test_bursty_trace_single_trace_exact_stats(mini):
     svc = InferenceService(prog, batch_slots=8, backend="xla",
                            collect_stats=True)
     images = _images(100, seed=3)
-    reqs = [ClassifyRequest(image=img) for img in images]
+    reqs = [Request(image=img) for img in images]
     # bursty arrivals: uneven burst sizes interleaved with service steps,
     # so batches run at many different occupancies
     bursts = [1, 7, 19, 2, 30, 5, 11, 3, 22]
@@ -193,7 +194,7 @@ def test_stats_windows_exclude_dead_slots(mini):
     svc = InferenceService(prog, batch_slots=8, backend="xla",
                            collect_stats=True)
     images = _images(3, seed=11)
-    svc.serve([ClassifyRequest(image=img) for img in images])
+    svc.serve([Request(image=img) for img in images])
     assert svc.batches_run == 1
     _, ref = make_forward(prog, backend="xla", collect_stats=True)(
         jnp.asarray(images)
@@ -210,8 +211,8 @@ def test_serve_validates_all_shapes_up_front(mini):
     cfg, params, bits, prog = mini
     svc = InferenceService(prog, batch_slots=4, backend="xla")
     good = _images(5)
-    reqs = [ClassifyRequest(image=img) for img in good]
-    reqs.insert(3, ClassifyRequest(image=np.zeros((1, 5, 5), np.float32)))
+    reqs = [Request(image=img) for img in good]
+    reqs.insert(3, Request(image=np.zeros((1, 5, 5), np.float32)))
     with pytest.raises(ValueError, match="request image"):
         svc.serve(reqs)
     assert svc.batches_run == 0
@@ -219,7 +220,7 @@ def test_serve_validates_all_shapes_up_front(mini):
     assert not svc.scheduler.has_work()
     # submit() validates too
     with pytest.raises(ValueError, match="request image"):
-        svc.submit(ClassifyRequest(image=np.zeros((2, 2), np.float32)))
+        svc.submit(Request(image=np.zeros((2, 2), np.float32)))
 
 
 def test_submit_backpressure_and_drain(mini):
@@ -227,16 +228,16 @@ def test_submit_backpressure_and_drain(mini):
     svc = InferenceService(prog, batch_slots=2, backend="xla", max_queue=3)
     imgs = _images(8, seed=13)
     for img in imgs[:3]:
-        svc.submit(ClassifyRequest(image=img))
+        svc.submit(Request(image=img))
     with pytest.raises(SchedulerFull):
-        svc.submit(ClassifyRequest(image=imgs[3]))
+        svc.submit(Request(image=imgs[3]))
     assert svc.metrics["rejected"] == 1
     done = svc.run()
     assert len(done) == 3 and all(r.done for r in done)
     # serve() interleaves submission with serving, so a one-shot batch
     # larger than queue + slots still drains through a bounded queue —
     # and its internal backpressure waits are not counted as rejections
-    reqs = [ClassifyRequest(image=img) for img in imgs]
+    reqs = [Request(image=img) for img in imgs]
     svc.serve(reqs)
     assert all(r.done for r in reqs)
     assert svc.metrics["rejected"] == 1  # only the explicit submit() above
@@ -267,7 +268,7 @@ def test_traced_service_keeps_single_trace_and_emits_lifecycles(mini):
     svc = InferenceService(prog, batch_slots=4, backend="xla",
                            collect_stats=True, tracer=tr)
     images = _images(10, seed=21)
-    reqs = [ClassifyRequest(image=img) for img in images]
+    reqs = [Request(image=img) for img in images]
     svc.serve(reqs)
     assert svc.trace_count() == 1
     assert all(r.done for r in reqs)
@@ -281,7 +282,7 @@ def test_traced_service_keeps_single_trace_and_emits_lifecycles(mini):
     assert all(e["cat"] == "serve" for e in steps)
     # an untraced service's logits are bit-identical: same forward path
     svc2 = InferenceService(prog, batch_slots=4, backend="xla")
-    reqs2 = [ClassifyRequest(image=img) for img in images]
+    reqs2 = [Request(image=img) for img in images]
     svc2.serve(reqs2)
     np.testing.assert_array_equal(
         np.stack([r.logits for r in reqs]),
@@ -329,7 +330,8 @@ def test_compile_tracer_records_phases_without_changing_output(mini):
 
     cfg, params, bits, prog = mini
     tr = Tracer()
-    prog_tr = compile_network(cfg, params, bits, tracer=tr)
+    prog_tr = compile_network(cfg, params, bits,
+                              options=CompileOptions(tracer=tr))
     names = [s.name for s in tr.spans("compile")]
     assert "compile_network" in names
     assert {"prune", "reorder", "pack"} <= set(names)

@@ -8,9 +8,9 @@ the chain traces, before any compiler pass) and the sha256 of its logits
 on four seeded images, each on the XLA path and in the Pallas
 interpreter. They were recorded when each layer's stored output order
 was first folded into the next layer's weight rows (manifest format 6),
-so the forward gathers no channels; the program a format-5 manifest
-loads, which gathers after every layer, gives the same logits to fp32
-rounding. The logits are computed in a child process held to one core,
+so the forward gathers no channels; the same chain lowered as the format-5
+compiler did, which gathers after every layer, gives the same logits to
+fp32 rounding. The logits are computed in a child process held to one core,
 since XLA's CPU kernels may split their sums by the number of cores.
 """
 
@@ -22,10 +22,10 @@ import sys
 import jax
 import numpy as np
 import pytest
-from conftest import downgrade_manifest, pre_v6_columns, unfolded
+from conftest import pre_v6_columns, unfolded
 
 from repro.core.pruning import build_dictionaries, magnitude_prune, project_params
-from repro.engine import compile_network, load_program, make_forward, save_program
+from repro.engine import compile_network, make_forward
 from repro.models.cnn import conv_weight_names, init_cnn, mini_cnn_config
 
 SRC = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
@@ -97,10 +97,11 @@ def test_vgg_chain_forward_is_pinned(digests, backend, what):
 
 
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
-def test_folded_chain_matches_its_format5_copy(backend, tmp_path):
-    """The pinned chain gathers nothing; the same program saved as format 5
-    (rows in channel order, padding first) gathers after every layer and
-    at the logits, and gives the same logits to fp32 rounding."""
+def test_folded_chain_matches_its_format5_copy(backend):
+    """The pinned chain gathers nothing; the same program lowered as the
+    format-5 compiler did (rows in channel order, padding first) gathers
+    after every layer and at the logits, and gives the same logits to
+    fp32 rounding."""
     cfg = mini_cnn_config(num_classes=4, input_hw=12, widths=(8, 16, 16))
     params = init_cnn(cfg, jax.random.PRNGKey(0))
     names = conv_weight_names(cfg)
@@ -108,9 +109,7 @@ def test_folded_chain_matches_its_format5_copy(backend, tmp_path):
     params, bits = project_params(params, build_dictionaries(params, names, 4))
     prog = compile_network(cfg, params, bits)
     with pre_v6_columns():
-        path = save_program(str(tmp_path / "v5"), unfolded(prog))
-    downgrade_manifest(path, 5)
-    old = load_program(path)
+        old = unfolded(prog)
     assert prog.gathers() == 0
     assert old.gathers() == len(old.convs) + 1
     kw = {"backend": "pallas", "interpret": True} if backend == "pallas" \
