@@ -79,7 +79,7 @@ from repro.engine.stats import (
     skip_patterns_and_masks,
     stats_from_counts,
 )
-from repro.kernels.ops import pattern_spmm_raw
+from repro.kernels.ops import default_backend, pattern_spmm_raw, spmm_grid
 from repro.kernels.ops import _pad_to as _pad_axis_to_mult
 from repro.models.cnn import POOLS, channel_norm, conv_out_hw, out_sizes
 from repro.parallel.sharding import shard_block_pattern
@@ -207,18 +207,32 @@ class _Dispatch:
         self.backend = backend
         self.interpret = interpret
         self.bm = bm
+        # layer -> the Pallas kernel's grid at the latest trace
+        self.grid: dict[str, dict] = {}
 
     def prepare(self, bp):
         """Per-layer operand prep (identity here; padding when sharded)."""
         return bp
 
-    def spmm(self, x2d: jax.Array, bp, prepared) -> jax.Array:
+    def _raw(self, name, x2d, w_comp, block_ids, block, w_scales):
+        """``pattern_spmm_raw`` on one device's operands, recording the
+        kernel's ``(M, bm, grid steps)`` under ``name`` at trace time."""
+        backend = self.backend or default_backend()
+        if backend == "pallas":
+            dtype = x2d.dtype if w_scales is None else jnp.int8
+            m = x2d.shape[0]
+            bm, steps = spmm_grid(m, w_comp, dtype, self.bm)
+            self.grid[name] = {"m": m, "bm": bm, "steps": steps}
+        return pattern_spmm_raw(
+            x2d, w_comp, block_ids, block, backend=backend,
+            interpret=self.interpret, bm=self.bm, w_scales=w_scales,
+        )
+
+    def spmm(self, x2d: jax.Array, bp, prepared, name: str) -> jax.Array:
         """``x2d @ W`` in ``bp``'s stored column order: [M, tiles*tile]."""
         with jax.named_scope("spmm"):
-            return pattern_spmm_raw(
-                x2d, bp.w_comp, bp.block_ids, bp.block,
-                backend=self.backend, interpret=self.interpret, bm=self.bm,
-                w_scales=bp.w_scales,
+            return self._raw(
+                name, x2d, bp.w_comp, bp.block_ids, bp.block, bp.w_scales
             ).astype(x2d.dtype)
 
     def counts(
@@ -256,7 +270,7 @@ class _ShardedDispatch(_Dispatch):
             part.data_axis if part.data > 1 and m % part.data == 0 else None
         )
 
-    def spmm(self, x2d: jax.Array, bp, prepared) -> jax.Array:
+    def spmm(self, x2d: jax.Array, bp, prepared, name: str) -> jax.Array:
         part = self.part
         model, maxis = part.model, part.model_axis
         width = (prepared.n_tiles // model) * bp.tile
@@ -271,10 +285,9 @@ class _ShardedDispatch(_Dispatch):
             # quantizes its (replicated-along-model) activation rows
             # identically, so the psum still combines disjoint column
             # slabs of already-dequantized fp32 partials.
-            yl = pattern_spmm_raw(
-                xl, w_comp, block_ids, bp.block,
-                backend=self.backend, interpret=self.interpret, bm=self.bm,
-                w_scales=scales[0] if quantized else None,
+            yl = self._raw(
+                name, xl, w_comp, block_ids, bp.block,
+                scales[0] if quantized else None,
             )
             # The slabs are disjoint, so a tiled all_gather would also
             # reassemble them with less traffic; the scatter + psum form
@@ -385,7 +398,9 @@ def _run_conv(
                 patches[:, : op.k_unpadded], op.c_in, op.kernel * op.kernel,
                 stat_masks, row_valid, op.patch_order,
             )
-    y = _outputs(disp.spmm(patches, op.bp, prepared), op.bp, op.c_out, lay)
+    y = _outputs(
+        disp.spmm(patches, op.bp, prepared, op.name), op.bp, op.c_out, lay
+    )
     with jax.named_scope("epilogue"):
         # bias, and every op after it, in the output's channel order
         y = y + jnp.asarray(op.bias[lay.out_order])
@@ -416,7 +431,7 @@ def _run_fc(
         x = _gather(x, lay.gather_in, 1)
     with jax.named_scope("patches"):
         xf = _pad_features(x, op.bp.k_in)
-    y = _outputs(disp.spmm(xf, op.bp, prepared), op.bp, op.d_out, lay)
+    y = _outputs(disp.spmm(xf, op.bp, prepared, "fc"), op.bp, op.d_out, lay)
     with jax.named_scope("epilogue"):
         return y + jnp.asarray(op.bias[lay.out_order])
 
@@ -455,7 +470,8 @@ def make_forward(
       interpret: run the Pallas kernels in the interpreter.  Only an
         explicit ``True`` does; ``backend='pallas'`` off the TPU without
         it raises.
-      bm: spmm row tile; None autotunes from the batch size.
+      bm: spmm row tile; None picks each call's from its row count
+        (``kernels/ops._pick_bm``).
       collect_stats: also measure per-layer all-zero-selection counts.
       mesh: a ``jax.sharding.Mesh`` to execute on.  Tiles split over the
         mesh's model axis (psum-combined partial outputs), batch rows and
@@ -475,7 +491,10 @@ def make_forward(
     influence live logits; their own outputs are meaningless and must be
     dropped by the caller.  The returned callable exposes
     ``fn.trace_count()``, the number of times the forward has been traced
-    (a retrace means a new batch shape hit the jit cache), and
+    (a retrace means a new batch shape hit the jit cache),
+    ``fn.kernel_grid()``, each layer's Pallas spmm call at the latest
+    trace as ``{"m": rows, "bm": row tile, "steps": grid steps}`` (per
+    shard under a mesh; empty until traced and on the XLA path), and
     ``fn.lower(x, valid)``, the jitted forward's ``jax.jit(...).lower``
     (inspect the compiled program; after a call at the same shapes its
     ``compile()`` is a cache hit).
@@ -579,6 +598,7 @@ def make_forward(
             return logits, stats
 
     fn.trace_count = lambda: traces["n"]
+    fn.kernel_grid = lambda: {k: dict(v) for k, v in disp.grid.items()}
     fn.lower = jitted.lower
     return fn
 

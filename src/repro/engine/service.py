@@ -109,8 +109,19 @@ class InferenceService:
 
     def warmup(self) -> None:
         """Trace/compile the forward at the serving batch shape without
-        sending traffic through the scheduler (metrics stay at zero)."""
-        warmup_forward(self._forward, self.program, self.batch_slots)
+        sending traffic through the scheduler (metrics stay at zero).
+
+        A ``service.warmup`` span on the tracer; its ``spmm_grid_steps``
+        arg is the forward's Pallas grid steps summed over its spmm calls
+        (``kernel_grid``; 0 on the XLA path, absent for a wrapped forward
+        that exposes no ``kernel_grid``)."""
+        with self._tracer.span("service.warmup", cat="serve") as sp:
+            warmup_forward(self._forward, self.program, self.batch_slots)
+            grid = getattr(self._forward, "kernel_grid", None)
+            if grid is not None:
+                sp.args["spmm_grid_steps"] = sum(
+                    g["steps"] for g in grid().values()
+                )
 
     def lower(self):
         """``jax.jit(...).lower`` of the served forward at the fixed batch

@@ -8,8 +8,9 @@ Each op dispatches between:
 
 ``backend=None`` picks Pallas on TPU devices and XLA elsewhere.  Asking
 for ``backend='pallas'`` off the TPU without ``interpret=True`` raises:
-nothing silently swaps the compiled kernel for the interpreter.  Shapes
-are padded to tile multiples here so kernels only see aligned sizes.
+nothing silently swaps the compiled kernel for the interpreter.  The row
+tile of ``pattern_spmm`` is chosen here from the call's static shapes
+(``_pick_bm``); the flash-attention wrapper pads to its tiles.
 """
 
 from __future__ import annotations
@@ -36,6 +37,7 @@ __all__ = [
     "default_backend",
     "pattern_spmm",
     "pattern_spmm_raw",
+    "spmm_grid",
     "flash_attention",
     "ou_mvm",
 ]
@@ -73,19 +75,72 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-def _pick_bm(m: int, dtype) -> int:
-    """Row-tile for pattern_spmm, autotuned from the (static) batch M.
+# Row tiles above 128 rows are chosen from M alone (``_pick_bm``).  A grid
+# step of the Pallas spmm costs about 0.4 us on a v5e whatever its work at
+# bm = 128, so the tile is as large as these allow: at most _BM_MAX rows,
+# at least _BM_MIN where no ragged last block is needed, and its blocks
+# within _VMEM_BUDGET (the scoped VMEM default is 16 MiB; the f32 dot at
+# HIGHEST precision needs temporaries beyond the blocks).
+_BM_MAX = 1024
+_BM_MIN = 512
+_VMEM_BUDGET = 8 * 2**20
 
-    Serving batches are often tiny; padding 1 row up to bm=128 wastes a
-    128x factor of MXU work, so pick the smallest sublane-aligned tile that
-    covers M.  The floor keeps the second-minor dimension at the dtype's
-    minimum TPU tile (8 for 4-byte, 16 for 2-byte, 32 for 1-byte types).
+
+def _sublane_floor(dtype) -> int:
+    """The dtype's minimum TPU tile height: 8 rows of 4-byte, 16 of 2-byte,
+    32 of 1-byte types."""
+    return {4: 8, 2: 16, 1: 32}.get(jnp.dtype(dtype).itemsize, 8)
+
+
+def _bm_cap(dtype, block: int, tile: int) -> int:
+    """The most rows whose blocks fit ``_VMEM_BUDGET``: x ``[bm, block]`` and
+    the fp32 output ``[bm, tile]`` double-buffered, the fp32 accumulator
+    once, beside two ``[block, tile]`` bricks; a multiple of 128, at most
+    ``_BM_MAX``."""
+    item = jnp.dtype(dtype).itemsize
+    per_row = 2 * block * item + 2 * tile * 4 + tile * 4
+    rows = (_VMEM_BUDGET - 2 * block * tile * item) // per_row
+    return max(128, min(_BM_MAX, rows // 128 * 128))
+
+
+def _pick_bm(m: int, dtype, block: int = 128, tile: int = 128) -> int:
+    """Row tile of the Pallas spmm for ``m`` rows, from static shapes alone.
+
+    Up to 128 rows (serving batches, the FC heads): the smallest of 8, 32
+    and 128 that covers M, at least the dtype's sublane floor; the caller
+    pads M up to it.  Padding 1 row up to 128 would waste a 128x factor of
+    MXU work.
+
+    Above 128 rows: the largest multiple of 128 that divides M within
+    :func:`_bm_cap`, when that is at least ``_BM_MIN`` rows or all of M.
+    Otherwise M is split into ``ceil(M / cap)`` near-equal tiles rounded up
+    to the sublane floor, and the last block is ragged: Pallas masks its
+    out-of-bounds rows, and each output row reads only its own input row,
+    so the rows past M change nothing and no padded copy of x is made (one
+    tile taller than M is padded, as below 128 rows).
     """
-    floor = {4: 8, 2: 16, 1: 32}.get(jnp.dtype(dtype).itemsize, 8)
+    floor = _sublane_floor(dtype)
     for cand in (8, 32, 128):
         if m <= cand:
             return max(cand, floor)
-    return 128
+    cap = _bm_cap(dtype, block, tile)
+    div = max((c for c in range(128, cap + 1, 128) if m % c == 0), default=0)
+    if div >= min(_BM_MIN, m):
+        return div
+    rows = -(-m // -(-m // cap))  # ceil(M / the number of tiles)
+    return -(-rows // floor) * floor
+
+
+def spmm_grid(
+    m: int, w_comp, dtype, bm: int | None = None
+) -> tuple[int, int]:
+    """``(bm, grid steps)`` of the Pallas spmm on ``m`` rows of ``dtype``
+    (int8 for a quantized program) against ``w_comp`` ``[T, k_max, block,
+    tile]``: the tile :func:`pattern_spmm_raw` runs (``bm`` wins when
+    given) and ``ceil(M / bm) * T * k_max``."""
+    t, k_max, block, tile = w_comp.shape
+    bm = bm or _pick_bm(m, dtype, block, tile)
+    return bm, -(-m // bm) * t * k_max
 
 
 def pattern_spmm_raw(
@@ -120,9 +175,9 @@ def pattern_spmm_raw(
         interp = _interpret_flag(interpret)
         xin = xq if quant else xm
         m = xin.shape[0]
-        if bm is None:
-            bm = _pick_bm(m, xin.dtype)
-        xp = _pad_to(xin, 0, bm)
+        bm, _ = spmm_grid(m, w_comp, xin.dtype, bm)
+        # only a tile taller than M pads; a ragged last block is masked
+        xp = _pad_to(xin, 0, bm) if m < bm else xin
         if quant:
             y = pattern_spmm_pallas_quant(
                 xp, w_comp, block_ids, w_scales,
@@ -150,7 +205,7 @@ def pattern_spmm(
 ) -> jax.Array:
     """y = x @ W for a block-pattern compressed weight.  x: [..., K].
 
-    ``bm=None`` (default) autotunes the row tile from the batch size.
+    ``bm=None`` (default) picks the row tile from M (``_pick_bm``).
     Quantized weights (``bp.w_scales is not None``) dispatch the int8
     variant transparently; output dtype follows ``x`` either way.  The
     compressed matmul runs under ``jax.named_scope("spmm")`` and the

@@ -13,14 +13,23 @@ This is the TPU-native form of the paper's mapping (DESIGN §3):
   * the fp32 accumulator lives in VMEM scratch across the k dimension.
 
 Grid: (m_tiles, n_tiles, k_max), k innermost so the accumulator stays
-resident while bricks stream.  VMEM working set per step:
-bm*block + block*bn + bm*bn (+ fp32 acc) — with bm = bn = block = 128 and
-bf16 inputs ≈ 96 KiB + 64 KiB acc, comfortably inside 16 MiB VMEM; bm can
-be raised to 512 for better MXU pipelining (see ops.py autotile).
+resident while bricks stream.  A grid step has a fixed cost (pipeline
+bookkeeping, DMA issue, the MXU weight load) of about 0.25 us on a v5e
+besides its dot and its DMAs, so the row tile bm is as tall as VMEM and
+the row count allow: ``ops._pick_bm`` keeps 8/32/128 for M <= 128 and
+above it takes the largest multiple of 128 that divides M, up to
+``_BM_MAX`` (1024), when that is at least 512 rows or all of M, else
+near-equal tiles whose last block is ragged (Pallas masks its rows past
+M).  Each output row is the same sum of the same brick dots in the same
+k order at any bm.  VMEM per step at bm = 1024, block = tile = 128, fp32:
+x [bm, block] and the output [bm, tile] double-buffered, 2 x 512 KiB
+each, the fp32 accumulator 512 KiB and two 64 KiB bricks: 2.6 MiB of
+blocks, inside ``ops._VMEM_BUDGET`` (8 MiB) and the 16 MiB scoped VMEM
+default with room for the HIGHEST-precision dot's bf16 splits.
 
-Padded brick slots (k >= nnz[t]) carry zero weights: they waste a cycle
-but contribute zero — ops.py sorts tiles by nnz so the waste concentrates
-in few tiles (the paper's grey area analogue).
+Padded brick slots (k >= nnz[t]) carry zero weights and block id 0: they
+cost a grid step but contribute zero.  Neither benchmark program has
+one (``nnz == k_max`` in every tile).
 """
 
 from __future__ import annotations

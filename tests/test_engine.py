@@ -1,5 +1,6 @@
 """Inference engine: lowering/executor parity, serialization, service."""
 
+import dataclasses
 import json
 import os
 
@@ -197,6 +198,49 @@ def test_mini_cnn_parity(mini, backend, interpret):
     ref = cnn_apply(cfg, params, x)
     out = make_forward(prog, backend=backend, interpret=interpret)(x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-3)
+
+
+@pytest.fixture(scope="module")
+def tall():
+    """A mini net at 50x50, batch 2: conv1 and conv2 run 5,000 rows (tiles
+    of 1,000 divide them), conv3 after the pool 1,250 rows, which no tile
+    of 512 rows or more divides (two tiles of 632, the last ragged)."""
+    cfg = mini_cnn_config(num_classes=4, input_hw=50, widths=(8, 16, 16))
+    cfg = dataclasses.replace(cfg, pool_after=frozenset({2}))
+    params, bits = _pruned_net(cfg, seed=3)
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 1, 50, 50))
+    return compile_network(cfg, params, bits), x
+
+
+def test_forward_same_logits_at_any_tile(tall):
+    """The automatic row tiles and bm=128 run the same per-row sums of the
+    same bricks in the same order, so the logits are equal."""
+    prog, x = tall
+    auto = make_forward(prog, backend="pallas", interpret=True)
+    fixed = make_forward(prog, backend="pallas", interpret=True, bm=128)
+    np.testing.assert_array_equal(np.asarray(auto(x)), np.asarray(fixed(x)))
+    assert {k: g["bm"] for k, g in auto.kernel_grid().items()} == {
+        "conv1": 1000, "conv2": 1000, "conv3": 632, "fc": 8,
+    }
+    assert all(g["bm"] == 128 for g in fixed.kernel_grid().values())
+
+
+@pytest.mark.parametrize("bm", [None, 128])
+def test_kernel_grid_counts_steps(tall, bm):
+    """``kernel_grid()`` gives each spmm call's rows, tile and grid steps:
+    ceil(M / bm) * T * k_max."""
+    prog, x = tall
+    fwd = make_forward(prog, backend="pallas", interpret=True, bm=bm)
+    assert fwd.kernel_grid() == {}
+    fwd(x)
+    grid = fwd.kernel_grid()
+    rows = {"conv1": 5000, "conv2": 5000, "conv3": 1250, "fc": 2}
+    assert {k: g["m"] for k, g in grid.items()} == rows
+    bps = {op.name: op.bp for op in prog.convs} | {"fc": prog.fc.bp}
+    for name, g in grid.items():
+        t, k_max = bps[name].w_comp.shape[:2]
+        assert g["steps"] == -(-g["m"] // g["bm"]) * t * k_max
+    assert make_forward(prog, backend="xla").kernel_grid() == {}
 
 
 @pytest.fixture(scope="module")

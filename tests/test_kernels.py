@@ -55,27 +55,83 @@ def test_pattern_spmm_sweep(rng, m, k, n, block, tile, dtype):
     )
 
 
-def test_pattern_spmm_bm_autotune(rng):
-    """bm=None picks a sublane-aligned row tile from M; result unchanged."""
+# the spmm calls' row counts in both benchmark cells (VGG16 at batch 32,
+# ResNet-50 at batch 128, 224x224): batch x Ho x Wo
+CELL_ROWS = (1_605_632, 401_408, 100_352, 25_088, 6_272)
+
+
+@pytest.mark.parametrize(
+    "m,dtype,want",
+    [
+        (1, jnp.float32, 8),
+        (8, jnp.float32, 8),
+        (20, jnp.float32, 32),
+        (200, jnp.float32, 200),  # one tile of all 200 rows, no padding
+        (1, jnp.bfloat16, 16),  # bf16 min sublane tile is 16
+        (100, jnp.bfloat16, 128),
+        (32, jnp.float32, 32),  # VGG16's FC at batch 32
+        (128, jnp.float32, 128),  # ResNet-50's FC at batch 128
+        (130, jnp.float32, 136),  # rounded up to the sublane floor, padded
+        (2500, jnp.float32, 840),  # three near-equal tiles, the last ragged
+        (6_272, jnp.float32, 896),  # 7 x 128 divides it
+        (25_088, jnp.float32, 896),
+        (1_605_632, jnp.float32, 1024),
+    ],
+)
+def test_pattern_spmm_bm_autotune(m, dtype, want):
+    """bm=None picks a sublane-aligned row tile from M alone."""
     from repro.kernels.ops import _pick_bm
 
-    assert _pick_bm(1, jnp.float32) == 8
-    assert _pick_bm(8, jnp.float32) == 8
-    assert _pick_bm(20, jnp.float32) == 32
-    assert _pick_bm(200, jnp.float32) == 128
-    assert _pick_bm(1, jnp.bfloat16) == 16  # bf16 min sublane tile is 16
-    assert _pick_bm(100, jnp.bfloat16) == 128
+    assert _pick_bm(m, dtype) == want
 
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8])
+@pytest.mark.parametrize("m", CELL_ROWS)
+def test_pick_bm_cell_rows(m, dtype):
+    """Every row count of the benchmark cells gets a tile of at least 512
+    rows that divides it (no padded copy, no ragged block) and whose blocks
+    fit the VMEM budget."""
+    from repro.kernels import ops
+
+    bm = ops._pick_bm(m, dtype)
+    assert m % bm == 0 and bm % 128 == 0
+    assert bm >= 512
+    item = jnp.dtype(dtype).itemsize
+    vmem = 2 * bm * 128 * item + 3 * bm * 128 * 4 + 2 * 128 * 128 * item
+    assert vmem <= ops._VMEM_BUDGET
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
+@pytest.mark.parametrize("m", [129, 777, 2500, 4_100, 10_007, 123_457])
+def test_pick_bm_ragged_rows(m, dtype):
+    """A row count no large tile divides: near-equal tiles of at most the
+    cap, on the sublane floor, whose last block is ragged by fewer rows
+    than one tile (or, under one tile, padded); never a 128-row grid."""
+    from repro.kernels import ops
+
+    bm = ops._pick_bm(m, dtype)
+    floor = ops._sublane_floor(dtype)
+    assert bm % floor == 0 and bm <= ops._bm_cap(dtype, 128, 128)
+    tiles = -(-m // bm)
+    assert tiles * bm - m < bm
+    assert tiles == -(-m // ops._bm_cap(dtype, 128, 128))
+    if m >= 4_096:
+        assert bm >= 512
+
+
+@pytest.mark.parametrize("m", [1, 3, 17, 130, 2500])
+def test_pattern_spmm_auto_tile_matches_xla(rng, m):
+    """The automatic tile (padded, whole or ragged) gives the XLA path's
+    result."""
     k, n = 256, 256
     w = rng.normal(size=(k, n)).astype(np.float32)
     bp = build_block_pattern(w, num_patterns=4, density=0.4)
-    for m in (1, 3, 17, 130):
-        x = jnp.asarray(rng.normal(size=(m, k)).astype(np.float32))
-        y_auto = pattern_spmm(x, bp, backend="pallas", interpret=True)
-        y_ref = pattern_spmm(x, bp, backend="xla")
-        np.testing.assert_allclose(
-            np.asarray(y_auto), np.asarray(y_ref), rtol=2e-5, atol=2e-5
-        )
+    x = jnp.asarray(rng.normal(size=(m, k)).astype(np.float32))
+    y_auto = pattern_spmm(x, bp, backend="pallas", interpret=True)
+    y_ref = pattern_spmm(x, bp, backend="xla")
+    np.testing.assert_allclose(
+        np.asarray(y_auto), np.asarray(y_ref), rtol=2e-5, atol=2e-5
+    )
 
 
 def test_pattern_spmm_matches_dense_oracle(rng):

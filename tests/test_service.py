@@ -294,6 +294,34 @@ def test_traced_service_keeps_single_trace_and_emits_lifecycles(mini):
     assert "engine_service_latency_seconds_count 10" in text
 
 
+@pytest.mark.parametrize("backend,interpret", BACKENDS)
+def test_warmup_span_counts_spmm_grid_steps(mini, backend, interpret):
+    """``warmup`` is a ``service.warmup`` span whose ``spmm_grid_steps``
+    is the forward's Pallas grid steps over its spmm calls (0 on the XLA
+    path, which runs no grid)."""
+    from repro.obs import Tracer
+
+    cfg, params, bits, prog = mini
+    tr = Tracer()
+    svc = InferenceService(prog, batch_slots=4, backend=backend,
+                           interpret=interpret, tracer=tr)
+    svc.warmup()
+    (span,) = [s for s in tr.spans("serve") if s.name == "service.warmup"]
+    grid = svc._forward.kernel_grid()
+    assert span.args["spmm_grid_steps"] == sum(
+        g["steps"] for g in grid.values()
+    )
+    if backend == "xla":
+        assert grid == {} and span.args["spmm_grid_steps"] == 0
+    else:
+        # every call of the mini net at batch 4 fits one row tile
+        assert set(grid) == {op.name for op in prog.convs} | {"fc"}
+        assert span.args["spmm_grid_steps"] == sum(
+            op.bp.w_comp.shape[0] * op.bp.w_comp.shape[1]
+            for op in [*prog.convs, prog.fc]
+        )
+
+
 def test_instrumented_forward_matches_and_reports_drift(mini):
     """Measured per-layer seconds — a plain dict, as a profile of the
     jitted forward's ``<layer>/*`` scopes gives them — feed the hardware

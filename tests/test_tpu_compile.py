@@ -29,11 +29,18 @@ BLOCK = TILE = 128
 
 # (M, K, T, k_max) of the VGG16-224 engine at batch 8: conv2 (M = 8*224^2,
 # K = 64*9 padded to 640), conv11-13 (M = 8*14^2, K = 512*9), and the FC
-# head at the int8 row floor
+# head at the int8 row floor; then the benchmark cells' largest call
+# (VGG16's conv2 at batch 32, ResNet-50's stem rows at batch 128) and
+# their deepest (VGG16's conv11-13 at batch 32, ResNet-50's layer4 at
+# batch 128), each at the row tile ``_pick_bm`` chooses; and a row count
+# no large tile divides, whose last row block is ragged
 SHAPES = {
     "conv2": (8 * 224 * 224, 640, 1, 5),
     "conv13": (8 * 14 * 14, 4608, 4, 36),
     "fc": (32, 512, 8, 4),
+    "cell_largest": (32 * 224 * 224, 640, 1, 5),
+    "cell_deepest": (32 * 14 * 14, 4608, 4, 36),
+    "ragged": (4_100, 640, 1, 5),
 }
 
 

@@ -10,7 +10,10 @@ interpreter. They were recorded when each layer's stored output order
 was first folded into the next layer's weight rows (manifest format 6),
 so the forward gathers no channels; the same chain lowered as the format-5
 compiler did, which gathers after every layer, gives the same logits to
-fp32 rounding. The logits are computed in a child process held to one core,
+fp32 rounding. The Pallas HLO pin was recorded again when the kernel's row
+tile began to follow each call's row count (one tile of 576 rows a layer,
+where five of 128 ran after a row pad); its logits pin did not move. The
+logits are computed in a child process held to one core,
 since XLA's CPU kernels may split their sums by the number of cores.
 """
 
@@ -36,7 +39,7 @@ PINS = {
         "logits": "8fe775ac449524c133912614c472c5aef3abc907eaf1db9b87052e1d1400df21",
     },
     "pallas": {
-        "hlo": "cad983103162ae50c98bb344d4ec61f97783ec864300b6d3e746cc6bc4bd60b0",
+        "hlo": "731acdf29cbb69dcc1f80f206d6e90f74fede844a62e33dc695079e4a5c25145",
         "logits": "2794bc09ec8b3b12b4b2747952db2db180344e76b47027b37e0f32a2ea946ad1",
     },
 }
