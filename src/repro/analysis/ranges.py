@@ -455,7 +455,9 @@ def analyze_network(
         )
         # channel_norm (divisor in [eps, inf)) where the layer keeps one
         # (folded batch norm is already in the bound), the residual add,
-        # ReLU; max pools are the identity on intervals
+        # ReLU or ReLU6's clip to [0, 6]; max pools are the identity on
+        # intervals.  A grouped conv's bound is its block-diagonal
+        # operand's: the zeros outside each group add nothing.
         act_lo, act_hi = pre_lo, pre_hi
         if conv.norm == "channel":
             act_lo = min(pre_lo, 0.0) / NORM_EPS
@@ -463,8 +465,10 @@ def analyze_network(
         if conv.residual is not None:
             r_lo, r_hi = tensors[conv.residual]
             act_lo, act_hi = act_lo + r_lo, act_hi + r_hi
-        if conv.relu:
+        if conv.activation in ("relu", "relu6"):
             act_lo, act_hi = max(act_lo, 0.0), max(act_hi, 0.0)
+        if conv.activation == "relu6":
+            act_lo, act_hi = min(act_lo, 6.0), min(act_hi, 6.0)
         facts = _analyze_operand(conv.bp, conv.name, program.cell_bits, r) \
             if quantized else {}
         layers.append(LayerRanges(

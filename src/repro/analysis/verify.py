@@ -30,10 +30,11 @@ V112   a zero scale must not silently drop a nonzero brick
 V113   quantized payloads are int8 within ``[-QMAX, QMAX]``
 V114   ``cell_slices`` recompose bit-exactly to the stored ``w_comp``
 V115   fp32 payloads are finite
-V201   ``pattern_bits`` shaped ``[c_out, c_in]``, integer
+V201   ``pattern_bits`` shaped ``[c_out, c_in // groups]``, integer
 V202   pattern bitmasks lie within the ``kernel x kernel`` window
 V203   layer-vs-operand geometry: ``bp.k_in``/``bp.n_out`` are exactly the
-       padded matmul dims of the layer
+       padded matmul dims of the layer, and ``groups`` divides both of its
+       channel counts
 V204   bias shape/finiteness
 V205   mapping strategy tags are known (``block_order`` in
        ``BLOCK_ORDERS``, conv/fc ``reorder`` in ``REORDERS``) and the
@@ -489,6 +490,7 @@ def verify_conv(conv, cell_bits: int = 4, report: Report | None = None) -> Repor
             "an odd kernel",
             severity=WARNING, layer=name, location="kernel",
         )
+    groups = getattr(conv, "groups", 1)
     if conv.out_hw < 1 or conv.c_in < 1 or conv.c_out < 1:
         r.add(
             "V203",
@@ -497,15 +499,25 @@ def verify_conv(conv, cell_bits: int = 4, report: Report | None = None) -> Repor
             layer=name, location="dims",
         )
         return r
+    if groups < 1 or conv.c_in % groups or conv.c_out % groups:
+        r.add(
+            "V203",
+            f"groups={groups} does not divide c_in={conv.c_in} and "
+            f"c_out={conv.c_out}",
+            layer=name, location="groups",
+        )
+        return r
 
     bits = np.asarray(conv.pattern_bits)
-    if bits.shape != (conv.c_out, conv.c_in) or not np.issubdtype(
+    want_bits = (conv.c_out, conv.c_in // groups)
+    if bits.shape != want_bits or not np.issubdtype(
         bits.dtype, np.integer
     ):
+        per = "" if groups == 1 else f" // groups={groups}"
         r.add(
             "V201",
             f"pattern_bits shape {bits.shape} (dtype {bits.dtype}) != "
-            f"integer [c_out={conv.c_out}, c_in={conv.c_in}]",
+            f"integer [c_out={conv.c_out}, c_in={conv.c_in}{per}]",
             layer=name, location="pattern_bits",
         )
     elif bits.size and (
@@ -711,7 +723,7 @@ def verify_network(program, report: Report | None = None) -> Report:
     tensors = {"input": (cfg.in_channels, cfg.input_hw)}
     prev = "input"
     fields = ("name", "c_in", "c_out", "kernel", "stride", "src",
-              "residual", "relu", "norm", "pool")
+              "residual", "activation", "norm", "pool", "groups")
     for conv, spec in zip(program.convs, specs):
         got = tuple(getattr(conv, f) for f in fields)
         want = tuple(getattr(spec, f) for f in fields)

@@ -268,6 +268,7 @@ def build_block_pattern(
     masks: np.ndarray | None = None,
     tracer=None,
     reorder: str = "pattern",
+    fixed_order: np.ndarray | None = None,
 ) -> BlockPatternWeight:
     """Pattern-prune + reorder + compress a dense [K, N] weight.
 
@@ -290,7 +291,9 @@ def build_block_pattern(
     (:func:`reorder_columns`).  All strategies produce the same
     ``BlockPatternWeight`` contract and identical semantics (the stored
     inverse permutation undoes the layout); they differ only in how many
-    bricks the tiles need.
+    bricks the tiles need.  ``fixed_order`` (int [N], new position ->
+    original column), when given, is the column permutation itself and no
+    strategy runs: the caller has fixed which columns share a tile.
     """
     tracer = tracer or NULL_TRACER
     w = np.asarray(w, np.float32)
@@ -316,8 +319,15 @@ def build_block_pattern(
 
     # kernel reordering: group equal masks; the strategy orders the groups
     with tracer.span("reorder", cat="compile", n_out=n_out,
-                     strategy=reorder):
-        new_order = reorder_columns(masks, reorder)
+                     strategy=reorder if fixed_order is None else "fixed"):
+        if fixed_order is None:
+            new_order = reorder_columns(masks, reorder)
+        else:
+            new_order = np.asarray(fixed_order, np.int32)
+            if not np.array_equal(np.sort(new_order), np.arange(n_out)):
+                raise ValueError(
+                    f"order is not a permutation of the {n_out} columns"
+                )
         inv_order = np.argsort(new_order).astype(np.int32)
         masks_sorted = masks[new_order]
         w_sorted = w[:, new_order]

@@ -6,11 +6,13 @@ tap-major where K spans more than one block), dispatches through
 ``kernels/ops.pattern_spmm_raw`` (the Pallas TPU kernel on the chip; the
 XLA path, or the Pallas interpreter when asked for, on CPU), then the
 layer's epilogue: the real output columns, bias, the shared
-``channel_norm`` where the layer keeps one, the residual add, ReLU and the
-pool, matching ``cnn_apply`` (``resnet_apply`` for a ResNet) on the pruned
-weights to numerical tolerance.  The program is a graph in execution
-order: the forward keeps each layer's output by name, for a later layer's
-``src`` or ``residual``.
+``channel_norm`` where the layer keeps one, the residual add, the
+activation (ReLU or ReLU6, or none) and the pool, matching ``cnn_apply``
+(``resnet_apply`` for a ResNet, ``mobilenet_apply`` for a MobileNetV2) on
+the pruned weights to numerical tolerance.  A grouped or depthwise conv
+takes the same path: im2col and the spmm of its block-diagonal matrix.
+The program is a graph in execution order: the forward keeps each
+layer's output by name, for a later layer's ``src`` or ``residual``.
 
 Each kept tensor stays in the channel order its producer's kernel wrote
 it in (the reordered columns of ``bp.new_order``): the next layer's
@@ -81,7 +83,13 @@ from repro.engine.stats import (
 )
 from repro.kernels.ops import default_backend, pattern_spmm_raw, spmm_grid
 from repro.kernels.ops import _pad_to as _pad_axis_to_mult
-from repro.models.cnn import POOLS, channel_norm, conv_out_hw, out_sizes
+from repro.models.cnn import (
+    ACTIVATIONS,
+    POOLS,
+    channel_norm,
+    conv_out_hw,
+    out_sizes,
+)
 from repro.parallel.sharding import shard_block_pattern
 
 __all__ = [
@@ -413,8 +421,8 @@ def _run_conv(
         with jax.named_scope("residual"):
             y = y + residual
     with jax.named_scope("epilogue"):
-        if op.relu:
-            y = jax.nn.relu(y)
+        if op.activation is not None:
+            y = ACTIVATIONS[op.activation](y)
         if op.pool is not None:
             y = POOLS[op.pool](y)
     return y, counts
@@ -472,7 +480,9 @@ def make_forward(
         it raises.
       bm: spmm row tile; None picks each call's from its row count
         (``kernels/ops._pick_bm``).
-      collect_stats: also measure per-layer all-zero-selection counts.
+      collect_stats: also measure per-layer all-zero-selection counts
+        (full convs only: a program with a grouped conv raises
+        ``NotImplementedError``).
       mesh: a ``jax.sharding.Mesh`` to execute on.  Tiles split over the
         mesh's model axis (psum-combined partial outputs), batch rows and
         stat counters over the data axis; without a mesh the historical
@@ -524,9 +534,11 @@ def make_forward(
       arg);
     * ``epilogue`` — the column slice, bias (in the output's channel
       order), NHWC->NCHW transpose, ``channel_norm`` (where ``norm ==
-      'channel'``), ReLU and the pool; for ``gap``, the mean;
+      'channel'``), the activation (ReLU, or ReLU6's clip) and the pool;
+      for ``gap``, the mean;
     * ``residual`` — the add of the layer's ``residual`` tensor, between
-      the two halves of its epilogue (a ResNet block's conv3);
+      the two halves of its epilogue (a ResNet block's conv3, a
+      MobileNetV2 block's linear projection);
     * ``stats`` — the ``collect_stats`` skip counters.
 
     Profiles attribute device time by these names; they are a contract.
@@ -546,6 +558,13 @@ def make_forward(
 
     stat_masks = {}
     if collect_stats:
+        grouped = [op.name for op in program.convs if op.groups > 1]
+        if grouped:
+            raise NotImplementedError(
+                "collect_stats: the skip counters count (input channel, "
+                "pattern) selections of full convs; grouped convs "
+                f"{grouped[:3]} are not counted"
+            )
         for op in program.convs:
             _, masks = skip_patterns_and_masks(
                 op.pattern_bits, op.kernel * op.kernel

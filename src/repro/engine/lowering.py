@@ -10,7 +10,10 @@ structure (``nonzero_block_masks``), so reorder -> compress -> index
 produces real kernel operands and the compressed program computes
 bit-the-same weights as the pruned dense network.  The FC head is lowered
 onto the same path, and so are dense convs (a ResNet's stem, 1x1 convs and
-shortcuts): their every brick is nonzero and stored.
+shortcuts): their every brick is nonzero and stored.  A grouped conv
+(``ConvSpec.groups``; a MobileNetV2's depthwise 3x3s) is the same spmm on
+its block-diagonal matrix (:func:`conv_matrix`), whose zero bricks the
+compression drops like any other.
 
 A layer whose params carry batch-norm statistics (``{w, bn: {gamma, beta,
 mean, var}}``) is folded into one weight and bias first
@@ -28,6 +31,15 @@ the FC's) are therefore built in the channel order of the tensor they
 read — the image's, or the producing conv's stored order — and recorded
 as its ``in_order``, which leaves the forward no gather between layers
 (``program.CompiledNetwork.layout``).
+
+Kernel reordering for depthwise convs: a depthwise conv's stored columns
+keep its input's order, so output tile ``j`` reads only the ``j``-th
+block of channels of each tap.  Its kernels are instead reordered one
+layer up: :func:`compile_network` stores the columns of the conv that
+produces a depthwise's input (where that producer is dense and feeds
+nothing else) in the stable sort of the depthwise's kernels by pattern,
+so each block of channels holds few patterns and a tap that none of them
+uses leaves a zero brick.
 """
 
 from __future__ import annotations
@@ -50,6 +62,7 @@ from repro.core.sparse import (
     BlockPatternWeight,
     build_block_pattern,
     nonzero_block_masks,
+    predicted_tile_nnz,
 )
 from repro.engine.program import (
     CompiledConv,
@@ -58,11 +71,13 @@ from repro.engine.program import (
     leading_order,
 )
 from repro.models.cnn import CNNConfig, ConvSpec, out_sizes
+from repro.models.mobilenet import MobileNetV2Config
 from repro.models.resnet import ResNetConfig
 from repro.obs.trace import NULL_TRACER, Tracer
 
 __all__ = ["CompileOptions", "PRECISIONS", "PATCH_ORDERS",
-           "patch_order", "conv_matrix", "fold_bn", "lower_matrix",
+           "patch_order", "group_dense", "conv_matrix", "fold_bn",
+           "lower_matrix",
            "lower_conv",
            "lower_fc", "conv_mapping_search", "compile_network"]
 
@@ -181,19 +196,39 @@ def patch_order(c_in: int, kernel: int, block: int) -> str:
     return "tap" if c_in * kernel * kernel > block else "channel"
 
 
+def group_dense(w: np.ndarray, groups: int = 1) -> np.ndarray:
+    """A grouped conv's weight ``[C_out, C_in/groups, Kh, Kw]`` as the
+    full ``[C_out, C_in, Kh, Kw]`` one, zero outside each output channel's
+    group (output channel ``o`` of group ``g = o // (C_out/groups)`` reads
+    input channels ``g * C_in/groups`` on)."""
+    w = np.asarray(w)
+    if groups == 1:
+        return w
+    co, cig = w.shape[:2]
+    if co % groups:
+        raise ValueError(f"C_out={co} is not a multiple of groups={groups}")
+    first = (np.arange(co) // (co // groups)) * cig
+    out = np.zeros((co, cig * groups) + w.shape[2:], w.dtype)
+    out[np.arange(co)[:, None], first[:, None] + np.arange(cig)] = w
+    return out
+
+
 def conv_matrix(
-    w: np.ndarray, order: str = "channel", in_order=None
+    w: np.ndarray, order: str = "channel", in_order=None, groups: int = 1,
 ) -> np.ndarray:
-    """[C_out, C_in, Kh, Kw] -> im2col matmul view [C_in*Kh*Kw, C_out].
+    """[C_out, C_in/groups, Kh, Kw] -> im2col matmul view [C_in*Kh*Kw,
+    C_out].
 
     The rows follow the executor's patch layout for ``order``
     (:data:`PATCH_ORDERS`): ``'channel'`` puts weight ``(c, dy, dx)`` at
     row ``c * Kh*Kw + (dy*Kw + dx)``, ``'tap'`` at row
     ``(dy*Kw + dx) * C_in + c``, where ``c`` is the position of the input
     channel in ``in_order`` (position ``i`` holds channel
-    ``in_order[i]``; None: channel order).
+    ``in_order[i]``; None: channel order).  A grouped conv's matrix is
+    block-diagonal (:func:`group_dense`): zero where an output channel
+    meets an input channel outside its group.
     """
-    w = np.asarray(w)
+    w = group_dense(w, groups)
     if in_order is not None:
         w = w[:, np.asarray(in_order)]
     co, ci = w.shape[:2]
@@ -207,6 +242,7 @@ def conv_matrix(
 def lower_matrix(
     wm: np.ndarray, block: int, tile: int, precision: str = "fp32",
     tracer: Tracer | None = None, reorder: str = "pattern",
+    order: np.ndarray | None = None,
 ) -> BlockPatternWeight:
     """Pad a dense [K, N] matrix to (block, tile) multiples and compress it
     losslessly from its nonzero structure; ``precision='int8'`` then
@@ -214,7 +250,9 @@ def lower_matrix(
 
     ``reorder`` selects the column-permutation strategy
     (``core/sparse.REORDERS``); every strategy yields the same semantics
-    through the stored inverse permutation.
+    through the stored inverse permutation.  ``order`` (a permutation of
+    the N real columns) fixes the stored column order instead, the
+    padding columns last.
 
     With a ``tracer`` the lowering phases land as ``compile``-category
     spans: ``prune`` (nonzero-structure mask discovery), ``reorder`` +
@@ -227,8 +265,11 @@ def lower_matrix(
     wp = _pad_axis(_pad_axis(np.asarray(wm, np.float32), 0, block), 1, tile)
     with tracer.span("prune", cat="compile", shape=list(wp.shape)):
         masks = nonzero_block_masks(wp, block)
+    if order is not None:
+        order = np.concatenate([np.asarray(order, np.int32), np.arange(
+            len(order), wp.shape[1], dtype=np.int32)])
     bp = build_block_pattern(wp, block=block, tile=tile, masks=masks,
-                             tracer=tracer, reorder=reorder)
+                             tracer=tracer, reorder=reorder, fixed_order=order)
     if precision == "int8":
         with tracer.span("quantize", cat="compile", shape=list(wp.shape)):
             bp = quantize_bp(bp)
@@ -259,36 +300,49 @@ def lower_conv(
     tracer: Tracer | None = None,
     mapping: MappingCandidate | None = None,
     in_order: np.ndarray | None = None,
+    out_order: np.ndarray | None = None,
 ) -> CompiledConv:
-    """One conv ``spec`` with weight ``w`` and bias ``b`` as an im2col
-    spmm, its rows in ``in_order``, the channel order of the tensor it
-    reads (None: channel order).  Pattern bits not given are read off the
-    weights' nonzero masks: a dense kernel of any size gets its all-ones
-    pattern."""
+    """One conv ``spec`` with weight ``w`` ``[c_out, c_in // groups, k,
+    k]`` and bias ``b`` as an im2col spmm, its rows in ``in_order``, the
+    channel order of the tensor it reads (None: channel order).  Its
+    stored columns are in ``out_order`` where given, in ``in_order`` for a
+    depthwise conv, else in the column reorder's own.  Pattern bits
+    ``[c_out, c_in // groups]`` not given are read off the weights'
+    nonzero masks: a dense kernel of any size gets its all-ones pattern."""
     name = spec.name
     w = np.asarray(w, np.float32)
-    c_out, c_in, kh, kw = w.shape
+    c_out, c_in_g, kh, kw = w.shape
+    g = spec.groups
     if kh != kw:
         raise ValueError(f"{name}: non-square kernel {kh}x{kw}")
-    if (c_in, c_out, kh) != (spec.c_in, spec.c_out, spec.kernel):
+    if spec.c_in % g or spec.c_out % g:
+        raise ValueError(
+            f"{name}: groups={g} does not divide c_in={spec.c_in} and "
+            f"c_out={spec.c_out}"
+        )
+    if (c_in_g, c_out, kh) != (spec.c_in // g, spec.c_out, spec.kernel):
         raise ValueError(
             f"{name}: weight {w.shape} does not match the layer's "
-            f"(c_out, c_in, k) = ({spec.c_out}, {spec.c_in}, {spec.kernel})"
+            f"(c_out, c_in // groups, k) = ({spec.c_out}, "
+            f"{spec.c_in // g}, {spec.kernel})"
         )
+    c_in = spec.c_in
     if pattern_bits is None:
         pattern_bits = masks_to_bits(kernel_masks(w))
     reorder = mapping.reorder if mapping is not None else "pattern"
     order = patch_order(c_in, kh, options.block)
     in_order = _order_or_identity(in_order, c_in)
+    if out_order is None and spec.depthwise:
+        out_order = in_order
     return CompiledConv(
         name=name,
         c_in=c_in,
         c_out=c_out,
         kernel=kh,
         out_hw=out_hw,
-        bp=lower_matrix(conv_matrix(w, order, in_order), options.block,
+        bp=lower_matrix(conv_matrix(w, order, in_order, g), options.block,
                         options.tile, options.precision, tracer=tracer,
-                        reorder=reorder),
+                        reorder=reorder, order=out_order),
         bias=np.asarray(b, np.float32).copy(),
         pattern_bits=np.asarray(pattern_bits, np.int64).copy(),
         mapping=mapping,
@@ -296,10 +350,11 @@ def lower_conv(
         stride=spec.stride,
         src=spec.src,
         residual=spec.residual,
-        relu=spec.relu,
+        activation=spec.activation,
         norm=spec.norm,
         pool=spec.pool,
         in_order=in_order,
+        groups=g,
     )
 
 
@@ -388,8 +443,65 @@ def conv_mapping_search(
     )
 
 
+def _layer_bits(params: dict, pattern_bits: dict, name: str) -> np.ndarray:
+    bits = pattern_bits.get(name)
+    if bits is None:
+        bits = masks_to_bits(kernel_masks(np.asarray(params[name]["w"])))
+    return np.asarray(bits, np.int64)
+
+
+def depthwise_producer_orders(
+    layers: list[ConvSpec], params: dict, pattern_bits: dict
+) -> dict[str, np.ndarray]:
+    """``{producer: column order}``: the kernel reordering of each
+    depthwise conv, imposed on the conv that produces its input.
+
+    The order is the stable sort of the depthwise's kernels by pattern
+    bits.  It is imposed only where the producer is a dense full conv
+    (every kernel all ones, so no column order stores fewer of its bricks
+    than another), adds no residual, and is read by nothing but the
+    depthwise conv (no other layer, nor the FC through the pool): then
+    the tensor's new order costs no gather anywhere."""
+    reads: dict[str, int] = {}
+    prev = "input"
+    for spec in layers:
+        for t in (prev if spec.src is None else spec.src, spec.residual):
+            if t is not None:
+                reads[t] = reads.get(t, 0) + 1
+        prev = spec.name
+    reads[prev] = reads.get(prev, 0) + 1  # the FC, through the pool
+    by_name = {spec.name: spec for spec in layers}
+    out = {}
+    prev = "input"
+    for spec in layers:
+        src = prev if spec.src is None else spec.src
+        prev = spec.name
+        producer = by_name.get(src)
+        if (not spec.depthwise or producer is None or producer.groups != 1
+                or producer.residual is not None or reads[src] != 1):
+            continue
+        full = (1 << producer.kernel ** 2) - 1
+        if not np.all(_layer_bits(params, pattern_bits, src) == full):
+            continue
+        bits = _layer_bits(params, pattern_bits, spec.name)[:, 0]
+        out[src] = np.argsort(bits, kind="stable").astype(np.int32)
+    return out
+
+
+def _channel_order_bricks(spec: ConvSpec, w: np.ndarray,
+                          options: CompileOptions) -> int:
+    """The bricks a conv ``spec`` of weight ``w`` stores with its rows and
+    columns in channel order (a depthwise conv whose input is)."""
+    order = patch_order(spec.c_in, spec.kernel, options.block)
+    wp = _pad_axis(_pad_axis(conv_matrix(w, order, None, spec.groups), 0,
+                             options.block), 1, options.tile)
+    masks = nonzero_block_masks(wp, options.block)
+    return int(predicted_tile_nnz(masks, np.arange(wp.shape[1]),
+                                  options.tile).sum())
+
+
 def compile_network(
-    cfg: CNNConfig | ResNetConfig,
+    cfg: CNNConfig | ResNetConfig | MobileNetV2Config,
     params: dict,
     pattern_bits: dict[str, np.ndarray] | None = None,
     *,
@@ -398,15 +510,17 @@ def compile_network(
     """Lower a (pruned) CNN end-to-end into a :class:`CompiledNetwork`.
 
     Args:
-      cfg: network geometry (``models.cnn.CNNConfig`` or
-        ``models.resnet.ResNetConfig``): its ``layers()`` are lowered in
-        order, one loop for every network.
+      cfg: network geometry (``models.cnn.CNNConfig``,
+        ``models.resnet.ResNetConfig`` or
+        ``models.mobilenet.MobileNetV2Config``): its ``layers()`` are
+        lowered in order, one loop for every network.
       params: parameter pytree ``{<layer>: {w, b} or {w, bn}, ..., fc: {w,
         b}}``; a layer with ``bn`` statistics is folded (:func:`fold_bn`,
         eps ``cfg.bn_eps``).
       pattern_bits: per-conv packed pattern bitmasks
-        (``PruneResult.pattern_bits``); recovered from the weights' nonzero
-        structure for layers not listed (dense layers: all-ones).
+        (``PruneResult.pattern_bits``; ``[c_out, c_in // groups]``);
+        recovered from the weights' nonzero structure for layers not
+        listed (dense layers: all-ones).
       options: the lowering geometry and every compile-pass switch
         (:class:`CompileOptions`); None: ``CompileOptions()``.
     """
@@ -423,7 +537,16 @@ def compile_network(
     pattern_bits = pattern_bits or {}
     convs = []
     layers = cfg.layers()
+    if search_cfg is not None:
+        grouped = [spec.name for spec in layers if spec.groups > 1]
+        if grouped:
+            raise NotImplementedError(
+                "optimize: the mapping search prices full convs only; "
+                f"grouped convs {grouped[:3]} are not in its space"
+            )
     sizes = out_sizes(layers, cfg.input_hw)
+    producer_orders = depthwise_producer_orders(layers, params, pattern_bits)
+    dw_bricks = dw_channel_bricks = 0
     # the channel order of each tensor the forward keeps; None: channel
     # order (the image's)
     orders: dict[str, np.ndarray | None] = {"input": None}
@@ -432,6 +555,7 @@ def compile_network(
         "compile_network", cat="compile",
         layers=len(layers) + 1, precision=options.precision,
         optimize=search_cfg is not None,
+        depthwise=sum(spec.depthwise for spec in layers),
     ) as compile_span:
         for spec in layers:
             name = spec.name
@@ -453,7 +577,7 @@ def compile_network(
                         area_cells=res.cost.area_cells,
                         fixed_area_cells=res.fixed_cost.area_cells,
                     )
-            with tracer.span(f"lower:{name}", cat="compile"):
+            with tracer.span(f"lower:{name}", cat="compile") as sp:
                 conv = lower_conv(
                     spec,
                     w,
@@ -464,7 +588,15 @@ def compile_network(
                     tracer=tracer,
                     mapping=mapping,
                     in_order=orders[prev if spec.src is None else spec.src],
+                    out_order=producer_orders.get(name),
                 )
+                bricks = int(np.sum(conv.bp.nnz))
+                sp.args.update(groups=spec.groups, bricks=bricks)
+                if spec.depthwise:
+                    channel_bricks = _channel_order_bricks(spec, w, options)
+                    sp.args.update(bricks_channel_order=channel_bricks)
+                    dw_bricks += bricks
+                    dw_channel_bricks += channel_bricks
             convs.append(conv)
             # a residual add keeps the conv's own order
             orders[name] = leading_order(conv.bp, conv.c_out)
@@ -495,7 +627,10 @@ def compile_network(
             cell_bits=options.cell_bits,
         )
         # the channel gathers the forward will trace
-        compile_span.args.update(gathers=program.gathers())
+        compile_span.args.update(
+            gathers=program.gathers(), dw_bricks=dw_bricks,
+            dw_bricks_channel_order=dw_channel_bricks,
+        )
     if verify is not None:
         from repro.analysis.ranges import analyze_network
         from repro.analysis.verify import verify_network

@@ -82,12 +82,20 @@ class CompiledConv:
     executor's patches (``lowering.patch_order``): ``'channel'`` (row
     ``c * kernel**2 + tap``) or ``'tap'`` (row ``tap * c_in + c``).
 
-    ``stride``, ``src``, ``residual``, ``relu``, ``norm`` and ``pool``
-    are the layer's :class:`~repro.models.cnn.ConvSpec` fields: im2col at
-    ``stride`` with ``kernel // 2`` padding over tensor ``src`` (``None``:
-    the previous op's output), then the epilogue bias -> ``channel_norm``
-    (``norm == 'channel'``) -> ``+ residual`` -> ReLU -> ``pool``.
-    ``out_hw`` is the conv's output side, before its pool.
+    ``stride``, ``src``, ``residual``, ``activation``, ``norm``,
+    ``pool`` and ``groups`` are the layer's
+    :class:`~repro.models.cnn.ConvSpec` fields: im2col at ``stride`` with
+    ``kernel // 2`` padding over tensor ``src`` (``None``: the previous
+    op's output), then the epilogue bias -> ``channel_norm`` (``norm ==
+    'channel'``) -> ``+ residual`` -> ``activation`` (ReLU, ReLU6 or
+    none) -> ``pool``.  ``out_hw`` is the conv's output side, before its
+    pool.  A grouped conv (``groups > 1``) is the same im2col spmm on its
+    block-diagonal matrix (``lowering.conv_matrix``): its ``c_in *
+    kernel**2`` rows hold zeros outside each output channel's group, and
+    its ``pattern_bits`` are ``[c_out, c_in // groups]``.  A depthwise
+    conv's stored columns are in its input's order (``in_order``), so
+    output tile ``j`` reads only the ``j``-th 128 input channels of each
+    tap.
 
     ``mapping`` (optional) is the searched per-layer crossbar mapping
     (``CompileOptions(optimize=...)``, ``core/mapsearch.py``):
@@ -110,20 +118,25 @@ class CompiledConv:
     out_hw: int  # output feature-map side at compile-time input_hw
     bp: BlockPatternWeight
     bias: np.ndarray  # [c_out]
-    pattern_bits: np.ndarray  # [c_out, c_in] packed kernel patterns
+    pattern_bits: np.ndarray  # [c_out, c_in // groups] packed patterns
     mapping: MappingCandidate | None = None
     patch_order: str = "channel"
     stride: int = 1
     src: str | None = None
     residual: str | None = None
-    relu: bool = True
+    activation: str | None = "relu"
     norm: str = "channel"
     pool: str | None = None
     in_order: np.ndarray | None = None
+    groups: int = 1
 
     @property
     def k_unpadded(self) -> int:
         return self.c_in * self.kernel * self.kernel
+
+    @property
+    def depthwise(self) -> bool:
+        return self.groups > 1 and self.groups == self.c_in == self.c_out
 
 
 @dataclasses.dataclass
@@ -302,14 +315,17 @@ class CompiledNetwork:
                  f"density={block_density(c.bp):.2f}")
             if c.kernel != 3 or c.stride != 1:
                 d = f"{c.kernel}x{c.kernel}/{c.stride} " + d
+            if c.groups > 1:
+                d = ("depthwise " if c.depthwise
+                     else f"groups={c.groups} ") + d
             if c.src is not None:
                 d = f"({c.src}) " + d
             if c.norm == "channel":
                 d += " + norm"
             if c.residual is not None:
                 d += f" + {c.residual}"
-            if c.relu:
-                d += " + relu"
+            if c.activation is not None:
+                d += f" + {c.activation}"
             if c.pool is not None:
                 d += {"max2": " + maxpool2x2",
                       "max3s2": " + maxpool3x3/2"}[c.pool]
@@ -329,7 +345,7 @@ class CompiledNetwork:
         comp = dense = 0
         for c in self.convs:
             comp += self._bp_bytes(c.bp)
-            dense += c.k_unpadded * c.c_out * 4
+            dense += c.k_unpadded // c.groups * c.c_out * 4
         comp += self._bp_bytes(self.fc.bp)
         dense += self.fc.d_in * self.fc.d_out * 4
         return comp, dense
@@ -343,12 +359,18 @@ class CompiledNetwork:
         return n
 
     def _synthetic_layers(self) -> list[SyntheticLayer]:
-        """The convs as ``SyntheticLayer``s for crossbar-model pricing."""
+        """The convs as ``SyntheticLayer``s for crossbar-model pricing.
+
+        A grouped conv is priced as its ``c_out`` kernels over the
+        ``c_in // groups`` input channels each one reads (its
+        ``pattern_bits``): a depthwise conv is ``C`` kernels of one input
+        channel each, never the dense ``C x C`` layer its block-diagonal
+        spmm matrix would suggest."""
         layers = []
         for c in self.convs:
             spec = LayerSpec(
                 name=c.name,
-                c_in=c.c_in,
+                c_in=c.c_in // c.groups,
                 c_out=c.c_out,
                 out_hw=c.out_hw,
                 kernel_size=c.kernel * c.kernel,
@@ -358,7 +380,7 @@ class CompiledNetwork:
                 patterns=tuple(int(b) for b in np.unique(c.pattern_bits)),
             )
             weights = np.zeros(
-                (c.c_out, c.c_in, spec.kernel_size), np.float32
+                (c.c_out, spec.c_in, spec.kernel_size), np.float32
             )
             layers.append(SyntheticLayer(
                 spec=spec, pdict=pdict,

@@ -26,8 +26,8 @@ refused (M002): recompile such a program and save it again.
 
 Only chain programs (``CNNConfig``: stride-1 convs with ``channel_norm``,
 ReLU and optional 2x2 max pools) have a manifest; saving a graph program
-(a ResNet's strides, residual adds, folded batch norm, other pools) raises
-``NotImplementedError``.
+(a ResNet's strides, residual adds, folded batch norm, other pools; a
+MobileNetV2's grouped convs and ReLU6) raises ``NotImplementedError``.
 """
 
 from __future__ import annotations
@@ -128,8 +128,8 @@ def save_program(directory: str, program: CompiledNetwork) -> str:
         raise NotImplementedError(
             "save_program: the manifest format holds chain programs "
             f"(CNNConfig) only, not a {type(program.config).__name__} "
-            "network: strides, src/residual edges, folded batch norm and "
-            "3x3/2 pools are not in it"
+            "network: strides, src/residual edges, folded batch norm, "
+            "3x3/2 pools, grouped convs and ReLU6 are not in it"
         )
     parent = os.path.dirname(os.path.abspath(directory))
     os.makedirs(parent, exist_ok=True)

@@ -7,9 +7,10 @@ the convolutions the mapping scheme targets.  Params are plain pytrees
 with ``repro.core`` mapping code.
 
 Every network config the compiler takes (``CNNConfig`` here,
-``models.resnet.ResNetConfig``) describes itself as a list of
-:class:`ConvSpec` in execution order (``layers()``), followed by a global
-average pool and one FC; :func:`out_sizes` walks that list's geometry.
+``models.resnet.ResNetConfig``, ``models.mobilenet.MobileNetV2Config``)
+describes itself as a list of :class:`ConvSpec` in execution order
+(``layers()``), followed by a global average pool and one FC;
+:func:`out_sizes` walks that list's geometry.
 """
 
 from __future__ import annotations
@@ -25,6 +26,7 @@ from repro.core.synthetic import TABLE_II, VGG16_CONV_CHANNELS, synthesize_netwo
 
 __all__ = [
     "ConvSpec",
+    "ACTIVATIONS",
     "POOLS",
     "conv_out_hw",
     "pool_out_hw",
@@ -51,8 +53,13 @@ class ConvSpec:
     image) or an earlier conv's ``name``, whose output (after its pool)
     they read.  The epilogue is bias, ``channel_norm`` where ``norm ==
     "channel"`` (``"none"``: batch norm folded into weight and bias),
-    the ``residual`` add, ReLU where ``relu``, then ``pool``
-    (:data:`POOLS`).  Padding is ``kernel // 2`` on every side.
+    the ``residual`` add, the ``activation`` (:data:`ACTIVATIONS`, or
+    None: linear), then ``pool`` (:data:`POOLS`).  Padding is ``kernel //
+    2`` on every side.  ``groups`` splits the channels as PyTorch's
+    ``Conv2d(groups=...)`` does: output channel ``o`` reads only the
+    ``c_in // groups`` input channels of its group, and its weight is
+    ``[c_out, c_in // groups, kernel, kernel]``; a depthwise conv is
+    ``groups == c_in == c_out``.
     """
 
     name: str
@@ -61,10 +68,15 @@ class ConvSpec:
     kernel: int
     stride: int = 1
     src: str | None = None  # None: the previous op's output
-    residual: str | None = None  # tensor added before the ReLU
-    relu: bool = True
+    residual: str | None = None  # tensor added before the activation
+    activation: str | None = "relu"  # 'relu', 'relu6' or None (linear)
     norm: str = "channel"  # 'channel' or 'none'
     pool: str | None = None  # None, 'max2' or 'max3s2'
+    groups: int = 1
+
+    @property
+    def depthwise(self) -> bool:
+        return self.groups > 1 and self.groups == self.c_in == self.c_out
 
 
 def conv_out_hw(hw: int, kernel: int, stride: int = 1) -> int:
@@ -250,6 +262,14 @@ def max_pool_3x3s2(x: jax.Array) -> jax.Array:
 
 
 POOLS = {"max2": max_pool_2x2, "max3s2": max_pool_3x3s2}
+
+
+def relu6(x: jax.Array) -> jax.Array:
+    """``min(max(x, 0), 6)``: MobileNetV2's activation."""
+    return jnp.clip(x, 0.0, 6.0)
+
+
+ACTIVATIONS = {"relu": jax.nn.relu, "relu6": relu6}
 
 
 def cnn_apply(cfg: CNNConfig, params: dict, x: jax.Array) -> jax.Array:

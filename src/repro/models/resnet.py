@@ -83,7 +83,7 @@ class ResNetConfig:
                     shortcut, src = f"{name}.downsample", prev
                     out.append(ConvSpec(
                         f"{name}.downsample", c, c_out, 1, stride, src=prev,
-                        relu=False, norm="none",
+                        activation=None, norm="none",
                     ))
                 out += [
                     ConvSpec(f"{name}.conv1", c, width, 1, src=src,

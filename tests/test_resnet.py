@@ -282,7 +282,7 @@ def test_range_pass_follows_the_graph(small):
         if c.residual is not None:
             r = cert.layer(c.residual)
             lo, hi = lo + r.act_lo, hi + r.act_hi
-        if c.relu:
+        if c.activation == "relu":
             lo, hi = max(lo, 0.0), max(hi, 0.0)
         assert (e.act_lo, e.act_hi) == (lo, hi), c.name
     x = jnp.clip(_images(cfg, n=4), -3.0, 3.0)
